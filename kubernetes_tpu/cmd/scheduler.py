@@ -72,9 +72,9 @@ def build_parser() -> argparse.ArgumentParser:
                    "expressPriorityThreshold; default: annotation only)")
     p.add_argument("--compile-cache-dir", default=None,
                    help="persistent XLA compile cache directory (config "
-                   "compileCacheDir; default /tmp/ktpu_jax_cache or "
-                   "$KTPU_COMPILE_CACHE_DIR; 'off' disables) — restarts "
-                   "pay zero recompiles")
+                   "compileCacheDir; $JAX_COMPILATION_CACHE_DIR wins when "
+                   "set; default <checkout>/.jax_cache; 'off' disables) "
+                   "— restarts pay zero recompiles")
     p.add_argument("--prewarm", action="store_true", default=None,
                    help="pre-pay engine compiles for every AIMD pow2 "
                    "width (+ the express width) at startup (config "
@@ -373,22 +373,11 @@ def main(argv=None) -> int:
 
     # persistent compile cache BEFORE any jit compile (engine build,
     # prewarm, first cycle) so every executable of this process is served
-    # from / saved to disk.  The cache directory is partitioned by
-    # topology (backend + device count + mesh shape) so an executable
-    # compiled single-chip is never served to a sharded process, or vice
-    # versa (utils/compilecache.py topology_tag)
+    # from / saved to disk (utils/compilecache.py: JAX_COMPILATION_CACHE_DIR,
+    # else compileCacheDir, else the checkout's .jax_cache)
     from kubernetes_tpu.utils.compilecache import enable_compile_cache
 
-    # ... and by megacycle depth: a K-deep scan is a different program
-    # family than the single-cycle executables, and the K dimension must
-    # partition the cache exactly like the mesh shape does
-    mesh_extra = None
-    if cc.shard_devices or cc.mesh_shape:
-        mesh_extra = f"mesh{cc.mesh_shape or cc.shard_devices}"
-    if cc.megacycle_batches > 1:
-        mega_tag = f"mega{cc.megacycle_batches}"
-        mesh_extra = f"{mesh_extra}-{mega_tag}" if mesh_extra else mega_tag
-    enable_compile_cache(cc.compile_cache_dir, topology_extra=mesh_extra)
+    enable_compile_cache(cc.compile_cache_dir)
 
     if args.kubeconfig:
         with open(args.kubeconfig) as f:

@@ -4,8 +4,8 @@ The reference treats fault tolerance as a first-class harness (the
 chaosmonkey in test/e2e/chaosmonkey + the disruptive e2e suites), but its
 faults all live at the CLUSTER layer — pods die, nodes go dark, leaders
 crash.  A TPU control plane has a second failure domain the reference never
-had: the accelerator itself.  A tunnel-attached device can time out, come
-back garbled, slow to a crawl, or vanish ("device lost"), and each of those
+had: the accelerator itself.  A device can time out, come back garbled,
+slow to a crawl, or vanish ("device lost"), and each of those
 deserves a different response from the scheduling loop:
 
   transient   retry the SAME in-flight batch with jittered backoff — the
@@ -314,7 +314,7 @@ class FaultInjector:
         else:
             err = TransientDeviceError(
                 f"injected transient XLA error at {site} (fire #{a.fired}): "
-                "UNAVAILABLE: fabric tunnel reset"
+                "UNAVAILABLE: device link reset"
             )
         err.device_index = hit
         raise err

@@ -410,37 +410,50 @@ def test_express_width_does_not_grow_sticky_dims():
 def test_compile_cache_knob(tmp_path, monkeypatch):
     from kubernetes_tpu.utils import compilecache as cc
 
-    # every resolved directory carries the topology partition tag
-    # (ISSUE 9: a cache written single-chip is never served to a sharded
-    # process); under the 8-virtual-device test mesh the tag is cpu-d8
-    tag = cc.topology_tag()
-    assert tag.startswith("cpu")
-
-    # precedence: explicit arg > env > default; "off" disables
-    monkeypatch.delenv(cc.CACHE_DIR_ENV, raising=False)
-    assert cc.resolve_cache_dir(None) == os.path.join(
-        cc.DEFAULT_CACHE_DIR, tag
-    )
-    monkeypatch.setenv(cc.CACHE_DIR_ENV, str(tmp_path / "env"))
-    assert cc.resolve_cache_dir(None) == os.path.join(
-        str(tmp_path / "env"), tag
-    )
-    assert cc.resolve_cache_dir(str(tmp_path / "arg")) == os.path.join(
-        str(tmp_path / "arg"), tag
+    # precedence: JAX_COMPILATION_CACHE_DIR > explicit arg > the fixed
+    # default inside the checkout; "off" disables; no subdirectory
+    monkeypatch.delenv(cc.JAX_CACHE_ENV, raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert cc.DEFAULT_CACHE_DIR == os.path.join(repo, ".jax_cache")
+    assert cc.resolve_cache_dir(None) == cc.DEFAULT_CACHE_DIR
+    assert cc.resolve_cache_dir(str(tmp_path / "arg")) == str(
+        tmp_path / "arg"
     )
     assert cc.resolve_cache_dir("off") is None
-    monkeypatch.setenv(cc.CACHE_DIR_ENV, "off")
-    assert cc.resolve_cache_dir(None) is None
-    monkeypatch.delenv(cc.CACHE_DIR_ENV, raising=False)
+    monkeypatch.setenv(cc.JAX_CACHE_ENV, str(tmp_path / "env"))
+    assert cc.resolve_cache_dir(None) == str(tmp_path / "env")
+    assert cc.resolve_cache_dir(str(tmp_path / "arg")) == str(
+        tmp_path / "env"
+    )
+    assert cc.resolve_cache_dir("off") == str(tmp_path / "env")
+    monkeypatch.delenv(cc.JAX_CACHE_ENV, raising=False)
 
     import jax
 
     prev = jax.config.jax_compilation_cache_dir
     try:
         d = cc.enable_compile_cache(str(tmp_path / "cache"))
-        assert d == os.path.join(str(tmp_path / "cache"), tag)
+        assert d == str(tmp_path / "cache")
         assert os.path.isdir(d)
         assert jax.config.jax_compilation_cache_dir == d
         assert cc.enable_compile_cache("off") is None
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_compile_cache_jax_env_dir_used_exactly(tmp_path, monkeypatch):
+    """With JAX_COMPILATION_CACHE_DIR set, the cache is that exact
+    directory: whatever the explicit knob says, and with nothing added."""
+    import jax
+
+    from kubernetes_tpu.utils import compilecache as cc
+
+    want = str(tmp_path / "placed")
+    monkeypatch.setenv(cc.JAX_CACHE_ENV, want)
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        assert cc.enable_compile_cache(str(tmp_path / "knob")) == want
+        assert jax.config.jax_compilation_cache_dir == want
+        assert os.listdir(tmp_path) == ["placed"]
     finally:
         jax.config.update("jax_compilation_cache_dir", prev)

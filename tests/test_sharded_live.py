@@ -430,19 +430,14 @@ def test_component_config_plumbs_shard_knobs():
     assert KubeSchedulerConfiguration.from_dict({}).shard_devices == 0
 
 
-def test_compile_cache_topology_partitions(tmp_path):
-    """A cache written single-chip is never served to a sharded process
-    (and vice versa): the mesh extra lands in the directory tag."""
+def test_compile_cache_one_dir_for_every_topology(tmp_path, monkeypatch):
+    """Single-chip and sharded processes share one cache directory: jax's
+    own key hashes the device assignment, so their executables never
+    serve each other, and the directory stays where it was placed."""
     from kubernetes_tpu.utils import compilecache as cc
 
     base = str(tmp_path / "cache")
-    plain = cc.resolve_cache_dir(base)
-    mesh8 = cc.resolve_cache_dir(base, topology=cc.topology_tag("mesh8"))
-    mesh2x4 = cc.resolve_cache_dir(base, topology=cc.topology_tag("mesh2x4"))
-    assert len({plain, mesh8, mesh2x4}) == 3
-    for d in (plain, mesh8, mesh2x4):
-        assert d.startswith(base)
-    # same topology resolves stably (warm restarts hit the same dir)
-    assert mesh8 == cc.resolve_cache_dir(
-        base, topology=cc.topology_tag("mesh8")
-    )
+    monkeypatch.delenv(cc.JAX_CACHE_ENV, raising=False)
+    assert cc.resolve_cache_dir(base) == base
+    monkeypatch.setenv(cc.JAX_CACHE_ENV, base)
+    assert cc.resolve_cache_dir(None) == base

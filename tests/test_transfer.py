@@ -177,7 +177,7 @@ def test_async_fetch_routes_job_error_into_handle():
 
     class Evil:
         def __array__(self, *a, **k):
-            raise RuntimeError("UNAVAILABLE: tunnel reset")
+            raise RuntimeError("UNAVAILABLE: device link reset")
 
     f = AsyncFetch(Evil())
     with np.testing.assert_raises(RuntimeError):
