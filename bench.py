@@ -758,15 +758,16 @@ def run_live(args, batched: bool = True, pipeline: bool = True) -> dict:
     # measured on the fetch worker (codec/transfer.AsyncFetch), so under
     # the pipelined commit it overlaps the pop/commit host phases and
     # the PHASE SUM exceeds wall clock — efficiency > 1.0 is the async
-    # result path working, == 1.0 is fully serial.  fetch_block is a
-    # SUBSET of the fetch window (the part the host actually waited on),
-    # so it is excluded from the sum to avoid double counting.
-    # (host_stall and fetch_block are lockstep ALIASES of the same fence
-    # wait — subtract both so the stall is excluded exactly once)
+    # result path working, == 1.0 is fully serial.  host_stall is the
+    # part of the fetch window the host actually waited on, so the sum
+    # takes the host phases that partition the cycle WITHOUT it, plus
+    # fetch.
+    from kubernetes_tpu.runtime.scheduler import PARTITION_PHASES
+
     phase_sum = (
-        sum(sched.phase_seconds.values())
-        - sched.phase_seconds["fetch_block"]
-        - sched.phase_seconds.get("host_stall", 0.0)
+        sum(sched.phase_seconds[k] for k in PARTITION_PHASES
+            if k != "host_stall")
+        + sched.phase_seconds["fetch"]
         + t_enqueue
     )
     # ---- cluster_health stage (ISSUE 8): the fleet-state analytics the

@@ -33,6 +33,7 @@ import numpy as np
 
 from kubernetes_tpu.codec import faults
 from kubernetes_tpu.codec.schema import _pow2
+from kubernetes_tpu.utils.trace import annotation
 
 # ------------------------------------------------------ transfer accounting
 #
@@ -105,22 +106,6 @@ def transfer_delta(prev: "dict[str, dict]") -> "dict[str, dict]":
     return out
 
 
-def device_annotation(name: str):
-    """Optional jax.profiler annotation around a device-path section:
-    when a real accelerator backend is active (and a jax profiler trace
-    is being captured) the named range shows up in the device timeline,
-    composing with the host-side spans (utils/trace.py).  On the CPU
-    backend — the tier-1 path — this is a zero-cost no-op, so callers
-    can wrap hot sections unconditionally."""
-    import contextlib
-
-    if jax.default_backend() == "cpu":
-        return contextlib.nullcontext()
-    try:
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:  # profiler unavailable on this backend build
-        return contextlib.nullcontext()
-
 # ---------------------------------------------------------------- D2H fences
 #
 # Every device->host materialization the RUNTIME performs goes through the
@@ -180,7 +165,7 @@ def host_fetch(x, tag: str = "fetch") -> np.ndarray:
     observable."""
     _note_sync(tag)
     faults.check(faults.SITE_FETCH, devices=_involved_device_ids(x))
-    with device_annotation(f"ktpu.{tag}"):
+    with annotation(f"ktpu.{tag}"):
         out = faults.corrupt(faults.SITE_FETCH, np.asarray(x))
     note_transfer("d2h", tag, out.nbytes)
     return out
@@ -268,10 +253,19 @@ class AsyncFetch:
     result() is the ready-fence: it returns the host array, blocking only
     if the copy hasn't landed yet (and only that case is reported to the
     sync listeners); a device error re-raises HERE, so callers own the
-    batch's recovery at the fence.  `seconds` is the device-side window
-    from dispatch to copy-complete — the honest "fetch" phase cost, which
-    may overlap other host phases (bench.py's overlap-efficiency figure
-    divides wall clock by the sum of such phases)."""
+    batch's recovery at the fence.
+
+    The worker stamps four instants on the scheduler's clock
+    (time.monotonic): `t_submit` (dispatch), `t_start` (the worker took
+    the job), `t_ready` (block_until_ready returned: the computation is
+    done) and `t_done` (the host copy landed).  Every duration is read
+    from them: `seconds` (dispatch -> copy landed, the "fetch" phase,
+    which may overlap other host phases), `execute_seconds` and
+    `materialize_seconds` (the perf observatory's device split), and
+    `fence_split` (what a fence wait on this handle was waiting for).
+    The worker's own profiler range is `fetchworker.<tag>`: outside the
+    `ktpu.` prefix, so a worker-thread range never names the
+    scheduling thread's time."""
 
     def __init__(self, dev, tag: str = "fetch") -> None:
         self._dev = dev
@@ -285,42 +279,44 @@ class AsyncFetch:
         self._done = threading.Event()
         self._out: Any = None
         self._err: Any = None
-        self.seconds = 0.0
-        # the host/device attribution split (ISSUE 11), stamped by the
-        # ready fences in _run: execute = dispatch -> computation ready
-        # (the honest device-execute window), materialize = the residual
-        # D2H landing after the result was ready (with the async copy
-        # prefetch this is usually ~0).  execute + materialize <= seconds
-        # (the worker also pays queueing before the fence).
-        self.execute_seconds = 0.0
-        self.materialize_seconds = 0.0
-        self._t0 = time.monotonic()
+        self.t_submit = time.monotonic()
+        self.t_start = self.t_ready = self.t_done = self.t_submit
         _fetch_worker().submit(self._run)
 
     def _run(self) -> None:
+        self.t_start = time.monotonic()
         try:
             faults.check(faults.SITE_FETCH, devices=self._devices)
-            with device_annotation(f"ktpu.{self._tag}"):
-                t_wait0 = time.monotonic()
+            with annotation(f"fetchworker.{self._tag}"):
                 wait = getattr(self._dev, "block_until_ready", None)
                 if wait is not None:
                     # ready fence BEFORE the materialize: splits "device
                     # still computing" from "host copying" (a failed
                     # computation raises here exactly as np.asarray would)
                     wait()
-                self.execute_seconds = time.monotonic() - t_wait0
+                self.t_ready = time.monotonic()
                 self._out = faults.corrupt(
                     faults.SITE_FETCH, np.asarray(self._dev)
-                )
-                self.materialize_seconds = (
-                    time.monotonic() - t_wait0 - self.execute_seconds
                 )
             note_transfer("d2h", self._tag, self._out.nbytes)
         except BaseException as e:  # noqa: BLE001 — re-raised in result()
             self._err = e
         finally:
-            self.seconds = time.monotonic() - self._t0
+            self.t_done = time.monotonic()
+            self.t_ready = max(self.t_ready, self.t_start)
             self._done.set()
+
+    @property
+    def seconds(self) -> float:
+        return self.t_done - self.t_submit
+
+    @property
+    def execute_seconds(self) -> float:
+        return self.t_ready - self.t_start
+
+    @property
+    def materialize_seconds(self) -> float:
+        return self.t_done - self.t_ready
 
     def ready(self) -> bool:
         """Non-blocking fence probe: has the host copy landed?"""
@@ -338,6 +334,22 @@ class AsyncFetch:
         if self._err is not None:
             raise self._err
         return self._out
+
+
+def fence_split(fetch, t0: float, t1: float) -> Tuple[float, float, float]:
+    """A fence wait [t0, t1] on `fetch`, split by what the fetch worker
+    was doing: (queue, device, copy) = the overlap with [.., t_start)
+    (the worker had not started this job), with [t_start, t_ready) (the
+    result was not ready), and the rest (the D2H copy landing, and the
+    host checks after it).  The three add up to t1 - t0.  A handle
+    without stamps (a host-computed result) puts the whole wait in copy."""
+    t_start = getattr(fetch, "t_start", None)
+    if t_start is None or t1 <= t0:
+        return 0.0, 0.0, t1 - t0
+    queue = max(0.0, min(t1, t_start) - t0)
+    device = max(0.0, min(t1, fetch.t_ready) - max(t0, t_start))
+    return queue, device, (t1 - t0) - queue - device
+
 
 _GROUPS = ("f", "i", "b")
 _HOST_DTYPE = {"f": np.float32, "i": np.int32, "b": np.bool_}
@@ -712,7 +724,7 @@ class DeviceSnapshotCache:
                 "h2d", "snapshot_upload",
                 sum(staged[n].nbytes for n in changed),
             )
-            with device_annotation("ktpu.snapshot_upload"):
+            with annotation("ktpu.snapshot_upload"):
                 if self._mesh is not None:
                     uploaded = jax.device_put(
                         [staged[n] for n in changed],

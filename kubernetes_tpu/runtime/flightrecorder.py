@@ -27,7 +27,11 @@ import time
 from collections import deque
 from typing import Callable, Dict, List, Optional
 
-from kubernetes_tpu.utils.trace import Span, chrome_trace
+from kubernetes_tpu.utils.trace import (
+    Span,
+    chrome_trace,
+    profiler_clock_offset,
+)
 
 
 class FlightRecorder:
@@ -59,8 +63,10 @@ class FlightRecorder:
     # ------------------------------------------------------------ recording
 
     def record(self, span: Span) -> None:
-        """Retire one finished cycle span into the ring (O(1), the
-        always-on cost — no serialization happens here)."""
+        """Retire one cycle span into the ring (O(1), the always-on
+        cost — no serialization happens here).  The scheduler records a
+        cycle before its observe hooks run and finishes the span after
+        them, so a postmortem fired by a hook already sees the cycle."""
         with self._lock:
             self._ring.append(span)
             self.recorded_total += 1
@@ -151,6 +157,7 @@ class FlightRecorder:
         if limit is not None and limit >= 0:
             spans = spans[-limit:] if limit else []
         out = chrome_trace(spans)
+        offset = profiler_clock_offset()
         with self._lock:
             pms = list(self._postmortems)
         for pm in pms:
@@ -159,7 +166,7 @@ class FlightRecorder:
                 "cat": "ktpu.anomaly",
                 "ph": "i",
                 "s": "g",  # global-scope instant: draws across all tracks
-                "ts": int(pm["monotonic"] * 1e6),
+                "ts": int((pm["monotonic"] + offset) * 1e6),
                 "pid": 1,
                 "tid": 1,
                 "args": {"detail": pm["detail"]},

@@ -14,12 +14,13 @@ learned-scoring loop (item 4) need.  This module is that instrument:
       - `host_enqueue`     encode + extender fan-out + launch enqueue
                            (scheduling-thread Python until the dispatch
                            returned with the device still computing)
-      - `device_execute`   dispatch -> computation-ready, stamped by the
-                           block_until_ready fence on the fetch worker
-      - `d2h_materialize`  the residual host copy after ready (with the
-                           async copy prefetch this is usually ~0)
+      - `device_execute`   fetch-worker job start -> computation ready
+                           (AsyncFetch's t_start / t_ready stamps)
+      - `d2h_materialize`  the residual host copy after ready (t_ready ->
+                           t_done; with the async copy prefetch this is
+                           usually ~0)
       - `host_stall`       the scheduling thread's residual wait at the
-                           ready fence (phase_seconds' fetch_block)
+                           ready fence (phase_seconds' host_stall)
       - `host_commit`      state commit + bind/event tail + ledger +
                            telemetry (the full host tail)
 
@@ -41,9 +42,9 @@ learned-scoring loop (item 4) need.  This module is that instrument:
     jax.profiler start/stop in a throttled, bounded window into a
     configurable directory — `GET /debug/profile?seconds=N` on the
     health server and the apiserver.  Where the backend lacks profiler
-    support the capture degrades to a graceful no-op.  The PR 5
-    `device_annotation` labels (ktpu.fetch / ktpu.snapshot_upload / …)
-    make the captured device timeline phase-legible.
+    support the capture degrades to a graceful no-op.  The scheduler's
+    `ktpu.<phase>` ranges (utils/trace.PhaseSpan) make the captured
+    device timeline phase-legible.
 
 `OBSERVATORY`/`get_default`/`set_default` follow the flightrecorder
 RECORDER pattern: a Scheduler installs its observatory as the process

@@ -49,7 +49,7 @@ from kubernetes_tpu.codec.faults import (
     classify_device_error,
 )
 from kubernetes_tpu.codec import faults as device_faults
-from kubernetes_tpu.codec.transfer import AsyncFetch, host_fetch
+from kubernetes_tpu.codec.transfer import AsyncFetch, fence_split, host_fetch
 from kubernetes_tpu.ops.predicates import filter_batch, required_affinity_ok
 from kubernetes_tpu.runtime.cache import SchedulerCache
 from kubernetes_tpu.runtime.health import DeviceHealth
@@ -67,9 +67,35 @@ from kubernetes_tpu.runtime.queue import (
 )
 from kubernetes_tpu.utils import klog
 from kubernetes_tpu.utils import metrics as m
-from kubernetes_tpu.utils.trace import Span, current_trace_id, use_traceparent
+from kubernetes_tpu.utils.compilecache import (
+    install_metrics_listeners,
+    program_loads,
+)
+from kubernetes_tpu.utils.trace import (
+    WORKER_LANE,
+    PhaseSpan,
+    Span,
+    current_trace_id,
+    use_traceparent,
+)
 
 TAINT_NODE_UNSCHEDULABLE = "node.kubernetes.io/unschedulable"
+
+# the phase_seconds keys that partition run_once's wall time: every
+# second of a cycle lands in exactly one of them (tests/test_tracing.py
+# pins the sum against the wall).  The other keys overlap them:
+#   fetch         dispatch -> host copy landed, on the fetch worker
+#   fence_queue   } host_stall split by what the fetch worker was doing:
+#   fence_device  } not started on this job / result not ready / the
+#   fence_copy    } copy landing and the checks after it (they sum to it)
+#   program_load  backend compiles + persistent-cache retrievals
+PARTITION_PHASES = (
+    "pop", "encode", "dispatch", "host_stall", "commit", "preempt",
+    "observe",
+)
+OVERLAP_PHASES = (
+    "fetch", "fence_queue", "fence_device", "fence_copy", "program_load",
+)
 
 
 @dataclass
@@ -579,8 +605,7 @@ class _Staged:
     winners: List[Tuple] = field(default_factory=list)
     fit_idx: List[int] = field(default_factory=list)
     # residual host wait at the ready fence (host_stall in the perf
-    # observatory's cost model — the same window as the fetch_block
-    # phase counter)
+    # observatory's cost model and in phase_seconds)
     stall_s: float = 0.0
     # THIS cycle's wire traffic (codec.transfer.transfer_delta vs the
     # encode-time watermark), taken at the commit fence — under
@@ -929,22 +954,25 @@ class Scheduler:
         # in-flight span in postmortem snapshots (an express-cycle anomaly
         # reads differently from a bulk one)
         self._cur_tier: str = TIER_BULK
-        # per-phase seconds, cumulative (bench live-path reporting):
-        # pop (queue drain — under pipeline_commit this overlaps the
-        # previous batch's in-flight fetch), encode (host tensors +
-        # snapshot), dispatch (async enqueue), fetch (device compute +
-        # D2H, measured on the async-fetch worker — overlaps other
-        # phases), host_stall (residual host wait at the ready-fence —
-        # the perf observatory's name for the same window; a SUBSET of
-        # fetch, so phase sums must skip it; "fetch_block" is kept as a
-        # legacy ALIAS that moves in lockstep so /debug/perf and
-        # detail.phases reconcile exactly, ISSUE 12 satellite), commit
-        # (assume + bind + events + requeues), preempt
+        # per-phase seconds, cumulative (bench reporting, the
+        # benchmark's per-layer metrics).  PARTITION_PHASES split
+        # run_once's wall time: pop (queue drain, including its wait),
+        # encode (host tensors + snapshot + extender fan-out), dispatch
+        # (async enqueue), host_stall (the scheduling thread's wait at
+        # the ready fence — the perf observatory's name), commit
+        # (assume + bind + events + requeues), preempt, observe (the
+        # post-bind hooks: flight recorder, ledger, telemetry, perfobs,
+        # quality, capacity, timeline, slow-cycle log, and run_once's
+        # heartbeat + idle timeline tick).  OVERLAP_PHASES are subsets
+        # or overlaps of those and never add into a sum of them.
         self.phase_seconds: Dict[str, float] = {
-            "pop": 0.0, "encode": 0.0, "dispatch": 0.0, "fetch": 0.0,
-            "host_stall": 0.0, "fetch_block": 0.0, "commit": 0.0,
-            "preempt": 0.0,
+            k: 0.0 for k in PARTITION_PHASES + OVERLAP_PHASES
         }
+        # program loads (utils/compilecache.py), folded per cycle: the
+        # listeners are installed here so they are live whoever set up
+        # the cache, and the watermark is reset at every run_once
+        install_metrics_listeners()
+        self._loads_seen = program_loads()
         # always-on cycle-span ring + anomaly postmortems (ISSUE 5); the
         # default is the process-wide recorder served at /debug/traces
         self.flight_recorder = (
@@ -1173,13 +1201,52 @@ class Scheduler:
         """One accumulation point for per-phase seconds: the driver-
         visible phase_seconds dict (bench reporting, tiers aggregated)
         AND the tier-labeled /metrics counter family move together.
-        "host_stall" (the perfobs vocabulary for the fence wait) also
-        feeds the legacy "fetch_block" alias, so the two dict entries
-        can never drift — the metric family carries only host_stall."""
+        Timed phases reach it through _phase_span; the derived keys
+        (fetch, fence_*, program_load) directly."""
         self.phase_seconds[name] += dt
-        if name == "host_stall":
-            self.phase_seconds["fetch_block"] += dt
         m.CYCLE_PHASE_SECONDS.inc(dt, phase=name, tier=tier)
+
+    def _phase_span(self, span: Optional[Span], name: str,
+                    tier: str = TIER_BULK, child: Optional[str] = None,
+                    **attrs) -> PhaseSpan:
+        """THE phase primitive (utils/trace.PhaseSpan): phase counter,
+        cycle-span child (named `child`, default the phase) and, while
+        a profiler session is active, a `ktpu.<name>` annotation."""
+        return PhaseSpan(self._phase, name, tier, span, child, **attrs)
+
+    def _fence_split(self, fetch, fence: PhaseSpan, tier: str) -> None:
+        """Split one fence wait (fence.t0..t1, already in host_stall) by
+        what the fetch worker was doing, and hang the worker's own
+        stamps on the cycle span: device_wait (job start -> result
+        ready) and d2h (ready -> copy landed)."""
+        q, d, c = fence_split(fetch, fence.t0, fence.t1)
+        self._phase("fence_queue", q, tier)
+        self._phase("fence_device", d, tier)
+        self._phase("fence_copy", c, tier)
+        t_start = getattr(fetch, "t_start", None)
+        if t_start is not None and fence.parent is not None:
+            fence.parent.add_child(
+                "device_wait", t_start, fetch.t_ready, lane=WORKER_LANE
+            )
+            fence.parent.add_child(
+                "d2h", fetch.t_ready, fetch.t_done, lane=WORKER_LANE
+            )
+
+    def _fold_program_loads(self, span: Optional[Span],
+                            tier: str = TIER_BULK) -> None:
+        """Fold the program loads since the last fold into
+        phase_seconds["program_load"], and count them on the cycle
+        span (`program_loads`), so /debug/traces shows which cycle
+        loaded a program."""
+        n, secs = program_loads()
+        n0, secs0 = self._loads_seen
+        self._loads_seen = (n, secs)
+        if n != n0:
+            self._phase("program_load", secs - secs0, tier)
+        if span is not None:
+            span.annotate(
+                program_loads=span.attrs.get("program_loads", 0) + n - n0
+            )
 
     def _postmortem(self, trigger: str, detail: str = "") -> None:
         """Dump a flight-recorder postmortem for one anomaly trigger
@@ -1715,125 +1782,134 @@ class Scheduler:
         pre-compiled program) instead of the bulk lane's sticky width."""
         if not pods:
             return None
-        t_cycle0 = time.monotonic()
-        # transfer watermark BEFORE any device work: the commit tail
-        # diffs against it so the cycle's sample/span carry exactly the
-        # bytes THIS cycle moved (codec/transfer.py accounting)
-        from kubernetes_tpu.codec.transfer import transfer_totals
+        with self._phase_span(None, "encode", tier) as encode:
+            t_cycle0 = encode.t0
+            # transfer watermark BEFORE any device work: the commit
+            # tail diffs against it so the cycle's sample/span carry
+            # exactly the bytes THIS cycle moved (codec/transfer.py)
+            from kubernetes_tpu.codec.transfer import transfer_totals
 
-        xfer0 = transfer_totals()
-        enc = self.cache.encoder
-        cycle = self.queue.scheduling_cycle
-        express_width = (
-            self.config.express_batch_size if tier == TIER_EXPRESS else None
-        )
-        # the cycle's ROOT span: one fresh trace id per cycle, child spans
-        # per phase, annotated with the device-path facts (batch width,
-        # dirty rows, breaker state, retry class) — retired into the
-        # flight recorder when the commit tail finishes
-        trace = Span(
-            "schedule_cycle", start=t_cycle0, pods=len(pods), cycle=cycle,
-            tier=tier,
-        )
-        self._cur_span = trace
-        self._cur_tier = tier
-        enc_span = trace.child("encode")
-        batch_keys = {(p.namespace, p.name) for p in pods}
-        # engine choice is made BEFORE the encode so degraded cycles leave
-        # the encoder's dirty-row stream unconsumed (the device cache isn't
-        # listening; it is invalidated on trip and rebuilt on recovery).
-        # allow_device() may transition open -> half_open: the canary.
-        use_device = (
-            self.device_health.allow_device()
-            if self.config.cpu_fallback
-            else True
-        )
-        with self.cache._lock, enc.batch_width(express_width):
-            # in-batch affinity state when pods carry ANY pod-affinity terms
-            # (required or preferred) AND can interact (B > 1); built BEFORE
-            # encode_pods so novel term topology keys register (and possibly
-            # grow the pair vocabulary) before any TP-wide tensor is cut
-            aff_state = (
-                encode_batch_affinity(enc, pods)
-                if len(pods) > 1 and batch_has_pod_affinity(pods)
+            xfer0 = transfer_totals()
+            enc = self.cache.encoder
+            cycle = self.queue.scheduling_cycle
+            express_width = (
+                self.config.express_batch_size if tier == TIER_EXPRESS
                 else None
             )
-            batch = enc.encode_pods(pods)
-            ports = encode_batch_ports(enc, pods)
-            # two-pass evaluation: nominated pods (other than those being
-            # scheduled now) are added to their nominated nodes in pass one
-            nominated_pairs = [
-                (p, n)
-                for p, n in self.queue.nominated_pods()
-                if (p.namespace, p.name) not in batch_keys
-            ]
-            nominated = encode_nominated(enc, nominated_pairs)
-            cluster, generation = self.cache.snapshot()
-            # rows the incremental snapshot refreshed: lets the device
-            # cache scatter-update just those rows instead of re-shipping
-            # whole tensors (codec/transfer.py); taken under the lock so
-            # the row set corresponds exactly to THIS snapshot.  In hub
-            # mode the dirty stream's SINGLE consumer is the hub itself
-            # (its refresh() takes under the cache lock) — a replica
-            # taking here would starve its siblings' resident state.
-            dirty_rows = (
-                enc.take_dirty_rows()
-                if use_device and self._hub is None else None
+            # the cycle's ROOT span: one fresh trace id per cycle, child
+            # spans per phase, annotated with the device-path facts (batch
+            # width, dirty rows, breaker state, retry class) — retired
+            # into the flight recorder when the commit tail finishes
+            trace = encode.parent = Span(
+                "schedule_cycle", start=t_cycle0, pods=len(pods),
+                cycle=cycle, tier=tier,
             )
-            # ports + anti-affinity contributions of nominated pods (the
-            # non-resource half of podFitsOnNode's pass one) as a host
-            # mask folded into extra_mask below
-            nom_block = encode_nominated_block(
-                enc, nominated_pairs, pods, batch.n_pods, cluster.n_nodes,
+            self._cur_span = trace
+            self._cur_tier = tier
+            batch_keys = {(p.namespace, p.name) for p in pods}
+            # engine choice is made BEFORE the encode so degraded cycles
+            # leave the encoder's dirty-row stream unconsumed (the device
+            # cache isn't listening; it is invalidated on trip and rebuilt
+            # on recovery).  allow_device() may transition open ->
+            # half_open: the canary.
+            use_device = (
+                self.device_health.allow_device()
+                if self.config.cpu_fallback
+                else True
             )
-            # point-in-time name->row map consistent with THIS snapshot;
-            # extender round-trips below run outside the lock, and the live
-            # node_rows dict may be mutated (rows recycled/regrown) meanwhile
-            node_row_map = dict(enc.node_rows)
-        enc_span.finish()
-        fwk = self.framework
-        pc = None
-        extra_mask = extra_score = None
-        if fwk is not None:
-            # ONE PluginContext per cycle, shared across every extension
-            # point (the CycleState pattern: a plugin computes at the tensor
-            # Filter point and consumes at Prebind)
-            from kubernetes_tpu.framework.v1alpha1 import PluginContext
+            with self.cache._lock, enc.batch_width(express_width):
+                # in-batch affinity state when pods carry ANY pod-affinity
+                # terms (required or preferred) AND can interact (B > 1);
+                # built BEFORE encode_pods so novel term topology keys
+                # register (and possibly grow the pair vocabulary) before
+                # any TP-wide tensor is cut
+                aff_state = (
+                    encode_batch_affinity(enc, pods)
+                    if len(pods) > 1 and batch_has_pod_affinity(pods)
+                    else None
+                )
+                batch = enc.encode_pods(pods)
+                ports = encode_batch_ports(enc, pods)
+                # two-pass evaluation: nominated pods (other than those
+                # being scheduled now) are added to their nominated nodes
+                # in pass one
+                nominated_pairs = [
+                    (p, n)
+                    for p, n in self.queue.nominated_pods()
+                    if (p.namespace, p.name) not in batch_keys
+                ]
+                nominated = encode_nominated(enc, nominated_pairs)
+                cluster, generation = self.cache.snapshot()
+                # rows the incremental snapshot refreshed: lets the
+                # device cache scatter-update just those rows instead of
+                # re-shipping whole tensors (codec/transfer.py); taken
+                # under the lock so the row set corresponds exactly to
+                # THIS snapshot.  In hub mode the dirty stream's SINGLE
+                # consumer is the hub itself (its refresh() takes under
+                # the cache lock) — a replica taking here would starve
+                # its siblings' resident state.
+                dirty_rows = (
+                    enc.take_dirty_rows()
+                    if use_device and self._hub is None else None
+                )
+                # ports + anti-affinity contributions of nominated pods
+                # (the non-resource half of podFitsOnNode's pass one) as a
+                # host mask folded into extra_mask below
+                nom_block = encode_nominated_block(
+                    enc, nominated_pairs, pods, batch.n_pods,
+                    cluster.n_nodes,
+                )
+                # point-in-time name->row map consistent with THIS
+                # snapshot; extender round-trips below run outside the
+                # lock, and the live node_rows dict may be mutated (rows
+                # recycled/regrown) meanwhile
+                node_row_map = dict(enc.node_rows)
+            fwk = self.framework
+            pc = None
+            extra_mask = extra_score = None
+            if fwk is not None:
+                # ONE PluginContext per cycle, shared across every
+                # extension point (the CycleState pattern: a plugin
+                # computes at the tensor Filter point and consumes at
+                # Prebind)
+                from kubernetes_tpu.framework.v1alpha1 import PluginContext
 
-            pc = PluginContext()
-        if fwk is not None and (fwk.tensor_filter_plugins or fwk.tensor_score_plugins):
-            B, N = batch.n_pods, cluster.n_nodes
-            if fwk.tensor_filter_plugins:
-                extra_mask = np.asarray(
-                    fwk.run_filter_tensor(pc, cluster, batch, np.ones((B, N), bool))
+                pc = PluginContext()
+            if fwk is not None and (
+                fwk.tensor_filter_plugins or fwk.tensor_score_plugins
+            ):
+                B, N = batch.n_pods, cluster.n_nodes
+                if fwk.tensor_filter_plugins:
+                    extra_mask = np.asarray(fwk.run_filter_tensor(
+                        pc, cluster, batch, np.ones((B, N), bool)
+                    ))
+                if fwk.tensor_score_plugins:
+                    extra_score = np.asarray(
+                        fwk.run_score_tensor(
+                            pc, cluster, batch, np.zeros((B, N), np.float32)
+                        ),
+                        np.float32,
+                    )
+            ext_failed: Dict[int, str] = {}
+            # bind-/preempt-only extenders don't participate in
+            # filter/score; skip the fan-out (and keep extra_mask None)
+            # when none do
+            if any(
+                e.config.filter_verb or e.config.prioritize_verb
+                for e in self.extenders
+            ):
+                ext_span = trace.child("extenders", n=len(self.extenders))
+                extra_mask, extra_score, ext_failed = self._apply_extenders(
+                    pods, node_row_map, cluster, extra_mask, extra_score,
+                    n_rows=batch.n_pods, trace_ctx=trace.traceparent(),
                 )
-            if fwk.tensor_score_plugins:
-                extra_score = np.asarray(
-                    fwk.run_score_tensor(
-                        pc, cluster, batch, np.zeros((B, N), np.float32)
-                    ),
-                    np.float32,
+                ext_span.finish()
+            if nom_block is not None:
+                # pass-one infeasibility from nominated ports/anti-affinity
+                extra_mask = (
+                    ~nom_block if extra_mask is None
+                    else (extra_mask & ~nom_block)
                 )
-        ext_failed: Dict[int, str] = {}
-        # bind-/preempt-only extenders don't participate in filter/score;
-        # skip the fan-out (and keep extra_mask None) when none do
-        if any(
-            e.config.filter_verb or e.config.prioritize_verb
-            for e in self.extenders
-        ):
-            ext_span = trace.child("extenders", n=len(self.extenders))
-            extra_mask, extra_score, ext_failed = self._apply_extenders(
-                pods, node_row_map, cluster, extra_mask, extra_score,
-                n_rows=batch.n_pods, trace_ctx=trace.traceparent(),
-            )
-            ext_span.finish()
-        if nom_block is not None:
-            # pass-one infeasibility from nominated ports/anti-affinity
-            extra_mask = (
-                ~nom_block if extra_mask is None else (extra_mask & ~nom_block)
-            )
-        t_disp = time.monotonic()
-        self._phase("encode", t_disp - t_cycle0, tier)
         fn = self._schedule_fn
         if self._speculative_fn is not None:
             fn = self._speculative_fn
@@ -1929,37 +2005,37 @@ class Scheduler:
                 )
             return _HostResult(hosts, seconds=time.monotonic() - t0)
 
-        degraded = False
-        hosts_dev = attrib_dev = quality_dev = None
-        disp_span = trace.child("dispatch")
-        if use_device:
-            launched = self._launch_resilient(launch)
-        else:
-            launched = None
-        if launched is None:
-            # breaker open (or dispatch gave up): degraded CPU cycle
-            degraded = True
-            m.DEGRADED_CYCLES.inc()
-            self._postmortem(
-                "degraded_cycle",
-                "breaker open at dispatch" if not use_device
-                else "dispatch gave up on the device",
+        with self._phase_span(trace, "dispatch", tier) as dispatch:
+            degraded = False
+            hosts_dev = attrib_dev = quality_dev = None
+            if use_device:
+                launched = self._launch_resilient(launch)
+            else:
+                launched = None
+            if launched is None:
+                # breaker open (or dispatch gave up): degraded CPU cycle
+                degraded = True
+                m.DEGRADED_CYCLES.inc()
+                self._postmortem(
+                    "degraded_cycle",
+                    "breaker open at dispatch" if not use_device
+                    else "dispatch gave up on the device",
+                )
+                fetch = cpu_fetch()
+            else:
+                hosts_dev, fetch, attrib_dev, quality_dev = launched
+            self._last_index += len(pods)
+            trace.annotate(
+                batch=len(pods),
+                dirty_rows=len(dirty_rows) if dirty_rows is not None else -1,
+                breaker=self.device_health.state,
+                degraded=degraded,
+                engine="cpu" if degraded else self._engine_kind,
+                shards=self.mesh.size if self.mesh is not None else 0,
             )
-            fetch = cpu_fetch()
-        else:
-            hosts_dev, fetch, attrib_dev, quality_dev = launched
-        self._last_index += len(pods)
-        disp_span.finish()
-        trace.annotate(
-            batch=len(pods),
-            dirty_rows=len(dirty_rows) if dirty_rows is not None else -1,
-            breaker=self.device_health.state,
-            degraded=degraded,
-            engine="cpu" if degraded else self._engine_kind,
-            shards=self.mesh.size if self.mesh is not None else 0,
-        )
-        t_disp_end = time.monotonic()
-        self._phase("dispatch", t_disp_end - t_disp, tier)
+            # programs the launch loaded (a new width compiles here)
+            self._fold_program_loads(trace, tier)
+        t_disp_end = dispatch.t1
         # hub mode: the launch refreshed to the newest resident state —
         # inf carries the generation/cluster the engine ACTUALLY saw
         # (the reconciler's fast-path fence and the ledger's truth)
@@ -2121,24 +2197,25 @@ class Scheduler:
             else self.config.megacycle_batches,
             self.config.megacycle_batches,
         )
-        t_pop = time.monotonic()
         mega_pop_kw = (
             {"shard": self._replica_id, "of": self._replica_of}
             if self._replica_of > 1 else {}
         )
-        while len(windows) < k_target:
-            w = self.queue.pop_batch(width, 0.0, 0.0, **mega_pop_kw)
-            if not w:
-                break
-            if self.invariants is not None:
-                self.invariants.note_popped(w, self.queue.scheduling_cycle)
-            if self._megacycle_safe(w):
-                windows.append(w)
-                cycles.append(self.queue.scheduling_cycle)
-            else:
-                leftovers.append(w)
-                break
-        self._phase("pop", time.monotonic() - t_pop)
+        with self._phase_span(None, "pop"):
+            while len(windows) < k_target:
+                w = self.queue.pop_batch(width, 0.0, 0.0, **mega_pop_kw)
+                if not w:
+                    break
+                if self.invariants is not None:
+                    self.invariants.note_popped(
+                        w, self.queue.scheduling_cycle
+                    )
+                if self._megacycle_safe(w):
+                    windows.append(w)
+                    cycles.append(self.queue.scheduling_cycle)
+                else:
+                    leftovers.append(w)
+                    break
         k_eff = 1 << (len(windows).bit_length() - 1)  # pow2 floor
         leftovers = windows[k_eff:] + leftovers
         windows = windows[:k_eff]
@@ -2156,60 +2233,57 @@ class Scheduler:
         from kubernetes_tpu.models.megacycle import stack_windows
 
         K = len(windows)
-        t_cycle0 = time.monotonic()
-        xfer0 = transfer_totals()
-        enc = self.cache.encoder
-        spans = [
-            Span(
-                "schedule_cycle", start=t_cycle0, pods=len(w),
-                cycle=cycles[k], tier=TIER_BULK, mega=f"{k + 1}/{K}",
-            )
-            for k, w in enumerate(windows)
-        ]
-        self._cur_span = spans[0]
-        self._cur_tier = TIER_BULK
-        enc_span = spans[0].child("encode", windows=K)
-        use_device = (
-            self.device_health.allow_device()
-            if self.config.cpu_fallback
-            else True
-        )
-        with self.cache._lock:
-            batches = [enc.encode_pods(w) for w in windows]
-            shapes = {
-                tuple(
-                    np.asarray(leaf).shape
-                    for leaf in jax.tree_util.tree_leaves(b)
+        with self._phase_span(None, "encode", windows=K) as encode:
+            t_cycle0 = encode.t0
+            xfer0 = transfer_totals()
+            enc = self.cache.encoder
+            spans = [
+                Span(
+                    "schedule_cycle", start=t_cycle0, pods=len(w),
+                    cycle=cycles[k], tier=TIER_BULK, mega=f"{k + 1}/{K}",
                 )
-                for b in batches
-            }
-            if len(shapes) > 1:
-                # a later window grew a sticky pad dim: one more pass
-                # encodes every window at the (now stable) max shapes
-                batches = [enc.encode_pods(w) for w in windows]
-            ports = [encode_batch_ports(enc, w) for w in windows]
-            cluster, generation = self.cache.snapshot()
-            # hub mode: the hub is the dirty stream's single consumer
-            # (see _encode_and_dispatch)
-            dirty_rows = (
-                enc.take_dirty_rows()
-                if use_device and self._hub is None else None
+                for k, w in enumerate(windows)
+            ]
+            self._cur_span = encode.parent = spans[0]
+            self._cur_tier = TIER_BULK
+            use_device = (
+                self.device_health.allow_device()
+                if self.config.cpu_fallback
+                else True
             )
-            node_row_map = dict(enc.node_rows)
-        enc_span.finish()
-        # per-sub-batch rotation bases: base + cumulative RAW pod counts,
-        # exactly what K separate cycles would have seen
-        li0: List[int] = []
-        acc = self._last_index
-        for w in windows:
-            li0.append(acc)
-            acc += len(w)
-        self._last_index = acc
-        li0_arr = np.asarray(li0, np.int32)
-        batch_k = stack_windows(batches)
-        ports_k = stack_windows(ports)
-        t_disp = time.monotonic()
-        self._phase("encode", t_disp - t_cycle0)
+            with self.cache._lock:
+                batches = [enc.encode_pods(w) for w in windows]
+                shapes = {
+                    tuple(
+                        np.asarray(leaf).shape
+                        for leaf in jax.tree_util.tree_leaves(b)
+                    )
+                    for b in batches
+                }
+                if len(shapes) > 1:
+                    # a later window grew a sticky pad dim: one more pass
+                    # encodes every window at the (now stable) max shapes
+                    batches = [enc.encode_pods(w) for w in windows]
+                ports = [encode_batch_ports(enc, w) for w in windows]
+                cluster, generation = self.cache.snapshot()
+                # hub mode: the hub is the dirty stream's single consumer
+                # (see _encode_and_dispatch)
+                dirty_rows = (
+                    enc.take_dirty_rows()
+                    if use_device and self._hub is None else None
+                )
+                node_row_map = dict(enc.node_rows)
+            # per-sub-batch rotation bases: base + cumulative RAW pod
+            # counts, exactly what K separate cycles would have seen
+            li0: List[int] = []
+            acc = self._last_index
+            for w in windows:
+                li0.append(acc)
+                acc += len(w)
+            self._last_index = acc
+            li0_arr = np.asarray(li0, np.int32)
+            batch_k = stack_windows(batches)
+            ports_k = stack_windows(ports)
         mega_fn = self._mega_fn
         launch_box = {"cluster": cluster, "generation": generation,
                       "ledger": None}
@@ -2236,11 +2310,12 @@ class Scheduler:
                         leaf.copy_to_host_async()
             return hosts, AsyncFetch(hosts), qual
 
-        disp_span = spans[0].child("dispatch", windows=K)
-        launched = self._launch_resilient(launch) if use_device else None
-        disp_span.finish()
-        t_disp_end = time.monotonic()
-        self._phase("dispatch", t_disp_end - t_disp)
+        with self._phase_span(spans[0], "dispatch", windows=K) as dispatch:
+            launched = (
+                self._launch_resilient(launch) if use_device else None
+            )
+            self._fold_program_loads(spans[0])
+        t_disp_end = dispatch.t1
         degraded_dispatch = launched is None
         hosts_dev = fetch = quality_dev = None
         if not degraded_dispatch:
@@ -2372,55 +2447,58 @@ class Scheduler:
         relaunch_pending = False
         hosts_all = None
         qual_all = None
-        t_fence0 = time.monotonic()
-        while mf.fetch is not None:
-            try:
-                if relaunch_pending:
-                    # relaunch at the TOP of the try (the single-cycle
-                    # loop's relaunch_pending discipline): a classified
-                    # fault raised by the re-dispatch itself must feed
-                    # the same retry/degrade policy, not escape it
-                    mf.hosts_dev, mf.fetch, mf.quality_dev = mf.relaunch()
-                    relaunch_pending = False
-                hosts_all = np.asarray(mf.fetch.result())
-                for k, inf in enumerate(mf.windows):
-                    self._validate_hosts(hosts_all[k], len(inf.pods))
-                if mf.quality_dev is not None:
-                    # the stacked top-k rides the same fence discipline
-                    # as the winners: by now the launch has computed, so
-                    # this is the pre-enqueued copy landing; a fault here
-                    # retries/degrades the whole megacycle
-                    qual_all = type(mf.quality_dev)(
-                        *(np.asarray(x) for x in mf.quality_dev)
+        with self._phase_span(
+            mf.windows[0].trace, "host_stall", child="fence"
+        ) as fence:
+            while mf.fetch is not None:
+                try:
+                    if relaunch_pending:
+                        # relaunch at the TOP of the try (the single-cycle
+                        # loop's relaunch_pending discipline): a classified
+                        # fault raised by the re-dispatch itself must feed
+                        # the same retry/degrade policy, not escape it
+                        mf.hosts_dev, mf.fetch, mf.quality_dev = mf.relaunch()
+                        relaunch_pending = False
+                    hosts_all = np.asarray(mf.fetch.result())
+                    for k, inf in enumerate(mf.windows):
+                        self._validate_hosts(hosts_all[k], len(inf.pods))
+                    if mf.quality_dev is not None:
+                        # the stacked top-k rides the same fence discipline
+                        # as the winners: by now the launch has computed, so
+                        # this is the pre-enqueued copy landing; a fault here
+                        # retries/degrades the whole megacycle
+                        qual_all = type(mf.quality_dev)(
+                            *(np.asarray(x) for x in mf.quality_dev)
+                        )
+                    break
+                except BaseException as e:
+                    fc = classify_device_error(e)
+                    if fc is None:
+                        raise
+                    shard = self._shard_of(e)
+                    self._note_device_fault(
+                        fc, e,
+                        "megacycle-dispatch" if relaunch_pending
+                        else "megacycle-fence",
                     )
-                break
-            except BaseException as e:
-                fc = classify_device_error(e)
-                if fc is None:
-                    raise
-                shard = self._shard_of(e)
-                self._note_device_fault(
-                    fc, e,
-                    "megacycle-dispatch" if relaunch_pending
-                    else "megacycle-fence",
-                )
-                mf.windows[0].trace.annotate(
-                    fault_class=fc, fault_attempts=attempt + 1
-                )
-                if shard is not None:
-                    mf.windows[0].trace.annotate(fault_shard=shard)
-                if self._fault_retry_allowed(
-                    fc, attempt,
-                    can_relaunch=mf.relaunch is not None, shard=shard,
-                ):
-                    attempt += 1
-                    relaunch_pending = True
-                    continue
-                if not self.config.cpu_fallback:
-                    raise
-                hosts_all = None
-                break
-        stall = time.monotonic() - t_fence0
+                    mf.windows[0].trace.annotate(
+                        fault_class=fc, fault_attempts=attempt + 1
+                    )
+                    if shard is not None:
+                        mf.windows[0].trace.annotate(fault_shard=shard)
+                    if self._fault_retry_allowed(
+                        fc, attempt,
+                        can_relaunch=mf.relaunch is not None, shard=shard,
+                    ):
+                        attempt += 1
+                        relaunch_pending = True
+                        continue
+                    if not self.config.cpu_fallback:
+                        raise
+                    hosts_all = None
+                    break
+        stall = fence.seconds
+        self._fence_split(mf.fetch, fence, TIER_BULK)
         if hosts_all is None:
             # degraded megacycle: K sequential CPU-adapter sub-batches
             if mf.fetch is not None:
@@ -2440,7 +2518,6 @@ class Scheduler:
         self.device_health.record_success()
         if self.shard_health is not None and self._mesh_ids:
             self.shard_health.heal(self._mesh_ids)
-        self._phase("host_stall", stall)
         f = mf.fetch
         prev_gen = -1
         for k, inf in enumerate(mf.windows):
@@ -2594,136 +2671,126 @@ class Scheduler:
         _commit_tail.  In per-pod mode this only fetches — the tail runs
         the classic loop."""
         pods = inf.pods
-        t_fetch0 = time.monotonic()
-        hosts = inf.fetch.result()  # ready-fence: blocks only if the async
-        #                             D2H copy hasn't landed yet
-        hosts = self._validate_hosts(hosts, len(pods))
-        if inf.attrib_dev is not None:
-            # attribution rides the same launch: by the time the winners
-            # landed the rest of the outputs are computed, so this fetch
-            # costs one extra D2H copy, not a second device round-trip.
-            # Inside the resilient fence on purpose — a fault here
-            # retries/degrades exactly like a winners-fetch fault.
-            inf.attrib = type(inf.attrib_dev)(
-                *(np.asarray(x) for x in inf.attrib_dev)
-            )
-        if inf.quality_dev is not None:
-            # the quality top-k rides the same launch and the same
-            # discipline: its async copies were enqueued at dispatch, so
-            # this is a copy wait behind the landed winners, never a new
-            # sync; a fault here retries/degrades like the winners fetch
-            inf.quality = type(inf.quality_dev)(
-                *(np.asarray(x) for x in inf.quality_dev)
-            )
-        t_state0 = time.monotonic()
-        # "fetch" records the ASYNC window (dispatch -> copy-complete,
-        # measured on the fetch worker): it overlaps the dispatch/commit
-        # host phases, so sum-of-phases exceeding wall clock is the
-        # overlap working, not double counting.  "fetch_block" is the
-        # residual host stall at the fence — the number the async path
-        # exists to drive to ~0.
-        self._phase("fetch", inf.fetch.seconds, inf.tier)
-        self._phase("host_stall", t_state0 - t_fetch0, inf.tier)
-        # fetch = the ASYNC device window (stamped on the fetch worker,
-        # reconstructed here from its measured duration); fetch_block =
-        # the residual host stall at the fence, a SUBSET of fetch
-        inf.trace.add_child(
-            "fetch", t_state0 - inf.fetch.seconds, t_state0, overlapped=True,
-        )
-        inf.trace.add_child("fetch_block", t_fetch0, t_state0)
-        # algorithm latency: encode + device filter/score/select, amortized
-        # per pod (metrics.go SchedulingAlgorithmLatency)
-        algo_dt = (time.monotonic() - inf.t_cycle0) / len(pods)
-        m.ALGO_LATENCY.observe_n(algo_dt, len(pods))
-        batched = self.config.batched_commit and self.framework is None
-        staged = _Staged(
-            inf=inf, hosts=hosts, algo_dt=algo_dt, batched=batched,
-            t_state0=t_state0, stall_s=t_state0 - t_fetch0,
-        )
-        if inf.xfer0 is not None:
-            # the fence is the honest cycle boundary for transfer
-            # accounting: every upload/fetch this cycle caused has
-            # landed (AsyncFetch notes bytes before its done-event), and
-            # the pipelined loop has not dispatched the next batch yet
-            from kubernetes_tpu.codec.transfer import transfer_delta
-
-            staged.xfer_delta = transfer_delta(inf.xfer0)
-        if not batched:
-            return staged
-        import copy
-
-        enc = self.cache.encoder
-        winners = staged.winners
-        for i, pod in enumerate(pods):
-            if i in inf.ext_failed:
-                continue
-            row = int(hosts[i])
-            if row < 0:
-                staged.fit_idx.append(i)
-                continue
-            node_name = enc.row_name(row)
-            # shallow-copy + set beats two dataclasses.replace calls ~2x
-            # at 10k commits/s (Pod/PodSpec are plain mutable dataclasses)
-            spec = copy.copy(pod.spec)
-            spec.node_name = node_name
-            assumed = copy.copy(pod)
-            assumed.spec = spec
-            winners.append((i, pod, assumed, node_name))
-        if self._reconciler is not None:
-            # SEQUENCED optimistic-concurrency commit (ISSUE 14): the
-            # admission scan and the assume run as ONE critical section
-            # under the cache lock, so the headroom the scan read is
-            # exactly the headroom the delta lands on.  Race losers
-            # readd to their owner shard in the tail; quota losers park
-            # unschedulable.  Zero-conflict cycles (generation fence
-            # unchanged, no quotas) admit with one integer comparison.
-            with self.cache._lock:
-                kept, race_lost, quota_lost = self._reconciler.reconcile(
-                    self, inf, winners, hosts
+        with self._phase_span(
+            inf.trace, "host_stall", inf.tier, child="fence"
+        ) as fence:
+            hosts = inf.fetch.result()  # ready-fence: blocks only if the async
+            #                             D2H copy hasn't landed yet
+            hosts = self._validate_hosts(hosts, len(pods))
+            if inf.attrib_dev is not None:
+                # attribution rides the same launch: by the time the winners
+                # landed the rest of the outputs are computed, so this fetch
+                # costs one extra D2H copy, not a second device round-trip.
+                # Inside the resilient fence on purpose — a fault here
+                # retries/degrades exactly like a winners-fetch fault.
+                inf.attrib = type(inf.attrib_dev)(
+                    *(np.asarray(x) for x in inf.attrib_dev)
                 )
-                if kept is not winners:
-                    staged.winners = winners = list(kept)
-                staged.race_lost = race_lost
-                staged.quota_lost = quota_lost
-                self.cache.assume_pods([a for _, _, a, _ in winners])
-                inf.gen_after = enc.generation
-                if self.invariants is not None and winners:
-                    rows = sorted(
-                        {int(hosts[i]) for i, _, _, _ in winners}
+            if inf.quality_dev is not None:
+                # the quality top-k rides the same launch and the same
+                # discipline: its async copies were enqueued at dispatch, so
+                # this is a copy wait behind the landed winners, never a new
+                # sync; a fault here retries/degrades like the winners fetch
+                inf.quality = type(inf.quality_dev)(
+                    *(np.asarray(x) for x in inf.quality_dev)
+                )
+        t_state0 = fence.t1
+        with self._phase_span(inf.trace, "commit", inf.tier) as commit:
+            # "fetch" records the ASYNC window (dispatch -> copy landed,
+            # stamped on the fetch worker): it overlaps the dispatch/commit
+            # host phases.  The fence wait (host_stall) is split by what
+            # the worker was doing meanwhile.
+            self._phase("fetch", inf.fetch.seconds, inf.tier)
+            self._fence_split(inf.fetch, fence, inf.tier)
+            # algorithm latency: encode + device filter/score/select, amortized
+            # per pod (metrics.go SchedulingAlgorithmLatency)
+            algo_dt = (time.monotonic() - inf.t_cycle0) / len(pods)
+            m.ALGO_LATENCY.observe_n(algo_dt, len(pods))
+            batched = self.config.batched_commit and self.framework is None
+            staged = _Staged(
+                inf=inf, hosts=hosts, algo_dt=algo_dt, batched=batched,
+                t_state0=t_state0, stall_s=fence.seconds,
+            )
+            if inf.xfer0 is not None:
+                # the fence is the honest cycle boundary for transfer
+                # accounting: every upload/fetch this cycle caused has
+                # landed (AsyncFetch notes bytes before its done-event), and
+                # the pipelined loop has not dispatched the next batch yet
+                from kubernetes_tpu.codec.transfer import transfer_delta
+
+                staged.xfer_delta = transfer_delta(inf.xfer0)
+            if not batched:
+                return staged
+            import copy
+
+            enc = self.cache.encoder
+            winners = staged.winners
+            for i, pod in enumerate(pods):
+                if i in inf.ext_failed:
+                    continue
+                row = int(hosts[i])
+                if row < 0:
+                    staged.fit_idx.append(i)
+                    continue
+                node_name = enc.row_name(row)
+                # shallow-copy + set beats two dataclasses.replace calls ~2x
+                # at 10k commits/s (Pod/PodSpec are plain mutable dataclasses)
+                spec = copy.copy(pod.spec)
+                spec.node_name = node_name
+                assumed = copy.copy(pod)
+                assumed.spec = spec
+                winners.append((i, pod, assumed, node_name))
+            if self._reconciler is not None:
+                # SEQUENCED optimistic-concurrency commit: the
+                # admission scan and the assume run as ONE critical section
+                # under the cache lock, so the headroom the scan read is
+                # exactly the headroom the delta lands on.  Race losers
+                # readd to their owner shard in the tail; quota losers park
+                # unschedulable.  Zero-conflict cycles (generation fence
+                # unchanged, no quotas) admit with one integer comparison.
+                with self.cache._lock:
+                    kept, race_lost, quota_lost = self._reconciler.reconcile(
+                        self, inf, winners, hosts
                     )
+                    if kept is not winners:
+                        staged.winners = winners = list(kept)
+                    staged.race_lost = race_lost
+                    staged.quota_lost = quota_lost
+                    self.cache.assume_pods([a for _, _, a, _ in winners])
+                    inf.gen_after = enc.generation
+                    if self.invariants is not None and winners:
+                        rows = sorted(
+                            {int(hosts[i]) for i, _, _, _ in winners}
+                        )
+                        self.invariants.check_capacity(
+                            rows, enc.a_requested, enc.a_allocatable,
+                            row_name=enc.row_name,
+                        )
+                if race_lost or quota_lost:
+                    self.conflicts_total += len(race_lost)
+                    self.race_requeued_total += len(race_lost)
+                    self.quota_vetoed_total += len(quota_lost)
+                    inf.trace.annotate(
+                        conflicts=len(race_lost), quota_vetoed=len(quota_lost)
+                    )
+                staged.state_seconds = time.monotonic() - t_state0
+                commit.attrs["winners"] = len(winners)
+                return staged
+            # ONE lock acquisition + one encoder delta for the whole batch
+            self.cache.assume_pods([a for _, _, a, _ in winners])
+            if self.invariants is not None and winners:
+                # capacity invariant over exactly the rows this batch
+                # committed to — O(batch), read under the cache lock so the
+                # arrays are consistent with the delta just applied
+                rows = sorted({int(hosts[i]) for i, _, _, _ in winners})
+                with self.cache._lock:
                     self.invariants.check_capacity(
                         rows, enc.a_requested, enc.a_allocatable,
                         row_name=enc.row_name,
                     )
-            if race_lost or quota_lost:
-                self.conflicts_total += len(race_lost)
-                self.race_requeued_total += len(race_lost)
-                self.quota_vetoed_total += len(quota_lost)
-                inf.trace.annotate(
-                    conflicts=len(race_lost), quota_vetoed=len(quota_lost)
-                )
             staged.state_seconds = time.monotonic() - t_state0
-            inf.trace.add_child(
-                "commit", t_state0, time.monotonic(), winners=len(winners),
-            )
+            commit.attrs["winners"] = len(winners)
             return staged
-        # ONE lock acquisition + one encoder delta for the whole batch
-        self.cache.assume_pods([a for _, _, a, _ in winners])
-        if self.invariants is not None and winners:
-            # capacity invariant over exactly the rows this batch
-            # committed to — O(batch), read under the cache lock so the
-            # arrays are consistent with the delta just applied
-            rows = sorted({int(hosts[i]) for i, _, _, _ in winners})
-            with self.cache._lock:
-                self.invariants.check_capacity(
-                    rows, enc.a_requested, enc.a_allocatable,
-                    row_name=enc.row_name,
-                )
-        staged.state_seconds = time.monotonic() - t_state0
-        inf.trace.add_child(
-            "commit", t_state0, time.monotonic(), winners=len(winners),
-        )
-        return staged
 
     def _commit_tail(self, staged: _Staged) -> List[ScheduleResult]:
         """Side-effect tail of a cycle: binds, events, metrics, requeues,
@@ -2731,26 +2798,44 @@ class Scheduler:
         device dispatch (the state half already ran, so the next snapshot
         is exact)."""
         inf = staged.inf
-        pods = inf.pods
         t_tail0 = time.monotonic()
         # the cycle's trace context is CURRENT for the whole tail: binds
         # (RemoteBinder / bind-verb extenders attach the traceparent
         # header) and Scheduled/FailedScheduling events (trace_id field)
         # all join back to this cycle's root span
         with use_traceparent(inf.trace):
-            tail_span = inf.trace.child("bind-tail")
-            if staged.batched:
-                results, fit_errors = self._tail_batched(staged)
-            else:
-                results, fit_errors = self._tail_perpod(staged)
-            tail_span.finish()
+            with self._phase_span(
+                inf.trace, "commit", inf.tier, child="bind-tail"
+            ):
+                if staged.batched:
+                    results, fit_errors = self._tail_batched(staged)
+                else:
+                    results, fit_errors = self._tail_perpod(staged)
             if not self.config.disable_preemption:
-                t_p = time.monotonic()
-                p_span = inf.trace.child("preempt", failed=len(fit_errors))
-                for pod in fit_errors:
-                    self.preempt(pod)
-                p_span.finish()
-                self._phase("preempt", time.monotonic() - t_p, inf.tier)
+                with self._phase_span(
+                    inf.trace, "preempt", inf.tier, failed=len(fit_errors)
+                ):
+                    for pod in fit_errors:
+                        self.preempt(pod)
+        with self._phase_span(inf.trace, "observe", inf.tier):
+            results = self._observe(staged, results, t_tail0)
+        inf.trace.finish()
+        # slow-cycle log LAST, once the ENTIRE tail (ledger record +
+        # telemetry included) has run and the span is finished, so the
+        # logged total is the duration /debug/traces reports; its own
+        # time still counts as observe
+        if self.config.trace_threshold_s > 0:
+            with self._phase_span(None, "observe", inf.tier):
+                inf.trace.log_if_long(self.config.trace_threshold_s)
+        return results
+
+    def _observe(self, staged: _Staged, results: List[ScheduleResult],
+                 t_tail0: float) -> List[ScheduleResult]:
+        """The observe phase of a committed cycle: every post-bind hook
+        (flight recorder, ledger, telemetry, perfobs, quality, capacity,
+        timeline).  Each hook keeps its own /metrics cost counter, and
+        none may fail a cycle whose placements are already committed."""
+        inf = staged.inf
         placed = sum(1 for r in results if r.node is not None)
         inf.trace.annotate(placed=placed, unschedulable=len(results) - placed)
         # the cycle's wire traffic (taken at the commit fence — see
@@ -2766,7 +2851,8 @@ class Scheduler:
                 ),
                 transfer_top_seam=top[0],
             )
-        inf.trace.finish()
+        # into the ring now, so a postmortem fired by a hook below sees
+        # this cycle; the span finishes when the observe phase ends
         self.flight_recorder.record(inf.trace)
         if self.ledger is not None and inf.ledger_inputs is not None:
             self._ledger_record(inf, staged, results)
@@ -2887,14 +2973,8 @@ class Scheduler:
             finally:
                 m.TIMELINE_SECONDS.inc(time.perf_counter() - t_tl)
         self.results.extend(results)
-        # slow-cycle log LAST, once the ENTIRE tail (ledger record +
-        # telemetry included) has run: the span was finished above, so
-        # the logged total is the same duration the span tree at
-        # /debug/traces reports — on pipelined cycles the log used to
-        # fire mid-tail, reporting a duration the rest of the tail then
-        # outgrew (regression-pinned by tests/test_tracing.py)
-        if self.config.trace_threshold_s > 0:
-            inf.trace.log_if_long(self.config.trace_threshold_s)
+        # programs loaded since dispatch (a side launch's first compile)
+        self._fold_program_loads(inf.trace, inf.tier)
         return results
 
     def _telemetry_cycle(self, inf: _InFlight, results, placed: int) -> None:
@@ -3145,7 +3225,6 @@ class Scheduler:
         pods, hosts = inf.pods, staged.hosts
         generation, cycle, pc = inf.generation, inf.cycle, inf.pc
         ext_failed, algo_dt = inf.ext_failed, staged.algo_dt
-        t_commit0 = time.monotonic()
         enc = self.cache.encoder
         results = []
         fit_errors: List[Pod] = []
@@ -3206,7 +3285,6 @@ class Scheduler:
                         pod, node_name, algo_dt + (time.monotonic() - t_pod),
                         tier=inf.tier,
                     )
-        self._phase("commit", time.monotonic() - t_commit0, inf.tier)
         return results, fit_errors
 
     def _tail_batched(self, staged: _Staged):
@@ -3218,7 +3296,6 @@ class Scheduler:
         inf = staged.inf
         pods, hosts = inf.pods, staged.hosts
         generation, cycle = inf.generation, inf.cycle
-        t_tail0 = time.monotonic()
         B = len(pods)
         results: List[Optional[ScheduleResult]] = [None] * B
         events: List[Optional[Tuple]] = [None] * B
@@ -3362,10 +3439,6 @@ class Scheduler:
         else:  # duck-typed recorder without the batch entry point
             for kind, ns, name, type_, reason, msg, _tid in entries:
                 self.recorder.eventf(kind, ns, name, type_, reason, "%s", msg)
-        self._phase(
-            "commit", staged.state_seconds + time.monotonic() - t_tail0,
-            inf.tier,
-        )
         return list(results), [pods[i] for i in staged.fit_idx]
 
     # --------------------------------------------------------- extenders
@@ -3883,13 +3956,14 @@ class Scheduler:
         pop_express = getattr(self.queue, "pop_express_batch", None)
         if pop_express is None:
             return 0  # caller-owned queue without tier lanes
-        t_pop = time.monotonic()
-        pods = pop_express(max(1, self.config.express_batch_size))
+        with self._phase_span(None, "pop", TIER_EXPRESS):
+            pods = pop_express(max(1, self.config.express_batch_size))
+            if pods and self.invariants is not None:
+                self.invariants.note_popped(
+                    pods, self.queue.scheduling_cycle
+                )
         if not pods:
             return 0
-        if self.invariants is not None:
-            self.invariants.note_popped(pods, self.queue.scheduling_cycle)
-        self._phase("pop", time.monotonic() - t_pop, TIER_EXPRESS)
         results = self.schedule_cycle(pods, tier=TIER_EXPRESS)
         return sum(1 for r in results if r.node is not None)
 
@@ -4179,54 +4253,61 @@ class Scheduler:
         dispatches this batch and returns the PREVIOUS batch's placements
         (flush_pipeline drains the last one); gang cycles and empty polls
         drain the pipeline first so snapshots never go stale."""
-        self._maybe_heartbeat()
+        # program loads count from here: a compile between cycles
+        # (prewarm, a caller's own jit) is no cycle's
+        self._loads_seen = program_loads()
+        with self._phase_span(None, "observe"):
+            self._maybe_heartbeat()
+            # idle-path timeline tick: an empty queue must
+            # still sample — the commit tail only runs on committed
+            # cycles, and a quiet interval is exactly when a breaker/SLO
+            # excursion needs surrounding samples.  Cadence-gated inside
+            # the store, so a busy loop that just sampled in the commit
+            # tail pays one monotonic read here.
+            if self.timeline is not None:
+                t_tl = time.perf_counter()
+                try:
+                    self.timeline.maybe_sample()
+                except Exception as e:  # noqa: BLE001
+                    klog.errorf("timeline idle tick failed: %s", e)
+                finally:
+                    m.TIMELINE_SECONDS.inc(time.perf_counter() - t_tl)
         self._maybe_probe_shards()
-        # idle-path timeline tick (ISSUE 20): an empty queue must still
-        # sample — the commit tail only runs on committed cycles, and a
-        # quiet interval is exactly when a breaker/SLO excursion needs
-        # surrounding samples.  Cadence-gated inside the store, so a
-        # busy loop that just sampled in the commit tail pays one
-        # monotonic read here.
-        if self.timeline is not None:
-            t_tl = time.perf_counter()
-            try:
-                self.timeline.maybe_sample()
-            except Exception as e:  # noqa: BLE001
-                klog.errorf("timeline idle tick failed: %s", e)
-            finally:
-                m.TIMELINE_SECONDS.inc(time.perf_counter() - t_tl)
-        t_pop = time.monotonic()
-        express = self.config.express_lane
-        # tiered mode only adds the kwarg (an express arrival interrupts
-        # the bulk wait so the express cycle below runs immediately), and
-        # only for a queue that actually has tier lanes — a caller-owned
-        # duck-typed queue without them never sees it
-        pop_kw = (
-            {"yield_to_express": True}
-            if express and hasattr(self.queue, "pop_express_batch")
-            else {}
-        )
-        if self._replica_of > 1:
-            # queue-sharded replica (ISSUE 14): drain only this
-            # replica's stable hash-shard — pops are disjoint across
-            # replicas by construction, and every requeue of a popped
-            # pod lands back on this shard
-            pop_kw.update(shard=self._replica_id, of=self._replica_of)
-        pods = self.queue.pop_batch(
-            # adaptive mode pops at the CURRENT AIMD width; static mode
-            # keeps the configured batch size
-            self._cur_batch if self.config.adaptive_batch
-            else self.config.batch_size,
-            # with a batch in flight, don't block in the pop: its binds/
-            # events/requeues must not wait out the poll timeout when the
-            # queue momentarily empties (trickle arrival, burst tails)
-            0.0 if self.pipeline_pending else timeout,
-            self.config.batch_window_s,
-            **pop_kw,
-        )
-        if self.invariants is not None:
-            self.invariants.note_popped(pods, self.queue.scheduling_cycle)
-        self._phase("pop", time.monotonic() - t_pop)
+        with self._phase_span(None, "pop"):
+            express = self.config.express_lane
+            # tiered mode only adds the kwarg (an express arrival
+            # interrupts the bulk wait so the express cycle below runs
+            # immediately), and only for a queue that actually has tier
+            # lanes — a caller-owned duck-typed queue without them never
+            # sees it
+            pop_kw = (
+                {"yield_to_express": True}
+                if express and hasattr(self.queue, "pop_express_batch")
+                else {}
+            )
+            if self._replica_of > 1:
+                # queue-sharded replica: drain only this
+                # replica's stable hash-shard — pops are disjoint across
+                # replicas by construction, and every requeue of a popped
+                # pod lands back on this shard
+                pop_kw.update(shard=self._replica_id, of=self._replica_of)
+            pods = self.queue.pop_batch(
+                # adaptive mode pops at the CURRENT AIMD width; static mode
+                # keeps the configured batch size
+                self._cur_batch if self.config.adaptive_batch
+                else self.config.batch_size,
+                # with a batch in flight, don't block in the pop: its
+                # binds/events/requeues must not wait out the poll
+                # timeout when the queue momentarily empties (trickle
+                # arrival, burst tails)
+                0.0 if self.pipeline_pending else timeout,
+                self.config.batch_window_s,
+                **pop_kw,
+            )
+            if self.invariants is not None:
+                self.invariants.note_popped(
+                    pods, self.queue.scheduling_cycle
+                )
         # express lane between the bulk pop and the bulk dispatch: pending
         # latency-sensitive pods schedule (and commit) BEFORE this cycle's
         # bulk batch, and at most one small express batch runs per

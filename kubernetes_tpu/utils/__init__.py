@@ -1,6 +1,6 @@
 from kubernetes_tpu.utils.trace import (
+    PhaseSpan,
     Span,
-    Trace,
     chrome_trace,
     current_trace_id,
     current_traceparent,

@@ -24,7 +24,8 @@ cover every compile after them (jax reads the config per compile).
 from __future__ import annotations
 
 import os
-from typing import Optional
+import threading
+from typing import Optional, Tuple
 
 JAX_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 DEFAULT_CACHE_DIR = os.path.join(
@@ -36,20 +37,37 @@ DEFAULT_CACHE_DIR = os.path.join(
 # sentinel accepted by the explicit knob: disables the cache
 DISABLED = "off"
 
-# jax.monitoring event names this module listens on
+# jax.monitoring event names this module listens on.  A program load is
+# one _EVENT_COMPILE_SECS duration: jax emits it around
+# compiler.compile_or_get_cached, so it covers a backend compile AND a
+# persistent-cache hit (whose "/jax/compilation_cache/
+# cache_retrieval_time_sec" event times a part of the same interval and
+# is therefore not added again)
 _EVENT_HIT = "/jax/compilation_cache/cache_hits"
 _EVENT_MISS = "/jax/compilation_cache/cache_misses"
 _EVENT_COMPILE_SECS = "/jax/core/compile/backend_compile_duration"
 
 _listeners_installed = False
+# process-wide program loads so far: [count, seconds] (the scheduler
+# folds the delta into phase_seconds["program_load"] per cycle)
+_LOADS = [0, 0.0]
+_LOADS_LOCK = threading.Lock()
+
+
+def program_loads() -> Tuple[int, float]:
+    """(program loads, their seconds) since the listeners were
+    installed: every backend compile or persistent-cache retrieval of an
+    executable, in any thread of the process."""
+    with _LOADS_LOCK:
+        return _LOADS[0], _LOADS[1]
 
 
 def install_metrics_listeners() -> None:
     """Feed compile-cache hits/misses and cumulative backend-compile
     seconds into the metrics registry (ktpu_compile_cache_events_total,
-    ktpu_backend_compile_seconds_total) via jax.monitoring — the
-    telemetry hub (runtime/telemetry.py) reads the same counters.
-    Idempotent."""
+    ktpu_backend_compile_seconds_total) and the program-load totals
+    (program_loads) via jax.monitoring — the telemetry hub
+    (runtime/telemetry.py) reads the same counters.  Idempotent."""
     global _listeners_installed
     if _listeners_installed:
         return
@@ -66,6 +84,9 @@ def install_metrics_listeners() -> None:
     def _on_duration(event: str, duration: float, **kw) -> None:
         if event == _EVENT_COMPILE_SECS:
             m.COMPILE_SECONDS.inc(float(duration))
+            with _LOADS_LOCK:
+                _LOADS[0] += 1
+                _LOADS[1] += float(duration)
 
     monitoring.register_event_listener(_on_event)
     monitoring.register_event_duration_secs_listener(_on_duration)

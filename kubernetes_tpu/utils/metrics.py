@@ -574,19 +574,20 @@ CYCLE_DEADLINE_EXCEEDED = REGISTRY.register(
 # per-cycle phase accounting (ISSUE 5): the scheduler's phase_seconds
 # dict was driver-only state (bench reporting); this family exposes the
 # same cumulative seconds on /metrics so a dashboard can watch the
-# encode/dispatch/fetch/commit split move without running the bench
+# encode/dispatch/fence/commit/observe split move without running the
+# bench
 CYCLE_PHASE_SECONDS = REGISTRY.register(
     LabeledCounter(
         "scheduler_cycle_phase_seconds_total",
-        "Cumulative seconds spent per scheduling-cycle phase "
-        "(pop|encode|dispatch|fetch|host_stall|commit|preempt) and "
-        "latency tier (bulk|express); encode includes the extender/"
-        "framework fan-out (the span tree at /debug/traces splits "
-        "extenders out); fetch overlaps host phases and host_stall (the "
-        "residual fence wait — the perf-observatory name; the scheduler's "
-        "phase_seconds dict keeps fetch_block as a lockstep alias) is a "
-        "subset of fetch, so phase sums exceeding wall clock means the "
-        "pipeline is working",
+        "Cumulative seconds spent per scheduling-cycle phase and latency "
+        "tier (bulk|express).  pop|encode|dispatch|host_stall|commit|"
+        "preempt|observe partition the scheduling loop's wall time "
+        "(encode includes the extender/framework fan-out; host_stall is "
+        "the wait at the ready fence; observe the post-bind hooks).  "
+        "fetch (dispatch to host copy, on the fetch worker) overlaps them; "
+        "fence_queue|fence_device|fence_copy split host_stall by what the "
+        "fetch worker was doing; program_load counts backend compiles and "
+        "persistent-cache retrievals",
         ("phase", "tier"),
     )
 )

@@ -10,10 +10,15 @@ actually asks.  This module is the structured replacement:
 
   * `Span` — a named, attributed interval on the monotonic clock with
     child spans; the scheduler wraps every cycle in a root span with one
-    child per phase (encode / dispatch / fetch / fetch_block / commit /
-    bind-tail / preempt), annotated with batch width, dirty-row count,
-    breaker state, and retry class.  finish() is thread-safe and
+    child per phase (encode / dispatch / fence / commit / bind-tail /
+    preempt / observe, plus device_wait / d2h stamped by the fetch
+    worker), annotated with batch width, dirty-row count, breaker
+    state, retry class and program loads.  finish() is thread-safe and
     idempotent (the async-fetch worker may race the scheduling thread).
+  * `PhaseSpan` — the one phase primitive: a context manager that feeds
+    the phase counter, records the cycle span's child, and, while a
+    jax.profiler session is active, holds a `ktpu.<phase>` annotation
+    open on the calling thread for the same interval.
   * trace context — every root span mints a 16-byte trace id, carried
     across component boundaries as a W3C `traceparent` header
     (00-<trace>-<span>-01) via a thread-local (`use_traceparent` /
@@ -23,19 +28,24 @@ actually asks.  This module is the structured replacement:
   * Chrome `trace_event` export — `chrome_trace(spans)` emits the JSON
     object format Perfetto / chrome://tracing load directly; served at
     `/debug/traces` (runtime/health.py, apiserver/server.py) and written
-    by `bench.py --trace-out`.
+    by `bench.py --trace-out`.  Timestamps are exported on the
+    profiler's clock (microseconds since the Unix epoch): an event of a
+    jax.profiler capture sits at the capture's `profile_start_time` plus
+    its offset, so the two overlay.
 
-Device-side profiling (jax.profiler) composes with these host spans via
-codec/transfer.device_annotation; this module stays dependency-free.
+This module imports no jax: `annotation` reaches jax.profiler only once
+something else imported jax (a process without it has no session).
 """
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import os
+import sys
 import threading
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 logger = logging.getLogger("kubernetes_tpu")
 
@@ -116,6 +126,43 @@ class use_traceparent:
 
     def __exit__(self, *exc) -> None:
         _ctx.header = self._prev
+
+
+# ------------------------------------------------- the profiler's timeline
+
+_NULL = contextlib.nullcontext()
+
+
+def _trace_annotation():
+    """jax.profiler.TraceAnnotation once jax is imported, else None."""
+    prof = sys.modules.get("jax.profiler")
+    return getattr(prof, "TraceAnnotation", None)
+
+
+def annotation(name: str):
+    """A jax.profiler range named `name` on the calling thread while a
+    profiler session is active (a capture, or the benchmark's traced
+    span); otherwise a shared no-op.  The check is one static call
+    (TraceAnnotation.is_enabled, ~0.1 us), so callers wrap hot sections
+    unconditionally."""
+    ta = _trace_annotation()
+    if ta is None or not ta.is_enabled():
+        return _NULL
+    return ta(name)
+
+
+def profiler_clock_offset() -> float:
+    """Seconds to add to a time.monotonic() reading to put it on the
+    profiler's clock (the Unix epoch: TraceMe stamps wall-clock
+    nanoseconds).  Read at export, so it follows clock adjustments."""
+    return time.time() - time.monotonic()
+
+
+# chrome-export thread lanes: spans stamped on the async-fetch worker
+# draw on their own track, so they never overlap the scheduling
+# thread's phases on one track
+WORKER_LANE = "fetch-worker"
+_LANE_TID = {WORKER_LANE: 2}
 
 
 # ------------------------------------------------------------------- the span
@@ -225,21 +272,26 @@ class Span:
             "children": [c.to_dict() for c in kids],
         }
 
-    def chrome_events(self, pid: int = 1, tid: int = 1) -> List[dict]:
+    def chrome_events(self, pid: int = 1, tid: int = 1,
+                      clock_offset: Optional[float] = None) -> List[dict]:
         """This span tree as Chrome trace_event "X" (complete) events —
         the format chrome://tracing and Perfetto load.  ts/dur are in
-        MICROSECONDS on the process monotonic clock (consistent within
-        one export, which is all the viewers require); an unfinished
-        span exports up to "now" so a live dump is still loadable."""
+        MICROSECONDS on the profiler's clock (`profiler_clock_offset`,
+        taken once per export unless given); a child with a `lane`
+        attribute draws on that lane's track; an unfinished span exports
+        up to "now" so a live dump is still loadable."""
+        if clock_offset is None:
+            clock_offset = profiler_clock_offset()
         with self._lock:
             kids = list(self.children)
             attrs = dict(self.attrs)
         end = self.end if self.end is not None else time.monotonic()
+        tid = _LANE_TID.get(attrs.get("lane"), tid)
         out = [{
             "name": self.name,
             "cat": "ktpu",
             "ph": "X",
-            "ts": int(self.start * 1e6),
+            "ts": int((self.start + clock_offset) * 1e6),
             "dur": max(int((end - self.start) * 1e6), 1),
             "pid": pid,
             "tid": tid,
@@ -250,7 +302,8 @@ class Span:
             },
         }]
         for c in kids:
-            out.extend(c.chrome_events(pid=pid, tid=tid))
+            out.extend(c.chrome_events(pid=pid, tid=tid,
+                                       clock_offset=clock_offset))
         return out
 
     def find(self, name: str) -> Optional["Span"]:
@@ -298,37 +351,70 @@ def chrome_trace(spans) -> dict:
     and chrome://tracing both accept it; the bare-array format has no
     room for displayTimeUnit)."""
     events: List[dict] = []
+    offset = profiler_clock_offset()
     for sp in spans:
-        events.extend(sp.chrome_events())
+        events.extend(sp.chrome_events(clock_offset=offset))
     return {"traceEvents": events, "displayTimeUnit": "ms"}
 
 
-# ---------------------------------------------------------------- legacy shim
+# ---------------------------------------------------------- the phase span
 
 
-class Trace:
-    """The original dump-only-if-slow step timer, now a thin veneer over
-    Span (steps become zero-width children).  Kept for callers that want
-    utiltrace ergonomics without managing a span tree."""
+class PhaseSpan:
+    """One phase of a scheduling cycle, measured once where the work
+    happens:
 
-    def __init__(self, name: str, **fields):
-        self.span = Span(name, **fields)
+        with PhaseSpan(record, "encode", tier, parent=cycle_span):
+            ...
+
+    On a normal exit it does three things with one pair of clock
+    readings: `record(name, seconds, tier)` (the phase counters),
+    a `child` span of `parent` over [t0, t1] (named `child`, default
+    the phase name; the parent may be set inside the block), and — only
+    while a jax.profiler session is active — a `ktpu.<name>` annotation
+    held open on the calling thread for the same interval, so the
+    annotation shares the device trace's clock by construction.  A
+    block that raises still closes its span and annotation but feeds
+    no counter: the fault path that caught it owns that time.  With no
+    session the whole cost is two clock reads, one enabled check and
+    the record call."""
+
+    __slots__ = ("_record", "name", "tier", "parent", "child", "attrs",
+                 "t0", "t1", "span", "_ann")
+
+    def __init__(self, record: Callable[[str, float, str], None],
+                 name: str, tier: str, parent: Optional[Span] = None,
+                 child: Optional[str] = None, **attrs):
+        self._record = record
+        self.name = name
+        self.tier = tier
+        self.parent = parent
+        self.child = child or name
+        self.attrs = attrs
+        self.t0 = self.t1 = 0.0
+        self.span: Optional[Span] = None
+        self._ann = None
 
     @property
-    def name(self) -> str:
-        return self.span.name
+    def seconds(self) -> float:
+        return self.t1 - self.t0
 
-    @property
-    def fields(self) -> dict:
-        return self.span.attrs
+    def __enter__(self) -> "PhaseSpan":
+        ann = annotation("ktpu." + self.name)
+        if ann is not _NULL:
+            ann.__enter__()
+            self._ann = ann
+        self.t0 = time.monotonic()
+        return self
 
-    def step(self, msg: str) -> None:
-        t = time.monotonic()
-        self.span.add_child(msg, t, t)
-
-    def total(self) -> float:
-        return self.span.duration
-
-    def log_if_long(self, threshold_s: float) -> None:
-        self.span.finish()
-        self.span.log_if_long(threshold_s)
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self.t1 = time.monotonic()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
+        if self.parent is not None:
+            self.span = self.parent.add_child(
+                self.child, self.t0, self.t1, **self.attrs
+            )
+        if exc_type is None:
+            self._record(self.name, self.t1 - self.t0, self.tier)

@@ -82,12 +82,12 @@ def test_cycle_split_reconciles_with_phase_seconds():
     ph = sched.phase_seconds
     tot = summary["totals_s"]
     # same stamps, independent accumulation points: enqueue == the
-    # encode+dispatch phases, stall == fetch_block (tight tolerance)
+    # encode+dispatch phases, stall == host_stall (tight tolerance)
     enq_ref = ph["encode"] + ph["dispatch"]
     assert abs(tot["host_enqueue"] - enq_ref) <= 0.02 + 0.05 * enq_ref
     assert (
-        abs(tot["host_stall"] - ph["fetch_block"])
-        <= 0.02 + 0.05 * max(ph["fetch_block"], 1e-9)
+        abs(tot["host_stall"] - ph["host_stall"])
+        <= 0.02 + 0.05 * max(ph["host_stall"], 1e-9)
     )
     # the commit measure covers the WHOLE tail (ledger/telemetry/perf
     # included) so it bounds the phase counter from above
