@@ -466,6 +466,22 @@ class Pod:
             p for c in self.spec.containers for p in c.ports if p.host_port > 0
         ]
 
+    def assumed_on(self, node_name: str) -> "Pod":
+        """The assumed copy a commit charges to `node_name`: a shallow
+        copy of the pod and of its spec, with spec.node_name set.  It is
+        `copy.copy` of both without the copy protocol (`__reduce_ex__`,
+        copyreg), which the per-winner commit loop paid per pod."""
+        spec = self.spec
+        spec_state = spec.__dict__.copy()
+        spec_state["node_name"] = node_name
+        new_spec = type(spec).__new__(type(spec))
+        new_spec.__dict__ = spec_state
+        state = self.__dict__.copy()
+        state["spec"] = new_spec
+        pod = type(self).__new__(type(self))
+        pod.__dict__ = state
+        return pod
+
     @staticmethod
     def from_dict(d: dict) -> "Pod":
         st = d.get("status") or {}

@@ -124,6 +124,21 @@ class _TermGroup:
     members: int = 0
 
 
+class _CommitInputs(NamedTuple):
+    """What committing a pod derives from its content alone
+    (SnapshotEncoder._pod_commit_inputs); equal for every pod of one
+    encode_pods class."""
+    req: np.ndarray                  # f32[R-cap]
+    nonzero: np.ndarray              # f32[2]
+    ports: List[Tuple[int, int]]     # (proto/port id, ip id)
+    disk_vols: List[int]             # disk-conflict advertise tokens
+    vol_counts: np.ndarray           # f32[VT]
+    cnt_vols: object                 # per-type unique volume id sets
+    ns_id: int                       # interned namespace
+    label_ids: List[Tuple[int, int]]  # interned (key, value) pairs
+    tenant: int                      # per-namespace usage row
+
+
 @dataclass
 class _PodRecord:
     key: Tuple[str, str]
@@ -1607,24 +1622,12 @@ class SnapshotEncoder:
             )
         return np.array([cpu, mem], np.float32)
 
-    def add_pod(self, pod: Pod) -> None:
-        """Add an assigned (or assumed) pod: accumulate into its node's row and
-        the vectorized pod index (ref internal/cache/cache.go AddPod/AssumePod)."""
-        key = (pod.namespace, pod.name)
-        if key in self.pods:
-            self.remove_pod(pod)
-        if self._free_m:
-            m = self._free_m.pop()
-        else:
-            m = self._next_m
-            self._next_m += 1
-            if m >= self._cap_m:
-                self._grow_pods()
-        node_row = self.node_rows.get(pod.spec.node_name, -1)
-        # (req, nonzero) memo keyed by container request content: cache
-        # commits of controller-stamped identical pods skip the exact
-        # Fraction summation (~60us/pod).  rec.req arrays are never mutated
-        # in place (the R-regrow path replaces them), so sharing is safe.
+    def _req_pair(self, pod: Pod) -> Tuple[np.ndarray, np.ndarray]:
+        """(req, nonzero) of a pod to commit, memoized by container request
+        content: cache commits of controller-stamped identical pods skip
+        the exact Fraction summation (~60us/pod).  rec.req arrays are
+        never mutated in place (the R-regrow path replaces them), so
+        sharing is safe."""
         # unsorted items(): two insertion orders of the same content just
         # occupy two memo slots mapping to equal arrays — correct either
         # way, and skipping 3 sorts/pod matters at 10k commits/s
@@ -1641,7 +1644,23 @@ class SnapshotEncoder:
                 self._req_memo.clear()
             hit = (self._req_vector(pod.resource_request()), self._nonzero(pod))
             self._req_memo[rk] = hit
-        req, nonzero = hit
+        return hit
+
+    def add_pod(self, pod: Pod) -> None:
+        """Add an assigned (or assumed) pod: accumulate into its node's row and
+        the vectorized pod index (ref internal/cache/cache.go AddPod/AssumePod)."""
+        key = (pod.namespace, pod.name)
+        if key in self.pods:
+            self.remove_pod(pod)
+        if self._free_m:
+            m = self._free_m.pop()
+        else:
+            m = self._next_m
+            self._next_m += 1
+            if m >= self._cap_m:
+                self._grow_pods()
+        node_row = self.node_rows.get(pod.spec.node_name, -1)
+        req, nonzero = self._req_pair(pod)
         ports = self._pod_ports(pod)
         disk_check, disk_adv, vcounts, cnt_ids = self._pod_vols(pod)
         disk = disk_adv  # the NODE advertises; rec stores what to retract
@@ -1710,7 +1729,26 @@ class SnapshotEncoder:
         self._gc_dirty = True
         self.generation += 1
 
-    def add_pods(self, pods: Sequence[Pod]) -> None:
+    def _pod_commit_inputs(self, pod: Pod) -> _CommitInputs:
+        """`pod`'s commit inputs, interned in add_pod's order (ports,
+        volumes, namespace, labels).  add_pods derives them once per
+        class and the class's pods share them."""
+        req, nonzero = self._req_pair(pod)
+        ports = self._pod_ports(pod)
+        _, disk_adv, vcounts, cnt_ids = self._pod_vols(pod)
+        it = self.interner
+        ns_id = it.intern(pod.namespace)
+        label_ids = [(it.intern(k), it.intern(v)) for k, v in pod.labels.items()]
+        for kid, _ in label_ids:
+            if kid not in self._label_cols:
+                self._label_cols[kid] = np.full(self._cap_m, PAD, np.int32)
+        return _CommitInputs(req, nonzero, ports, disk_adv, vcounts,
+                             cnt_ids, ns_id, label_ids,
+                             self._ns_row(pod.namespace))
+
+    def add_pods(self, pods: Sequence[Pod],
+                 classes: Optional[np.ndarray] = None,
+                 rows: Optional[np.ndarray] = None) -> int:
         """Batched add_pod: one pass that produces byte-identical arena
         state to calling add_pod(p) for each pod in order, amortizing the
         per-pod numpy overhead (the host-commit wall of the live control
@@ -1720,14 +1758,27 @@ class SnapshotEncoder:
             of 2B row-slice adds (same accumulation order -> identical
             floats);
           * the pod-arena columns (alive/ns/node, label columns) write via
-            fancy indexing, grouped per label key;
+            fancy indexing, grouped per label key or per class;
           * port/volume row rebuilds (row-wide sorts) run once per TOUCHED
             row after all pods applied, not once per pod;
           * the generation counter advances by len(pods) in one step.
 
+        `classes` is encode_pods' `classes_out` for these pods, in this
+        order.  It plans the commit per pod class: a class's inputs
+        (_pod_commit_inputs) derive once, from its first pod here, which
+        is where add_pod would intern any new string; its other pods
+        share them, write their label columns once per class, and gather
+        their scatter rows from one row per class.  Class -1 pods derive
+        their own.  Returns the number of classes committed: 0 when every
+        pod derived its own inputs, as on the replacement path.
+
+        `rows`, where the caller already holds them, are the node rows the
+        pods are charged to: node_rows.get(pod.spec.node_name, -1) each,
+        which is what add_pods looks up without them.
+
         Equivalence is pinned by tests/test_batched_commit.py."""
         if not pods:
-            return
+            return 0
         # Replacement batches take the exact per-pod path: duplicate keys
         # within the batch would corrupt the two-pass layout, and replacing
         # already-resident keys would reorder the -old/+new float
@@ -1735,56 +1786,73 @@ class SnapshotEncoder:
         # the batched passes would group all removes first), breaking the
         # byte-identical contract in the low-order bits.  The hot path —
         # assuming a cycle's freshly-scheduled winners — never replaces.
-        batch_keys = [(p.namespace, p.name) for p in pods]
+        batch_keys = [(p.metadata.namespace, p.metadata.name) for p in pods]
         if len(set(batch_keys)) != len(batch_keys) or any(
             k in self.pods for k in batch_keys
         ):
             for pod in pods:
                 self.add_pod(pod)
-            return
+            return 0
         # -- pass 1: arena-slot allocation (growth first, so all later
         # vectorized writes target the final arrays)
-        ms: List[int] = []
-        for pod in pods:
-            if self._free_m:
-                m = self._free_m.pop()
-            else:
-                m = self._next_m
-                self._next_m += 1
-                if m >= self._cap_m:
-                    self._grow_pods()
-            ms.append(m)
+        # add_pod's order: freed slots off the list's end, then fresh
+        # ones, doubling the arena at each crossing
+        free_m = self._free_m
+        n_free = min(len(free_m), len(pods))
+        ms = [free_m.pop() for _ in range(n_free)]
+        n_new = len(pods) - n_free
+        ms += range(self._next_m, self._next_m + n_new)
+        self._next_m += n_new
+        while self._cap_m < self._next_m:
+            self._grow_pods()
         # -- pass 2: per-pod records + bookkeeping collection
-        recs: List[_PodRecord] = []
-        rows: List[int] = []
-        ns_ids: List[int] = []
+        if rows is None:
+            node_row_of = self.node_rows.get
+            rows = [node_row_of(p.spec.node_name, -1) for p in pods]
+        else:
+            rows = rows.tolist()
+        inputs: List[_CommitInputs] = []  # distinct, in first use order
+        src: List[int] = []          # per pod: its index into `inputs`
+        shared: Dict[int, int] = {}  # class -> index into `inputs`
+        members: Dict[int, List[int]] = {}  # shared input -> arena slots
         label_writes: Dict[int, Tuple[List[int], List[int]]] = {}
         touched_ports: Set[int] = set()
         touched_vols: Set[int] = set()
         vol_rows: Set[int] = set()
-        for pod, m in zip(pods, ms):
-            key = (pod.namespace, pod.name)
-            node_row = self.node_rows.get(pod.spec.node_name, -1)
-            rk = (
-                tuple(tuple(c.requests.items()) for c in pod.spec.containers),
-                () if not pod.spec.init_containers else tuple(
-                    tuple(c.requests.items())
-                    for c in pod.spec.init_containers
-                ),
-            )
-            hit = self._req_memo.get(rk)
-            if hit is None or hit[0].shape[0] != self.dims.R:
-                if len(self._req_memo) > 4096:
-                    self._req_memo.clear()
-                hit = (self._req_vector(pod.resource_request()), self._nonzero(pod))
-                self._req_memo[rk] = hit
-            req, nonzero = hit
-            ports = self._pod_ports(pod)
-            disk_check, disk_adv, vcounts, cnt_ids = self._pod_vols(pod)
+        cls = [-1] * len(pods) if classes is None else classes.tolist()
+        dims0 = self.dims
+        pods_map, row_pods = self.pods, self._row_pods
+        for j, pod in enumerate(pods):
+            m = ms[j]
+            key = batch_keys[j]
+            node_row = rows[j]
+            c = cls[j]
+            i = shared.get(c, -1) if c >= 0 else -1
+            fresh = i < 0
+            if fresh:
+                i = len(inputs)
+                inputs.append(self._pod_commit_inputs(pod))
+                if self.dims is not dims0:
+                    # a resource or volume column appeared: inputs derived
+                    # before it are narrower than add_pod's would now be
+                    shared.clear()
+                    dims0 = self.dims
+                if c >= 0:
+                    shared[c] = i
+                    members[i] = [m]
+                else:
+                    for kid, vid in inputs[i].label_ids:
+                        tgt = label_writes.setdefault(kid, ([], []))
+                        tgt[0].append(m)
+                        tgt[1].append(vid)
+            else:
+                members[i].append(m)
+            req, nonzero, ports, disk_adv, vcounts, cnt_ids = inputs[i][:6]
+            meta = pod.metadata
             rec = _PodRecord(
                 key=key,
-                labels=dict(pod.labels),
-                ns=pod.namespace,
+                labels=dict(meta.labels),
+                ns=key[0],
                 node_row=node_row,
                 m=m,
                 req=req,
@@ -1796,25 +1864,19 @@ class SnapshotEncoder:
                 priority=pod.spec.priority,
                 pod=pod,
                 start_time=pod.status.start_time,
-                uid=pod.metadata.uid,
+                uid=meta.uid,
             )
-            self.pods[key] = rec
-            recs.append(rec)
-            rows.append(node_row)
-            ns_ids.append(self.interner.intern(pod.namespace))
-            for k, v in pod.labels.items():
-                kid = self.interner.intern(k)
-                tgt = label_writes.setdefault(kid, ([], []))
-                tgt[0].append(m)
-                tgt[1].append(self.interner.intern(v))
-            # term registration stays IN the per-pod pass: it interns the
-            # term's selector/topology strings, and id assignment must
-            # follow add_pod's per-pod order (ns, labels, terms) or
-            # novel-string batches diverge from the per-pod loop in every
-            # interned-id-bearing tensor
-            self._register_pod_terms(pod, rec)
+            pods_map[key] = rec
+            src.append(i)
+            if fresh:
+                # term registration stays IN the per-pod pass: it interns
+                # the term's selector/topology strings, and id assignment
+                # must follow add_pod's per-pod order (ns, labels, terms).
+                # A class's later pods carry no affinity (the static key
+                # requires none), so theirs is a no-op.
+                self._register_pod_terms(pod, rec)
             if node_row >= 0:
-                self._row_pods.setdefault(node_row, set()).add(key)
+                row_pods.setdefault(node_row, set()).add(key)
                 if ports:
                     for pp_ip in ports:
                         self._node_ports[node_row][pp_ip] += 1
@@ -1838,27 +1900,36 @@ class SnapshotEncoder:
                     vol_rows.add(node_row)
         # -- pass 3: vectorized arena writes
         ms_arr = np.asarray(ms, np.intp)
+        src_arr = np.asarray(src, np.intp)
         self.p_alive[ms_arr] = True
-        self.p_ns[ms_arr] = np.asarray(ns_ids, np.int32)
+        self.p_ns[ms_arr] = np.asarray(
+            [inp.ns_id for inp in inputs], np.int32
+        )[src_arr]
         self.p_node[ms_arr] = np.asarray(rows, np.int32)
         for kid, (kms, vids) in label_writes.items():
-            col = self._label_cols.get(kid)
-            if col is None:
-                col = np.full(self._cap_m, PAD, np.int32)
-                self._label_cols[kid] = col
-            col[np.asarray(kms, np.intp)] = np.asarray(vids, np.int32)
+            self._label_cols[kid][np.asarray(kms, np.intp)] = np.asarray(
+                vids, np.int32
+            )
+        for i, cms in members.items():
+            cms_arr = np.asarray(cms, np.intp)
+            for kid, vid in inputs[i].label_ids:
+                self._label_cols[kid][cms_arr] = vid
         rows_arr = np.asarray(rows, np.intp)
         on_node = rows_arr >= 0
         if on_node.any():
-            req_stack = np.stack([r.req for r in recs])
-            nz_stack = np.stack([r.nonzero for r in recs])
-            np.add.at(self.a_requested, rows_arr[on_node], req_stack[on_node])
-            np.add.at(self.a_nonzero, rows_arr[on_node], nz_stack[on_node])
-            # tenant usage columns (ISSUE 14), same ordered-scatter shape
+            # one row per distinct input, gathered per pod: the [B, R]
+            # matrix np.stack over the records would build
+            req_stack = np.stack([inp.req for inp in inputs])[src_arr]
+            nz_stack = np.stack([inp.nonzero for inp in inputs])[src_arr]
             t_arr = np.asarray(
-                [self._ns_row(r.ns) for r in recs], np.intp
-            )
+                [inp.tenant for inp in inputs], np.intp
+            )[src_arr]
+            on_rows = rows_arr[on_node]
+            np.add.at(self.a_requested, on_rows, req_stack[on_node])
+            np.add.at(self.a_nonzero, on_rows, nz_stack[on_node])
+            # tenant usage columns, same ordered-scatter shape
             np.add.at(self.a_ns_usage, t_arr[on_node], req_stack[on_node])
+            self._dirty_pod_rows.update(on_rows.tolist())
         for row in vol_rows:
             cnts = self._node_cnt_vols[row]
             for t in range(self.dims.VT):
@@ -1867,10 +1938,9 @@ class SnapshotEncoder:
             self._rebuild_node_ports(row)
         for row in touched_vols:
             self._rebuild_node_vols(row)
-        for rec in recs:
-            self._mark_pod_dirty(rec.node_row)
         self._gc_dirty = True
         self.generation += len(pods)
+        return len(members)
 
     def remove_pod(self, pod: Pod) -> None:
         key = (pod.namespace, pod.name)
@@ -2538,9 +2608,17 @@ class SnapshotEncoder:
         finally:
             self._batch_width = prev
 
-    def encode_pods(self, pods: Sequence[Pod]) -> PodBatch:
+    def encode_pods(self, pods: Sequence[Pod],
+                    classes_out: Optional[np.ndarray] = None) -> PodBatch:
         """Encode pending pods into a PodBatch, precomputing the
-        inter-pod-affinity pair tensors against current cluster state."""
+        inter-pod-affinity pair tensors against current cluster state.
+
+        `classes_out`, an i32[len(pods)] filled with -1 by the caller,
+        receives each pod's class: pods with equal static keys
+        (_pod_static_key) share one index, numbered in order of first
+        appearance; pods with no static key (affinity, volumes, live
+        term_groups, unhashable content) stay -1.  add_pods takes the
+        same array to commit the winners class by class."""
         d = self.dims
         B = self.batch_pad(len(pods))
         if self._batch_width is None and B > d.B:
@@ -2736,11 +2814,15 @@ class SnapshotEncoder:
             self._pod_row_cache.clear()
             self._pod_cache_token = token
 
-        # cache-hit pods grouped by row key: one broadcast assignment per
-        # DISTINCT row per field instead of a per-pod python loop —
-        # controller-stamped workloads have ~20 distinct rows across
-        # thousands of pods, so this is ~100x fewer numpy calls
-        hit_groups: Dict[Tuple, List[int]] = {}
+        # pods grouped by static key into classes: each class's cache-hit
+        # pods take one broadcast assignment per field instead of a
+        # per-pod python loop — controller-stamped workloads have ~20
+        # distinct rows across thousands of pods, so this is ~100x fewer
+        # numpy calls.  The class index per pod is what `classes_out`
+        # hands the commit (add_pods), which shares the same classes.
+        class_of: Dict[Tuple, int] = {}
+        class_keys: List[Tuple] = []
+        hit_groups: List[List[int]] = []
         # CALL-LOCAL row sharing for the pods the cross-call cache must
         # refuse (affinity / live term_groups, where rows depend on cluster
         # state): within one encode_pods call the state is frozen (callers
@@ -2752,10 +2834,22 @@ class SnapshotEncoder:
         local_hits: Dict[int, List[int]] = {}
         for b, pod in enumerate(pods):
             ck = self._pod_static_key(pod)
-            cached = self._pod_row_cache.get(ck) if ck is not None else None
-            if cached is not None:
-                hit_groups.setdefault(ck, []).append(b)
-                continue
+            if ck is not None:
+                c = class_of.get(ck)
+                if c is None:
+                    c = class_of[ck] = len(class_keys)
+                    class_keys.append(ck)
+                    hit_groups.append([])
+                    # the class's first pod: a row-cache miss encodes
+                    # below and fills the cache for the rest of the class
+                    hit = ck in self._pod_row_cache
+                else:
+                    hit = True
+                if classes_out is not None:
+                    classes_out[b] = c
+                if hit:
+                    hit_groups[c].append(b)
+                    continue
             lk = self._pod_local_key(pod) if ck is None else None
             if lk is not None:
                 first = local_first.get(lk)
@@ -2878,7 +2972,9 @@ class SnapshotEncoder:
                 for b2 in idxs:
                     cnt_ids_by_b[b2] = cnt_ids_by_b[first]
 
-        for ck, idxs in hit_groups.items():
+        for ck, idxs in zip(class_keys, hit_groups):
+            if not idxs:
+                continue
             cached = self._pod_row_cache[ck]
             ia = np.asarray(idxs, np.intp)
             for k, v in cached.items():

@@ -35,7 +35,6 @@ placement).
 
 from __future__ import annotations
 
-import copy
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -90,14 +89,6 @@ class CpuEngineAdapter:
             w = DEFAULT_PRIORITY_WEIGHTS
         return dict(zip(PRIORITY_ORDER, np.asarray(w, np.float64).tolist()))
 
-    @staticmethod
-    def _assumed_copy(pod: Pod, node_name: str) -> Pod:
-        spec = copy.copy(pod.spec)
-        spec.node_name = node_name
-        assumed = copy.copy(pod)
-        assumed.spec = spec
-        return assumed
-
     # ------------------------------------------------------------- engine
 
     def schedule_batch(
@@ -127,7 +118,7 @@ class CpuEngineAdapter:
         hosts = np.full(len(pods), -1, np.int32)
         with self.cache._lock:
             nom_assumed = [
-                self._assumed_copy(p, node) for p, node in nominated
+                p.assumed_on(node) for p, node in nominated
             ]
             golden, nodes, row_of = self._golden(extra_pods=nom_assumed)
             name_of_row = {row_of[n.name]: n.name for n in nodes}
@@ -173,7 +164,7 @@ class CpuEngineAdapter:
                 hosts[i] = win_row
                 # in-batch sequential commit: later pods see this placement
                 win_name = name_of_row[win_row]
-                assumed = self._assumed_copy(pod, win_name)
+                assumed = pod.assumed_on(win_name)
                 golden.pods.append(assumed)
                 golden.by_node[win_name].append(assumed)
         return hosts

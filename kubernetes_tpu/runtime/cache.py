@@ -67,18 +67,23 @@ class SchedulerCache:
             self.encoder.add_pod(pod)
             self._assumed[key] = (pod, time.monotonic() + self.assume_ttl)
 
-    def assume_pods(self, pods) -> None:
+    def assume_pods(self, pods, classes=None, rows=None) -> int:
         """Batched AssumePod: one lock acquisition + one encoder delta
         apply for a whole commit batch (the per-pod loop held/released the
         lock and paid the numpy small-op overhead B times; the batched
-        encoder apply is state-equivalent — see encoder.add_pods)."""
+        encoder apply is state-equivalent — see encoder.add_pods, which
+        takes `classes` and `rows` and returns the number of classes
+        committed)."""
         if not pods:
-            return
+            return 0
         with self._lock:
             deadline = time.monotonic() + self.assume_ttl
-            self.encoder.add_pods(pods)
-            for pod in pods:
-                self._assumed[(pod.namespace, pod.name)] = (pod, deadline)
+            n_classes = self.encoder.add_pods(pods, classes, rows)
+            self._assumed.update(
+                ((p.metadata.namespace, p.metadata.name), (p, deadline))
+                for p in pods
+            )
+        return n_classes
 
     def forget_pod(self, pod: Pod) -> None:
         """Roll back an assumed pod (cache.go ForgetPod)."""

@@ -89,12 +89,15 @@ TAINT_NODE_UNSCHEDULABLE = "node.kubernetes.io/unschedulable"
 #   fence_device  } not started on this job / result not ready / the
 #   fence_copy    } copy landing and the checks after it (they sum to it)
 #   program_load  backend compiles + persistent-cache retrievals
+#   commit_state  } commit split: the state half (assume into the cache)
+#   commit_tail   } and the bind tail (binds, events, requeues)
 PARTITION_PHASES = (
     "pop", "encode", "dispatch", "host_stall", "commit", "preempt",
     "observe",
 )
 OVERLAP_PHASES = (
     "fetch", "fence_queue", "fence_device", "fence_copy", "program_load",
+    "commit_state", "commit_tail",
 )
 
 
@@ -541,6 +544,10 @@ class _InFlight:
     # the encoded batch's request matrix (host ref) — the regret
     # counterfactual's pod-side input
     quality_reqs: object = None
+    # i32[len(pods)] pod class per batch index, from encode_pods'
+    # classes_out (-1 = no static key): the state commit derives each
+    # class's encoder inputs once (encoder.add_pods)
+    classes: Optional[np.ndarray] = None
     # the snapshot refs the regret counterfactual packs into — set ONLY
     # when they are genuinely THIS cycle's pre-dispatch state: every
     # single cycle, but only sub-batch 0 of a megacycle (windows k>0
@@ -1208,11 +1215,18 @@ class Scheduler:
 
     def _phase_span(self, span: Optional[Span], name: str,
                     tier: str = TIER_BULK, child: Optional[str] = None,
-                    **attrs) -> PhaseSpan:
+                    part: Optional[str] = None, **attrs) -> PhaseSpan:
         """THE phase primitive (utils/trace.PhaseSpan): phase counter,
         cycle-span child (named `child`, default the phase) and, while
-        a profiler session is active, a `ktpu.<name>` annotation."""
-        return PhaseSpan(self._phase, name, tier, span, child, **attrs)
+        a profiler session is active, a `ktpu.<name>` annotation.  A
+        `part` key (commit_state, commit_tail) records the same seconds
+        as a sub-key of the phase, outside the partition."""
+        record = self._phase
+        if part is not None:
+            def record(phase, dt, tier_):
+                self._phase(phase, dt, tier_)
+                self._phase(part, dt, tier_)
+        return PhaseSpan(record, name, tier, span, child, **attrs)
 
     def _fence_split(self, fetch, fence: PhaseSpan, tier: str) -> None:
         """Split one fence wait (fence.t0..t1, already in host_stall) by
@@ -1828,7 +1842,8 @@ class Scheduler:
                     if len(pods) > 1 and batch_has_pod_affinity(pods)
                     else None
                 )
-                batch = enc.encode_pods(pods)
+                classes = np.full(len(pods), -1, np.int32)
+                batch = enc.encode_pods(pods, classes)
                 ports = encode_batch_ports(enc, pods)
                 # two-pass evaluation: nominated pods (other than those
                 # being scheduled now) are added to their nominated nodes
@@ -2070,6 +2085,7 @@ class Scheduler:
             width=batch.n_pods,
             enqueue_s=t_disp_end - t_cycle0,
             xfer0=xfer0,
+            classes=classes,
             reqs=batch.req if self._reconciler is not None else None,
         )
         if self._replica_of > 1:
@@ -2252,7 +2268,10 @@ class Scheduler:
                 else True
             )
             with self.cache._lock:
-                batches = [enc.encode_pods(w) for w in windows]
+                classes = [np.full(len(w), -1, np.int32) for w in windows]
+                batches = [
+                    enc.encode_pods(w, c) for w, c in zip(windows, classes)
+                ]
                 shapes = {
                     tuple(
                         np.asarray(leaf).shape
@@ -2263,7 +2282,10 @@ class Scheduler:
                 if len(shapes) > 1:
                     # a later window grew a sticky pad dim: one more pass
                     # encodes every window at the (now stable) max shapes
-                    batches = [enc.encode_pods(w) for w in windows]
+                    batches = [
+                        enc.encode_pods(w, c)
+                        for w, c in zip(windows, classes)
+                    ]
                 ports = [encode_batch_ports(enc, w) for w in windows]
                 cluster, generation = self.cache.snapshot()
                 # hub mode: the hub is the dirty stream's single consumer
@@ -2368,7 +2390,7 @@ class Scheduler:
                 pc=None, t_cycle0=t_cycle0, trace=spans[k],
                 relaunch=None, cpu_fetch=cpu_fetch,
                 degraded=degraded_dispatch, last_index0=li0[k],
-                tier=TIER_BULK,
+                tier=TIER_BULK, classes=classes[k],
                 quality_reqs=(
                     batches[k].req if self.quality is not None else None
                 ),
@@ -2695,7 +2717,9 @@ class Scheduler:
                     *(np.asarray(x) for x in inf.quality_dev)
                 )
         t_state0 = fence.t1
-        with self._phase_span(inf.trace, "commit", inf.tier) as commit:
+        with self._phase_span(
+            inf.trace, "commit", inf.tier, part="commit_state"
+        ) as commit:
             # "fetch" records the ASYNC window (dispatch -> copy landed,
             # stamped on the fetch worker): it overlaps the dispatch/commit
             # host phases.  The fence wait (host_stall) is split by what
@@ -2721,25 +2745,20 @@ class Scheduler:
                 staged.xfer_delta = transfer_delta(inf.xfer0)
             if not batched:
                 return staged
-            import copy
-
             enc = self.cache.encoder
+            row_name = enc.row_name
+            ext_failed = inf.ext_failed
             winners = staged.winners
-            for i, pod in enumerate(pods):
-                if i in inf.ext_failed:
+            for i, (pod, row) in enumerate(
+                zip(pods, hosts[: len(pods)].tolist())
+            ):
+                if i in ext_failed:
                     continue
-                row = int(hosts[i])
                 if row < 0:
                     staged.fit_idx.append(i)
                     continue
-                node_name = enc.row_name(row)
-                # shallow-copy + set beats two dataclasses.replace calls ~2x
-                # at 10k commits/s (Pod/PodSpec are plain mutable dataclasses)
-                spec = copy.copy(pod.spec)
-                spec.node_name = node_name
-                assumed = copy.copy(pod)
-                assumed.spec = spec
-                winners.append((i, pod, assumed, node_name))
+                node_name = row_name(row)
+                winners.append((i, pod, pod.assumed_on(node_name), node_name))
             if self._reconciler is not None:
                 # SEQUENCED optimistic-concurrency commit: the
                 # admission scan and the assume run as ONE critical section
@@ -2756,7 +2775,7 @@ class Scheduler:
                         staged.winners = winners = list(kept)
                     staged.race_lost = race_lost
                     staged.quota_lost = quota_lost
-                    self.cache.assume_pods([a for _, _, a, _ in winners])
+                    self._assume_winners(inf, winners, hosts, commit)
                     inf.gen_after = enc.generation
                     if self.invariants is not None and winners:
                         rows = sorted(
@@ -2777,7 +2796,7 @@ class Scheduler:
                 commit.attrs["winners"] = len(winners)
                 return staged
             # ONE lock acquisition + one encoder delta for the whole batch
-            self.cache.assume_pods([a for _, _, a, _ in winners])
+            self._assume_winners(inf, winners, hosts, commit)
             if self.invariants is not None and winners:
                 # capacity invariant over exactly the rows this batch
                 # committed to — O(batch), read under the cache lock so the
@@ -2792,6 +2811,27 @@ class Scheduler:
             commit.attrs["winners"] = len(winners)
             return staged
 
+    def _assume_winners(self, inf: _InFlight, winners: list, hosts,
+                        commit: PhaseSpan) -> None:
+        """Assume a batched commit's winners in one encoder delta, on the
+        rows the engine picked, class by class where encode gave the pods
+        classes, and count which path each pod took."""
+        idx = np.fromiter((w[0] for w in winners), np.intp, len(winners))
+        rows = hosts[idx]
+        # a row whose node left since dispatch has no name (row_name ""),
+        # so its pod is charged to no row, as a lookup by name would
+        rows = np.where(self.cache.encoder.a_valid[rows], rows, -1)
+        classes = inf.classes[idx] if inf.classes is not None else None
+        n_classes = self.cache.assume_pods(
+            [a for _, _, a, _ in winners], classes, rows
+        )
+        n_class = (
+            int(np.count_nonzero(classes >= 0)) if n_classes else 0
+        )
+        m.COMMIT_PODS.inc(n_class, path="class")
+        m.COMMIT_PODS.inc(len(winners) - n_class, path="per_pod")
+        commit.attrs["commit_classes"] = n_classes
+
     def _commit_tail(self, staged: _Staged) -> List[ScheduleResult]:
         """Side-effect tail of a cycle: binds, events, metrics, requeues,
         preemption.  Under pipeline_commit this overlaps the next batch's
@@ -2805,7 +2845,8 @@ class Scheduler:
         # all join back to this cycle's root span
         with use_traceparent(inf.trace):
             with self._phase_span(
-                inf.trace, "commit", inf.tier, child="bind-tail"
+                inf.trace, "commit", inf.tier, child="bind-tail",
+                part="commit_tail",
             ):
                 if staged.batched:
                     results, fit_errors = self._tail_batched(staged)
@@ -3577,6 +3618,7 @@ class Scheduler:
                 self.queue.add_unschedulable(pod, cycle)
                 return "failed"
         self.cache.assume_pod(assumed)
+        m.COMMIT_PODS.inc(path="per_pod")
         if fwk is not None and fwk.permit_plugins:
             status, wp, timeout = fwk.start_permit(pc, assumed, node_name)
             if wp is not None:

@@ -592,6 +592,19 @@ CYCLE_PHASE_SECONDS = REGISTRY.register(
     )
 )
 
+# pods the commit assumed, by how their encoder inputs were derived
+COMMIT_PODS = REGISTRY.register(
+    LabeledCounter(
+        "scheduler_commit_pods_total",
+        "Pods assumed by the scheduler's commit, by path: 'class' = the "
+        "pod's encoder inputs were derived once for its pod class (pods "
+        "with equal static keys in encode_pods) and shared, 'per_pod' = "
+        "derived for the pod alone (affinity, volumes, live term groups, "
+        "replacement batches, the per-pod commit mode)",
+        ("path",),
+    )
+)
+
 # device-resident megacycle (ISSUE 12): K pre-encoded batches chained
 # through the cluster state in one XLA launch (models/megacycle.py)
 MEGACYCLES = REGISTRY.register(

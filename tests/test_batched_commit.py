@@ -112,6 +112,33 @@ def test_add_pods_matches_sequential_add_pod():
         encs[1].interner.lookup("novel-sel-val")
 
 
+@pytest.mark.parametrize("resident,freed,batch", [
+    (0, 0, 70),     # fresh slots only, the arena doubles at 64
+    (10, 4, 3),     # freed slots only
+    (60, 5, 12),    # freed slots first, then fresh ones past the cap
+])
+def test_add_pods_arena_slots_follow_add_pod(resident, freed, batch):
+    """Arena slots: freed ones off the free list first, then fresh ones,
+    with the arena grown where add_pod would grow it."""
+    encs = [SnapshotEncoder(TEST_DIMS), SnapshotEncoder(TEST_DIMS)]
+    old = [make_pod(f"old-{i}", cpu="10m", mem="16Mi", node_name="n0")
+           for i in range(resident)]
+    new = [make_pod(f"new-{i}", cpu="10m", mem="16Mi", node_name="n0",
+                    labels={"app": "new"}) for i in range(batch)]
+    for enc in encs:
+        enc.add_node(make_node("n0", cpu="64", mem="256Gi", pods=500))
+        for p in old:
+            enc.add_pod(p)
+        for p in old[:freed]:
+            enc.remove_pod(p)
+    for p in new:
+        encs[0].add_pod(p)
+    encs[1].add_pods(new)
+    assert encs[0]._cap_m == encs[1]._cap_m
+    assert encs[0]._free_m == encs[1]._free_m
+    encoder_state_equal(encs[0], encs[1])
+
+
 def test_add_pods_duplicate_keys_in_one_batch():
     """Degenerate but legal: the same pod key twice in one batch.  The
     per-pod loop replaces the earlier record; the batched path must not
@@ -139,6 +166,308 @@ def test_add_pods_replaces_existing_records():
     encs[0].add_pod(updated)
     encs[1].add_pods([updated])
     encoder_state_equal(encs[0], encs[1])
+
+
+# ----------------------------------------------------- commit by pod class
+
+
+_ANTI_AFFINITY = {"podAntiAffinity": {
+    "requiredDuringSchedulingIgnoredDuringExecution": [{
+        "labelSelector": {"matchLabels": {"cls-sel-key": "cls-sel-val"}},
+        "topologyKey": "cls.example.com/topo",
+    }]}}
+
+
+def _controller_pods(prefix, n, ns="default", app="web", cpu="100m",
+                     **kw):
+    # controller-stamped: identical content but the name, so one class
+    return [
+        make_pod(f"{prefix}-{i}", namespace=ns, cpu=cpu, mem="128Mi",
+                 labels={"app": app, "tier": f"{app}-tier"}, **kw)
+        for i in range(n)
+    ]
+
+
+def _class_batch(case):
+    """A pending batch for one case, with the pods interleaved so that a
+    class's pods are not contiguous."""
+    a = _controller_pods("a", 4)
+    b = _controller_pods("b", 4, ns="other-ns", app="novel-app-b",
+                         cpu="250m")
+    extra = {
+        "two_controllers": [],
+        "host_port": [make_pod(
+            "hp", cpu="100m", mem="64Mi", labels={"app": "hp"},
+            ports=[{"hostPort": 8080, "protocol": "TCP"}])],
+        "volume": [make_pod(
+            "vol", cpu="100m", mem="64Mi", labels={"app": "vol"},
+            volumes=[{"gcePersistentDisk": {"pdName": "pd-cls"}}])],
+        "affinity": [make_pod(
+            "aff", cpu="100m", mem="64Mi", labels={"app": "aff-novel"},
+            affinity=_ANTI_AFFINITY)],
+        "init_container": _controller_pods(
+            "init", 3, app="init", init_requests=[{"cpu": "500m"}]),
+    }
+    if case == "mixed":
+        rest = [p for k in ("host_port", "volume", "affinity",
+                            "init_container") for p in extra[k]]
+    else:
+        rest = extra.get(case, [])
+    pods = []
+    for i in range(max(len(a), len(b), len(rest))):
+        for group in (a, b, rest):
+            if i < len(group):
+                pods.append(group[i])
+    return pods
+
+
+def _class_encoders(live_term_groups):
+    encs = [SnapshotEncoder(TEST_DIMS), SnapshotEncoder(TEST_DIMS)]
+    for enc in encs:
+        for i in range(4):
+            enc.add_node(make_node(
+                f"n{i}", cpu="16", mem="32Gi",
+                labels={ZONE_KEY: f"z-{i % 2}"},
+            ))
+        enc.add_spread_selector("default", {"app": "web"})
+        if live_term_groups:
+            # a resident pod with affinity terms: every pending pod's
+            # static key is None, so every pod commits per pod
+            enc.add_pod(make_pod(
+                "resident", cpu="100m", mem="64Mi", labels={"app": "r"},
+                node_name="n0", affinity=_ANTI_AFFINITY,
+            ))
+    return encs
+
+
+@pytest.mark.parametrize("case", [
+    "two_controllers", "host_port", "volume", "affinity", "init_container",
+    "term_groups", "mixed",
+])
+@pytest.mark.parametrize("given_rows", [False, True])
+def test_class_add_pods_matches_add_pod_loop(case, given_rows):
+    """Class-aware add_pods (the classes encode_pods handed out, and the
+    node rows where the caller passes them) leaves arena state, interner
+    ids, generation and dirty rows byte-identical to the add_pod loop.
+    The last pod names a node the arena does not hold: no row."""
+    encs = _class_encoders(live_term_groups=case == "term_groups")
+    pending = _class_batch("mixed" if case == "term_groups" else case)
+    classes = np.full(len(pending), -1, np.int32)
+    encs[0].encode_pods(pending)
+    encs[1].encode_pods(pending, classes)
+    nodes = [f"n{i % 4}" for i in range(len(pending) - 1)] + ["n-gone"]
+    assumed = [p.assumed_on(n) for p, n in zip(pending, nodes)]
+    rows = None
+    if given_rows:
+        rows = np.array([encs[1].node_rows.get(n, -1) for n in nodes],
+                        np.int32)
+        assert rows[-1] == -1 and (rows[:-1] >= 0).all()
+    for enc in encs:
+        enc.snapshot()
+        enc.take_dirty_rows()
+    for p in assumed:
+        encs[0].add_pod(p)
+    n_classes = encs[1].add_pods(assumed, classes, rows)
+
+    keyed = classes >= 0
+    if case == "term_groups":
+        assert not keyed.any() and n_classes == 0
+    else:
+        assert n_classes == len(set(classes[keyed].tolist())) >= 2
+        for p, c in zip(pending, classes):
+            per_pod = bool(p.spec.affinity or p.spec.volumes)
+            assert (c < 0) == per_pod, p.name
+    assert encs[0]._dirty_pod_rows == encs[1]._dirty_pod_rows
+    encoder_state_equal(encs[0], encs[1])
+    assert encs[0].interner._strs == encs[1].interner._strs
+    assert list(encs[0]._label_cols) == list(encs[1]._label_cols)
+    for kid, col in encs[0]._label_cols.items():
+        np.testing.assert_array_equal(col, encs[1]._label_cols[kid])
+    assert encs[0].ns_rows == encs[1].ns_rows
+    np.testing.assert_array_equal(encs[0].a_ns_usage, encs[1].a_ns_usage)
+    for key, r0 in encs[0].pods.items():
+        r1 = encs[1].pods[key]
+        assert (r0.labels, r0.ports, r0.disk_vols, r0.group_refs) == \
+            (r1.labels, r1.ports, r1.disk_vols, r1.group_refs), key
+        np.testing.assert_array_equal(r0.vol_counts, r1.vol_counts)
+
+
+def test_class_add_pods_ids_follow_first_pod_of_class():
+    """Novel strings are interned when their class is first seen in batch
+    order: a per-pod pod between two classes gets its ids in between."""
+    encs = _class_encoders(live_term_groups=False)
+    pending = [
+        *_controller_pods("x", 2, app="first-novel"),
+        make_pod("aff", cpu="100m", mem="64Mi", labels={"app": "mid-novel"},
+                 affinity=_ANTI_AFFINITY),
+        *_controller_pods("y", 2, app="last-novel"),
+    ]
+    classes = np.full(len(pending), -1, np.int32)
+    encs[1].encode_pods(pending, classes)
+    encs[0].encode_pods(pending)
+    assert classes.tolist() == [0, 0, -1, 1, 1]
+    assumed = [p.assumed_on("n1") for p in pending]
+    for p in assumed:
+        encs[0].add_pod(p)
+    encs[1].add_pods(assumed, classes)
+    ids = [encs[1].interner.lookup(s) for s in
+           ("first-novel", "mid-novel", "last-novel")]
+    assert ids == sorted(ids)
+    assert encs[0].interner._strs == encs[1].interner._strs
+    encoder_state_equal(encs[0], encs[1])
+
+
+def test_class_add_pods_volume_column_regrow_mid_batch():
+    """A CSI pod with a driver no column exists for, between two pods of
+    one class, widens the volume-count axis mid-batch: the class's later
+    pods must get the widened record vectors add_pod would give them."""
+    from kubernetes_tpu.api.storage import (
+        PersistentVolume, PersistentVolumeClaim,
+    )
+
+    encs = _class_encoders(live_term_groups=False)
+    for enc in encs:
+        enc.add_pv(PersistentVolume.from_dict({
+            "metadata": {"name": "pv-fresh"},
+            "spec": {"capacity": {"storage": "1Gi"},
+                     "accessModes": ["ReadWriteOnce"],
+                     "csi": {"driver": "fresh-driver",
+                             "volumeHandle": "h"}},
+        }))
+        enc.add_pvc(PersistentVolumeClaim.from_dict({
+            "metadata": {"name": "c-fresh", "namespace": "default"},
+            "spec": {"volumeName": "pv-fresh"},
+        }))
+    a = _controller_pods("a", 4)
+    csi = make_pod("csi", cpu="100m", mem="64Mi", volumes=[
+        {"persistentVolumeClaim": {"claimName": "c-fresh"}}])
+    pending = a[:2] + [csi] + a[2:]
+    # classes from another encoder's encode, so the column first appears
+    # inside add_pods here
+    classes = np.full(len(pending), -1, np.int32)
+    SnapshotEncoder(TEST_DIMS).encode_pods(pending, classes)
+    assert classes.tolist() == [0, 0, -1, 0, 0]
+    vt0 = encs[1].dims.VT
+    assumed = [p.assumed_on(f"n{i % 4}") for i, p in enumerate(pending)]
+    for p in assumed:
+        encs[0].add_pod(p)
+    encs[1].add_pods(assumed, classes)
+    assert encs[1].dims.VT > vt0
+    encoder_state_equal(encs[0], encs[1])
+    assert encs[0].interner._strs == encs[1].interner._strs
+    for key, r0 in encs[0].pods.items():
+        np.testing.assert_array_equal(r0.vol_counts,
+                                      encs[1].pods[key].vol_counts)
+    np.testing.assert_array_equal(encs[0].a_volcnt, encs[1].a_volcnt)
+
+
+@pytest.mark.parametrize("kind", ["plain", "affinity", "volume", "labels"])
+def test_assumed_on_matches_copy(kind):
+    """Pod.assumed_on is copy.copy of the pod and of its spec with
+    node_name set: equal field for field, sharing what copy.copy shares,
+    and never the PodSpec."""
+    import copy
+
+    kw = {
+        "plain": {},
+        "affinity": {"affinity": _ANTI_AFFINITY},
+        "volume": {"volumes": [{"gcePersistentDisk": {"pdName": "pd"}}]},
+        "labels": {"labels": {"app": "a", "tier": "t"},
+                   "node_selector": {ZONE_KEY: "z-0"},
+                   "ports": [{"hostPort": 80, "protocol": "TCP"}]},
+    }[kind]
+    pod = make_pod("orig", cpu="100m", mem="64Mi", **kw)
+    got = pod.assumed_on("n7")
+    spec = copy.copy(pod.spec)
+    spec.node_name = "n7"
+    want = copy.copy(pod)
+    want.spec = spec
+    assert type(got) is type(want) and type(got.spec) is type(want.spec)
+    assert got == want
+    assert got.spec is not pod.spec and got.spec.node_name == "n7"
+    assert pod.spec.node_name == ""
+    for f in dataclasses.fields(pod):
+        if f.name != "spec":
+            assert getattr(got, f.name) is getattr(pod, f.name), f.name
+    for f in dataclasses.fields(pod.spec):
+        if f.name != "node_name":
+            assert getattr(got.spec, f.name) is getattr(pod.spec, f.name)
+    assert vars(got.spec) == vars(want.spec) and vars(got) == vars(want)
+
+
+def test_assume_winners_rows_of_a_removed_node():
+    """The batched commit hands add_pods the engine's rows; a row whose
+    node left after dispatch charges no row, as add_pod's lookup of the
+    assumed pod's node name ("") does."""
+    import types
+
+    sched = _mk_scheduler(batched=True, with_extender=False)
+    enc = sched.cache.encoder
+    ref = SnapshotEncoder(TEST_DIMS)
+    for i in range(6):
+        ref.add_node(make_node(f"n{i}", cpu="4", mem="8Gi", pods=20,
+                               labels={ZONE_KEY: f"z-{i % 2}"}))
+    pods = _controller_pods("w", 5)
+    classes = np.full(len(pods), -1, np.int32)
+    enc.encode_pods(pods, classes)
+    ref.encode_pods(pods)
+    hosts = np.array([enc.node_rows[n] for n in
+                      ("n0", "n2", "n1", "n2", "n0")] + [-1] * 3, np.int32)
+    for e in (enc, ref):
+        e.remove_node("n2")
+    winners = []
+    for i, pod in enumerate(pods):
+        name = enc.row_name(int(hosts[i]))
+        winners.append((i, pod, pod.assumed_on(name), name))
+    assert [w[3] for w in winners] == ["n0", "", "n1", "", "n0"]
+    commit = types.SimpleNamespace(attrs={})
+    sched._assume_winners(types.SimpleNamespace(classes=classes), winners,
+                          hosts, commit)
+    for _, _, assumed, _ in winners:
+        ref.add_pod(assumed)
+    assert commit.attrs["commit_classes"] == 1
+    assert [enc.pods[("default", f"w-{i}")].node_row for i in range(5)] \
+        == [ref.pods[("default", f"w-{i}")].node_row for i in range(5)]
+    encoder_state_equal(ref, enc)
+
+
+@pytest.mark.parametrize("case,want", [
+    ("plain", (6, 0, 3)),
+    ("affinity", (5, 1, 2)),
+    ("volume", (5, 1, 2)),
+    ("per_pod_commit", (0, 6, 0)),
+])
+def test_commit_path_counter(case, want):
+    """scheduler_commit_pods_total{path} counts each committed winner
+    once, by how its encoder inputs were derived; the commit span carries
+    commit_classes, and commit_state + commit_tail split commit."""
+    from kubernetes_tpu.utils import metrics as m
+
+    sched = _mk_scheduler(batched=case != "per_pod_commit",
+                          with_extender=False)
+    pods = _controller_pods("c", 3) + _controller_pods(
+        "d", 2, ns="other-ns", app="d")
+    pods.append({
+        "plain": make_pod("lone", cpu="100m", mem="64Mi", labels={"app": "c"}),
+        "per_pod_commit": make_pod("lone", cpu="100m", mem="64Mi"),
+        "affinity": make_pod("aff", cpu="100m", mem="64Mi",
+                             labels={"app": "aff"}, affinity=_ANTI_AFFINITY),
+        "volume": make_pod("vol", cpu="100m", mem="64Mi",
+                           volumes=[{"gcePersistentDisk": {"pdName": "pd"}}]),
+    }[case])
+    before = [m.COMMIT_PODS.value(path=p) for p in ("class", "per_pod")]
+    results = sched.schedule_cycle(pods)
+    assert all(r.node for r in results), results
+    got = [m.COMMIT_PODS.value(path=p) - b
+           for p, b in zip(("class", "per_pod"), before)]
+    assert got == list(want[:2])
+    ph = sched.phase_seconds
+    if case != "per_pod_commit":
+        commit = sched.flight_recorder.spans()[-1].find("commit")
+        assert commit.attrs["commit_classes"] == want[2]
+    assert ph["commit_state"] > 0 and ph["commit_tail"] > 0
+    assert ph["commit_state"] + ph["commit_tail"] == pytest.approx(
+        ph["commit"], rel=1e-9)
 
 
 # ------------------------------------------------------- commit equivalence

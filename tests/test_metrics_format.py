@@ -223,9 +223,20 @@ def test_metrics_exposition_valid_after_mixed_live_workload():
         for _, lbl, v in
         families["scheduler_cycle_phase_seconds_total"]["samples"]
     }
-    for phase in ("pop", "encode", "dispatch", "commit"):
+    for phase in ("pop", "encode", "dispatch", "commit", "commit_state",
+                  "commit_tail"):
         assert phase in phases, f"phase {phase} missing from /metrics"
     assert phases["encode"] > 0.0
+    # the commit-path family: both series are exposed, and the placed
+    # pods (plain, so each has a pod class) took the class path
+    assert families["scheduler_commit_pods_total"]["type"] == "counter"
+    commit_paths = {
+        lbl["path"]: v
+        for _, lbl, v in
+        families["scheduler_commit_pods_total"]["samples"]
+    }
+    assert set(commit_paths) == {"class", "per_pod"}, commit_paths
+    assert commit_paths["class"] >= 2, commit_paths
     # ISSUE 7 satellites: the unschedulable pod fed the per-plugin
     # reasons family through the attribution path, and the ledger
     # accounted its cycles (ring-only here — bytes/dropped expose as
