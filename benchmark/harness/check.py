@@ -1,5 +1,6 @@
 """What decides `correct`: the placements the timed path committed,
-against the plain reference and against the configuration's guarantees.
+against the plain reference of the configuration's shape and against the
+configuration's guarantees.
 
 Every popped batch, warm-up included, is replayed into the reference's
 history in the order the engine saw it, and every one is compared.
@@ -8,6 +9,11 @@ Every number compared is exact, so each limit is 0.
 The control is the reference in bfloat16 put in the program's place:
 `control_placements` makes its answers for the same popped batches, and
 they go through the same comparison.
+
+The shape is the module benchmark/shapes/<shape>.py: its
+`Reference(world, dtype)` places a popped batch from the batch's stream
+entries (`place`) and takes the batch as committed (`commit`), and its
+`LIMITS` name the numbers its `guarantees` return.
 """
 
 from __future__ import annotations
@@ -16,22 +22,24 @@ from typing import Dict, List
 
 import numpy as np
 
-from benchmark.harness.reference import Reference
-
 LIMITS = {
     "placement_mismatches": 0,
     "failed_pods": 0,
     "degraded_cycles": 0,
-    "overcommitted_nodes": 0,
 }
 
 
-def compare(world, batches: List[List[int]], ctl_of: np.ndarray,
+def limits(shape) -> dict:
+    """Every number compared for a cell of this shape, with its limit."""
+    return {**LIMITS, **shape.LIMITS}
+
+
+def compare(shape, world, batches: List[List[int]], ctl_of: np.ndarray,
             node_of: Dict[int, int]) -> dict:
     """Replay `batches` (pod ids in pop order); node_of maps a bound pod
     id to its node id.  Returns the mismatches, how many pods and batches
     were compared, and which rule decided them."""
-    ref = Reference(world, dtype=np.float32)
+    ref = shape.Reference(world, dtype=np.float32)
     mism = 0
     how: Dict[str, int] = {}
     n_pods = 0
@@ -47,11 +55,11 @@ def compare(world, batches: List[List[int]], ctl_of: np.ndarray,
             "rules": how}
 
 
-def control_placements(world, batches: List[List[int]],
+def control_placements(shape, world, batches: List[List[int]],
                        ctl_of: np.ndarray, dtype) -> Dict[int, int]:
     """The reference in `dtype`, placing the same popped batches on its
     own history: pod id -> node id for every pod it placed."""
-    ref = Reference(world, dtype=dtype)
+    ref = shape.Reference(world, dtype=dtype)
     out: Dict[int, int] = {}
     for batch in batches:
         ctls = ctl_of[np.asarray(batch, np.int64)]
@@ -59,14 +67,3 @@ def control_placements(world, batches: List[List[int]],
         out.update((p, int(h)) for p, h in zip(batch, hosts) if h >= 0)
         ref.commit(ctls, hosts)
     return out
-
-
-def guarantees(world, node_of: Dict[int, int]) -> dict:
-    """The configuration's guarantees over every bound pod, from the
-    world's arrays alone."""
-    nodes = np.fromiter(node_of.values(), np.int64, len(node_of))
-    cnt = np.bincount(nodes, minlength=world.n)
-    over = ((cnt * world.pod_cpu > world.cpu)
-            | (cnt * world.pod_mem > world.mem)
-            | (cnt > world.pods))
-    return {"overcommitted_nodes": int(over.sum())}

@@ -1,26 +1,16 @@
-"""The measured window: the benchmark's seams around the live path.
+"""The measured window's seams around the live path, for every traffic
+kind (benchmark/kinds/<kind>.py drives the window through these).
 
 One thread drives everything: pods go to `PriorityQueue.add` between
 `Scheduler.run_once` calls, the binder records each bind, and a queue
 subclass records each popped batch (the order the engine saw it in).
 No thread or child is started.
-
-  backlog  the queue is topped up to `lead_batches` full batches before
-           every cycle; the window opens at the first add and closes at
-           the end of the first cycle that ends after `seconds` (its
-           binds are the first bind-commit after the deadline);
-  poisson  pods are due at open-loop arrival times; each is added at the
-           first cycle boundary after it is due and timed from its due
-           time to its bind; every pod due in the window is followed to
-           its bind, up to `follow_s` past the close.
 """
 
 from __future__ import annotations
 
 import time
 from typing import Dict, List
-
-import numpy as np
 
 clock = time.perf_counter
 
@@ -146,80 +136,3 @@ class Tracer:
 
         jax.profiler.stop_trace()
         self.active = False
-
-
-def backlog_window(sched, queue, seams, pods, metrics_mod, traffic, seconds,
-                   batch, tracer: Tracer, snap_fn) -> dict:
-    """Keep `lead_batches` full batches queued; measure `seconds`."""
-    lead = int(traffic.get("lead_batches", 3)) * batch
-    n = len(pods)
-    added = 0
-    t_open = clock()
-    tracer.start(snap_fn)
-    exhausted = None
-    while True:
-        pending = added - sum(len(b) for b in queue.popped[seams.n_pop0:])
-        if pending < lead and added < n:
-            k = min(lead - pending, n - added)
-            with _annotate("bench.add"):
-                for p in pods[added:added + k]:
-                    queue.add(p)
-            added += k
-        if added >= n and pending == 0:
-            exhausted = "backlog"
-            break
-        rec = run_cycle(sched, queue, seams, metrics_mod)
-        tracer.maybe_stop(snap_fn)
-        if rec["t1"] - t_open >= seconds:
-            break
-        if rec["popped"] and rec["placed"] == 0:
-            exhausted = "cluster"
-            break
-    t_close = clock()
-    tracer.maybe_stop(snap_fn, force=True)
-    return {"t_open": t_open, "t_close": t_close, "added": added,
-            "exhausted": exhausted}
-
-
-def poisson_window(sched, queue, seams, pods, offsets, metrics_mod,
-                   seconds, follow_s, tracer: Tracer, snap_fn) -> dict:
-    """Open loop: pod i is due at t_open + offsets[i]."""
-    n = len(pods)
-    t_open = clock()
-    due = t_open + np.asarray(offsets, np.float64)
-    nxt = 0
-    lag = []
-    tracer.start(snap_fn)
-    deadline = t_open + seconds + follow_s
-    queued_at_close = None
-    while True:
-        now = clock()
-        if queued_at_close is None and now >= t_open + seconds:
-            # pods due by the close that the scheduler has not popped yet:
-            # about one cycle's arrivals below the knee, growing above it
-            queued_at_close = int((due <= t_open + seconds).sum()) - sum(
-                len(b) for b in queue.popped[seams.n_pop0:])
-        if nxt < n and due[nxt] <= now:
-            with _annotate("bench.add"):
-                while nxt < n and due[nxt] <= now:
-                    queue.add(pods[nxt])
-                    lag.append(now - due[nxt])
-                    nxt += 1
-        popped = sum(len(b) for b in queue.popped[seams.n_pop0:])
-        if popped < nxt:
-            run_cycle(sched, queue, seams, metrics_mod)
-            tracer.maybe_stop(snap_fn)
-        elif nxt >= n:
-            break
-        else:
-            tracer.maybe_stop(snap_fn)
-            wait = due[nxt] - clock()
-            if wait > 0:
-                time.sleep(min(wait, 0.05))
-        if clock() > deadline:
-            break
-    t_close = clock()
-    tracer.maybe_stop(snap_fn, force=True)
-    return {"t_open": t_open, "t_close": t_close, "added": nxt,
-            "due": due, "lag": np.asarray(lag),
-            "queued_at_close": queued_at_close}

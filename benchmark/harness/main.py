@@ -4,43 +4,32 @@ Set-up (everything before the window opens, all counted in setup_s):
 world and pods, the Scheduler through the deployment path
 (KubeSchedulerConfiguration.from_dict -> SchedulerConfig.from_component_
 config), the configuration's existing pods, and warm-up batches at every
-width the window can meet.  The window is drive.py's.  After it: the
-device's peak memory, then the check (not counted anywhere), then the
-result line.
+width the window can meet.  The window is the traffic kind's.  After it:
+the device's peak memory, then the check (not counted anywhere), then
+the result line.
+
+What is particular to a configuration comes from its shape
+(benchmark/shapes/<shape>.py: world, stream, pods, encoder, capacity,
+warm-up widths, reference and guarantees), and what is particular to a
+mix from its kind (benchmark/kinds/<kind>.py: window size, window,
+end-to-end numbers); both are found by name (byname.py).
 """
 
 from __future__ import annotations
 
 import argparse
 import gc
-import importlib.util
 import json
 import os
 import sys
 from typing import Dict, List
 
-import numpy as np
-
+from benchmark.harness import byname
 from benchmark.harness import check as chk
 from benchmark.harness import drive, tracereduce
-from benchmark.harness.traffic import (
-    backlog_size,
-    burst_offsets,
-    load_traffic,
-    poisson_offsets,
-)
-from benchmark.harness.world import (
-    build_encoder,
-    build_world,
-    controller_of,
-    load_config,
-    pod_object,
-)
 
-# full batches of the test controller's pods in the warm-up
-WARM_FULL = 3
-# then single pods until this many cycles in a row load no program, at
-# most WARM_SINGLES of them
+# after the shape's warm-up batches, single pods until this many cycles
+# in a row load no program, at most WARM_SINGLES of them
 WARM_QUIET = 40
 WARM_SINGLES = 400
 # the traced span of a --trace 1 run
@@ -134,15 +123,6 @@ def enable_cache(root: str) -> str:
     return d
 
 
-def load_reader(root: str, name: str):
-    path = os.path.join(root, "benchmark", "metrics", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
-
-
 def cell_metrics(bench: dict, cell: str, trace: bool) -> List[dict]:
     """The metrics this cell reports: its end-to-end ones with --trace 0,
     its per-layer ones with --trace 1."""
@@ -176,8 +156,10 @@ def main(argv, root: str, t_start: float) -> int:
     if args.workload not in cells:
         raise SystemExit(f"benchmark: no workload {args.workload!r}")
     cell = cells[args.workload]
-    cfg = load_config(root, cell["config"])
-    traffic = load_traffic(root, cell["traffic"])
+    cfg = byname.load_config(root, cell["config"])
+    traffic = byname.load_traffic(root, cell["traffic"])
+    shape = byname.load(root, "shapes", cfg.get("shape", "plain"))
+    kind = byname.load(root, "kinds", traffic["kind"])
     device = require_device(int(cell["chips"]))
     cache_dir = enable_cache(root)
     compiles = CompileCounter()
@@ -189,43 +171,32 @@ def main(argv, root: str, t_start: float) -> int:
 
     t_import = drive.clock() - t_start
     seed = args.seed
-    world = build_world(cfg)
+    world = shape.build_world(cfg)
     kcfg = KubeSchedulerConfiguration.from_dict(cfg["scheduler"])
     batch = int(kcfg.batch_size)
 
     # ---- the stream: the configuration's existing pods (scheduled first,
     # as upstream does), then the warm-up's test pods, then the window's.
-    # The warm-up serves every batch width the window can meet: WARM_FULL
-    # full batches, then a batch of one pod and of 2**k + 1 pods for each
-    # power of two below batchSize, so that every power-of-two bucket up
-    # to batchSize is reached, and one more single pod (a batch's rows
-    # upload at the next cycle); then single pods until WARM_QUIET cycles
+    # The warm-up serves every batch width the window can meet: the
+    # shape's warm-up batches, then single pods until WARM_QUIET cycles
     # in a row load no program, so that side launches made every so many
     # cycles are warm too.
-    kind = traffic["kind"]
-    warm_sizes = ([world.existing] + [batch] * WARM_FULL + [1]
-                  + [(1 << k) + 1 for k in range(batch.bit_length() - 1)]
-                  + [1])
+    warm_sizes = shape.warm_sizes(world, batch)
     n_warm = sum(warm_sizes) + WARM_SINGLES
-    if kind == "backlog":
-        n_win = backlog_size(traffic, args.seconds,
-                             world.capacity() - n_warm)
-        offsets = None
-    else:
-        offsets = (poisson_offsets(traffic, args.seconds, seed)
-                   if kind == "poisson" else
-                   burst_offsets(traffic, args.seconds))
-        n_win = len(offsets)
+    cap = shape.capacity(world)
+    n_win, plan = kind.window_pods(traffic, args.seconds, seed,
+                                   cap - n_warm)
     n_total = n_warm + n_win
-    if n_total > world.capacity():
+    if n_total > cap:
         raise SystemExit("benchmark: the stream outgrows the cluster")
-    ctl_of = controller_of(world, n_total)
+    ctl_of = shape.stream(world, n_total)
     t0 = drive.clock()
-    pods = [pod_object(world, cfg, i, ctl_of[i]) for i in range(n_total)]
+    pods = [shape.pod_object(world, cfg, i, ctl_of[i])
+            for i in range(n_total)]
     t_pods = drive.clock() - t0
 
     t0 = drive.clock()
-    enc = build_encoder(world, cfg)
+    enc = shape.build_encoder(world, cfg)
     queue = drive.make_queue()
     seams = drive.Seams()
     sched = Scheduler(
@@ -286,14 +257,8 @@ def main(argv, root: str, t_start: float) -> int:
                   "compile": comp0, "cache_dir": cache_dir})
 
     # ---- the window
-    if kind == "backlog":
-        w = drive.backlog_window(sched, queue, seams, win_pods, m, traffic,
-                                 args.seconds, batch, tracer, snap)
-    else:
-        w = drive.poisson_window(sched, queue, seams, win_pods, offsets, m,
-                                 args.seconds,
-                                 float(traffic.get("follow_s", 60.0)),
-                                 tracer, snap)
+    w = kind.window(sched, queue, seams, win_pods, plan, m, traffic,
+                    args.seconds, batch, tracer, snap)
     comp1 = compiles.snap()
     gc.unfreeze()
     snap1 = snap()
@@ -313,28 +278,9 @@ def main(argv, root: str, t_start: float) -> int:
     wcycles = seams.cycles[n_cyc0:]
     failed_names = set(seams.failed)
     e2e: Dict[str, float] = {"setup_s": setup_s}
-    if kind == "backlog":
-        attempted_names = popped_win
-        bound_in = sum(1 for n in popped_win if n in seams.bound
-                       and seams.bind_t[n] <= w["t_close"])
-        e2e["pods_per_s"] = bound_in / (w["t_close"] - w["t_open"])
-        if w["exhausted"]:
-            log("window", {"closed_early_by": w["exhausted"]})
-    else:
-        attempted_names = win_names
-        due = w["due"]
-        lat = np.empty(len(win_names))
-        for i, n in enumerate(win_names):
-            t_b = seams.bind_t.get(n)
-            lat[i] = (t_b if t_b is not None else w["t_close"]) - due[i]
-        e2e["e2e_p50_ms"] = float(np.percentile(lat, 50) * 1e3)
-        e2e["e2e_p99_ms"] = float(np.percentile(lat, 99) * 1e3)
-        log("window", {"generator_lag_ms_p99":
-                       float(np.percentile(w["lag"], 99) * 1e3)
-                       if len(w["lag"]) else 0.0,
-                       "pods": len(win_names),
-                       "queued_at_close": w["queued_at_close"],
-                       "rate_per_s": len(win_names) / args.seconds})
+    kind_e2e, attempted_names = kind.end_to_end(w, seams, win_names,
+                                                popped_win, args.seconds, log)
+    e2e.update(kind_e2e)
     unbound = [n for n in attempted_names if n not in seams.bound]
     failed = len(set(unbound) | (failed_names & set(attempted_names)))
     degraded = sum(1 for c in seams.cycles if c["degraded"])
@@ -372,7 +318,7 @@ def main(argv, root: str, t_start: float) -> int:
             breakdown = {"device_ops": red["device_ops"],
                          "idle_gaps": red["idle_gaps"]}
         for mdef in cell_metrics(bench, args.workload, True):
-            val = load_reader(root, mdef["name"])(run)
+            val = byname.load(root, "metrics", mdef["name"]).read(run)
             if val is not None:
                 metrics_out[mdef["name"]] = {"value": float(val),
                                              "unit": mdef["unit"]}
@@ -396,20 +342,21 @@ def main(argv, root: str, t_start: float) -> int:
     if args.control:
         import ml_dtypes
 
-        node_of = chk.control_placements(world, batches, ctl_of,
+        node_of = chk.control_placements(shape, world, batches, ctl_of,
                                          ml_dtypes.bfloat16)
-    cmp = chk.compare(world, batches, ctl_of, node_of)
+    cmp = chk.compare(shape, world, batches, ctl_of, node_of)
     numbers = {
         "placement_mismatches": cmp["mismatches"],
         "failed_pods": failed,
         "degraded_cycles": degraded,
-        **chk.guarantees(world, node_of),
+        **shape.guarantees(world, node_of),
     }
     ref_s = drive.clock() - t0
     log("reference", {"seconds": ref_s, "pods": cmp["pods"],
                       "batches": cmp["batches"], "rules": cmp["rules"],
                       "control": "bfloat16" if args.control else None})
-    checks = {k: {"value": v, "limit": chk.LIMITS[k]}
+    limits = chk.limits(shape)
+    checks = {k: {"value": v, "limit": limits[k]}
               for k, v in numbers.items()}
     correct = all(v["value"] <= v["limit"] for v in checks.values())
     out = {

@@ -6,9 +6,10 @@ between chips to leave out."""
 import numpy as np
 import pytest
 
-from benchmark.tests.conftest import run_cell
+from benchmark.tests.conftest import TEST_CELL, run_cell
 
-CELLS = ["plain-5k.backlog", "plain-5k.bursts"]
+# the committed cells, and a cell that a test root adds as new files
+CELLS = ["plain-5k.backlog", "plain-5k.bursts", TEST_CELL]
 
 
 def _check(err, name):

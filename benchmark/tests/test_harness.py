@@ -9,10 +9,11 @@ import numpy as np
 import pytest
 
 from benchmark.harness import tracereduce
-from benchmark.harness.traffic import poisson_offsets
-from benchmark.tests.conftest import ROOT, run_cell
+from benchmark.kinds.poisson import poisson_offsets
+from benchmark.tests.conftest import ROOT, TEST_CELL, run_cell
 
-CELLS = ["plain-5k.backlog", "plain-5k.bursts"]
+# the committed cells, and a cell that a test root adds as new files
+CELLS = ["plain-5k.backlog", "plain-5k.bursts", TEST_CELL]
 KEYS = {"correct", "attempted", "failed", "metrics", "device"}
 
 
@@ -23,7 +24,7 @@ def test_cell_prints_a_correct_contract_line(cell, mini_root, on_cpu,
     assert KEYS <= set(out) and list(out)[-1] == "checks"
     assert out["correct"] is True and out["failed"] == 0
     assert out["attempted"] > 0
-    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+    bench = json.load(open(os.path.join(mini_root, "BENCHMARK.json")))
     want = {m["name"] for m in bench["end_to_end"]
             if cell in m.get("workloads", [cell])}
     assert set(out["metrics"]) == want
@@ -34,12 +35,12 @@ def test_cell_prints_a_correct_contract_line(cell, mini_root, on_cpu,
     assert all(ln.startswith("check ") and " limit " in ln for ln in tail)
 
 
-@pytest.mark.parametrize("cell", ["plain-5k.backlog", "plain-5k.bursts"])
+@pytest.mark.parametrize("cell", CELLS)
 def test_traced_run_reports_the_per_layer_metrics(cell, mini_root, on_cpu,
                                                   capsys):
     out, _ = run_cell(on_cpu, mini_root, capsys, cell, trace=1)
     assert out["correct"] is True
-    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+    bench = json.load(open(os.path.join(mini_root, "BENCHMARK.json")))
     host_read = {m["name"] for m in bench["per_layer"]
                  if cell in m["workloads"] and m["source"] != "device_trace"}
     # the CPU has no device plane: device metrics are left out, not 0
