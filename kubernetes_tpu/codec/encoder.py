@@ -24,7 +24,9 @@ import dataclasses
 from collections import Counter
 from dataclasses import dataclass, field
 from itertools import chain
-from typing import Dict, List, NamedTuple, Optional, Sequence, Set, Tuple
+from typing import (
+    Callable, Dict, List, NamedTuple, Optional, Sequence, Set, Tuple,
+)
 
 import numpy as np
 
@@ -576,6 +578,21 @@ class SnapshotEncoder:
                 self.a_topo[row, pid] = True
                 self._node_pair_id[kid][row] = pid
         return kid
+
+    def node_pair_index(self) -> Tuple[np.ndarray, np.ndarray]:
+        """(i32[arena rows, KP], i32[KP]): the topology pair of each node
+        row under each registered topology key (PAD where the node has no
+        such label) and each column's key (PAD for the padding), KP the
+        key count padded to a power of two: the rows of the snapshot's
+        topo_pairs incidence, as pair ids."""
+        keys = sorted(self.topo_keys)
+        kp = _pow2(len(keys))
+        out = np.full((self._cap_n, kp), PAD, np.int32)
+        for q, kid in enumerate(keys):
+            out[:, q] = self._node_pair_id[kid][: self._cap_n]
+        cols = np.full(kp, PAD, np.int32)
+        cols[: len(keys)] = keys
+        return out, cols
 
     def _vol_limit_col(self, name: str) -> Optional[int]:
         """Attach-limit column for an attachable-volumes-* allocatable key,
@@ -2609,7 +2626,8 @@ class SnapshotEncoder:
             self._batch_width = prev
 
     def encode_pods(self, pods: Sequence[Pod],
-                    classes_out: Optional[np.ndarray] = None) -> PodBatch:
+                    classes_out: Optional[np.ndarray] = None,
+                    affinity_phase: Optional[Callable] = None) -> PodBatch:
         """Encode pending pods into a PodBatch, precomputing the
         inter-pod-affinity pair tensors against current cluster state.
 
@@ -2618,7 +2636,11 @@ class SnapshotEncoder:
         (_pod_static_key) share one index, numbered in order of first
         appearance; pods with no static key (affinity, volumes, live
         term_groups, unhashable content) stay -1.  add_pods takes the
-        same array to commit the winners class by class."""
+        same array to commit the winners class by class.
+
+        `affinity_phase`, a context-manager factory, is entered around
+        the pass that fills the pods' inter-pod-affinity term and pair
+        columns (only batches that are not affinity-lean have one)."""
         d = self.dims
         B = self.batch_pad(len(pods))
         if self._batch_width is None and B > d.B:
@@ -2832,6 +2854,7 @@ class SnapshotEncoder:
         # binder assumptions).
         local_first: Dict[Tuple, int] = {}
         local_hits: Dict[int, List[int]] = {}
+        aff_rows: List[int] = []
         for b, pod in enumerate(pods):
             ck = self._pod_static_key(pod)
             if ck is not None:
@@ -2928,7 +2951,9 @@ class SnapshotEncoder:
                         self._encode_expr(
                             out, "pref_expr", b, s, e, expr.key, expr.operator, expr.values
                         )
-            self._encode_pod_affinity(out, b, pod)
+            # the pair columns come in one pass after this loop; a row
+            # the cache keeps has none (no affinity, no live term group)
+            aff_rows.append(b)
             for j, kid in enumerate(self.service_affinity_keys):
                 v = pod.spec.node_selector.get(it.string(kid))
                 if v is not None:
@@ -2963,6 +2988,11 @@ class SnapshotEncoder:
                 self._pod_row_cache[ck] = {
                     k: np.copy(v[b]) for k, v in out.items()
                 }
+
+        if not aff_lean and aff_rows:
+            with (affinity_phase or contextlib.nullcontext)():
+                for b in aff_rows:
+                    self._encode_pod_affinity(out, b, pods[b])
 
         for first, idxs in local_hits.items():
             ia = np.asarray(idxs, np.intp)

@@ -12,13 +12,17 @@ spreading counts exactly as if the reference had scheduled them one by one.
 Dynamic state inside the scan (everything else is precomputed static):
   requested[N, R], nonzero[N, 2]        — PodFitsResources + resource scores
   spread_extra[B, N]                    — SelectorSpreadPriority in-batch
-                                          increments (AND-match cross matrix)
+                                          increments (AND-match cross matrix;
+                                          [G + 1, N] by spread group for
+                                          spread-lean batches)
   port_used[N, PV]                      — PodFitsHostPorts within the batch,
                                           over a batch-local port vocabulary
                                           with a precomputed conflict matrix
                                           (wildcard-IP semantics preserved)
-  extra_aff/anti/forb/pref              — in-batch inter-pod affinity pair
-                                          state (predicateMetadata.AddPod
+  dom_cnt[G, KQ, N], forb_node[G, N],   — in-batch inter-pod affinity
+  pref_node[G, N]                         state per node by label group
+                                          and topology key
+                                          (predicateMetadata.AddPod
                                           analog) when aff_state is given
 """
 
@@ -58,6 +62,7 @@ from kubernetes_tpu.ops.priorities import (
     spread_counts,
     spread_score_from_counts,
     taint_toleration,
+    zone_layout,
 )
 from kubernetes_tpu.ops.select import (
     TopKQuality,
@@ -142,10 +147,12 @@ class BatchAffinityState:
     """In-batch inter-pod-affinity cross-match tensors.
 
     The per-pod pair tensors in PodBatch are computed against the PRE-batch
-    snapshot; these matrices let the sequential-commit scan update affinity
-    state as co-batched pods land (the tensorization of predicateMetadata's
-    incremental AddPod, ref algorithm/predicates/metadata.go:64-94), so pod
-    i+1's MatchInterPodAffinity sees pod i's placement.
+    snapshot; these matrices let the speculative engine's rounds update
+    affinity state as co-batched pods land (the tensorization of
+    predicateMetadata's incremental AddPod, ref
+    algorithm/predicates/metadata.go:64-94), so a later pod's
+    MatchInterPodAffinity sees an earlier one's placement.  The sequential
+    scan reads the factored form (LeanBatchAffinity) instead.
 
     Orientation: step axis first.  aff_match[j, i, t] = "batch pod j matches
     pod i's required-affinity term t" (namespaces + selector); anti_match
@@ -192,6 +199,12 @@ class LeanBatchAffinity(NamedTuple):
     pref_gm: Any        # bool[B, PP, G]
     pref_topo_key: Any  # i32[B, PP]
     pref_weight: Any    # f32[B, PP]
+    # i32[N, KP]: each node row's topology pair under each registered key
+    # (PAD = none), the snapshot's topo_pairs as pair ids, and i32[KP] the
+    # key of each column (PAD = none): the sequential scan keeps its
+    # in-batch affinity state per node with them
+    node_pairs: Any
+    node_pair_keys: Any
 
 
 def densify_batch_affinity(lean: LeanBatchAffinity) -> BatchAffinityState:
@@ -211,6 +224,24 @@ def densify_batch_affinity(lean: LeanBatchAffinity) -> BatchAffinityState:
         pref_match=jnp.transpose(pref_own, (2, 0, 1)),
         pref_own=pref_own,
     )
+
+
+_X = jax.lax.Precision.HIGHEST  # exact f32 products: these carry counts
+
+
+def _in_batch(gm, carry, q):
+    """f32[T, N]: for each term t of a pod (its group-match row gm[t] over
+    G label groups), the carry [G, KQ, N] at the term's key column q[t],
+    summed over the groups the term matches."""
+    return jnp.einsum("tg,gtn->tn", gm.astype(jnp.float32),
+                      jnp.take(carry, q, axis=1), precision=_X)
+
+
+def _owner(gm, dom, q):
+    """f32[G, N]: a committed pod's terms (group-match rows gm [T, G]),
+    each over its domain dom[q[t]] ([KQ, N]), by the groups they match."""
+    return jnp.einsum("tg,tn->gn", gm.astype(jnp.float32),
+                      jnp.take(dom, q, axis=0), precision=_X)
 
 
 def batch_has_pod_affinity(pods: Sequence) -> bool:
@@ -253,10 +284,11 @@ def encode_batch_affinity(encoder, pods: Sequence) -> LeanBatchAffinity:
             g = gid_of[sig] = len(reps)
             reps.append((p.namespace, p.labels))
         pod_gid[j] = g
-    # pad the group axis to a power of two; the LAST column stays all-False
-    # in every gm tensor and absorbs batch-padding pods, so they can never
-    # match a term
-    G = _pow2(len(reps) + 1)
+    # pad the group axis to a power of two, at least 8, so that batches
+    # of one workload (a batch may straddle a few controllers' pods) share
+    # one program; the LAST column stays all-False in every gm tensor and
+    # absorbs batch-padding pods, so they can never match a term
+    G = _pow2(len(reps) + 1, 8)
     gid = np.full(B, G - 1, np.int32)
     if nb:
         gid[:nb] = pod_gid[:nb]
@@ -325,6 +357,8 @@ def encode_batch_affinity(encoder, pods: Sequence) -> LeanBatchAffinity:
     return LeanBatchAffinity(
         gid=gid, aff_gm=A, anti_gm=N, pref_gm=P,
         pref_topo_key=p_key, pref_weight=p_w,
+        **dict(zip(("node_pairs", "node_pair_keys"),
+                   encoder.node_pair_index())),
     )
 
 
@@ -469,7 +503,7 @@ def encode_batch_ports(encoder, pods: Sequence) -> BatchPortState:
 
 
 def _dynamic_scores(cluster, req_cpu_mem, requested2, zone_key_id, counts,
-                    rtc_xs, rtc_ys):
+                    rtc_xs, rtc_ys, zones=None):
     """The state-dependent priorities, recomputed per scan step from the
     shared scoring cores in ops/priorities.py.
 
@@ -482,7 +516,7 @@ def _dynamic_scores(cluster, req_cpu_mem, requested2, zone_key_id, counts,
     least = least_requested_score(req, cap)                  # [N]
     most = most_requested_score(req, cap)
     balanced = balanced_allocation_score(req, cap)
-    spread = spread_score_from_counts(counts, cluster, zone_key_id)
+    spread = spread_score_from_counts(counts, cluster, zone_key_id, zones)
     util = jnp.where(cap > 0, req * 100.0 / jnp.maximum(cap, 1e-30), 100.0)
     rtc = jnp.floor(jnp.sum(jnp.interp(util, rtc_xs, rtc_ys), axis=-1) / 2.0)
     return least, most, balanced, spread, rtc
@@ -580,17 +614,20 @@ def make_sequential_scheduler(
     def schedule_impl(cluster: ClusterTensors, pods: PodBatch, ports: BatchPortState,
                       last_index0: jnp.ndarray, nominated: Optional[NominatedState] = None,
                       extra_mask=None, extra_score=None,
-                      aff_state: Optional[BatchAffinityState] = None):
+                      aff_state: Optional[LeanBatchAffinity] = None):
         """extra_mask bool[B, N] / extra_score f32[B, N]: the framework's
         tensor-level Filter/Score plugin outputs, folded into the static
         pass (one launch total — the TPU-shaped plugin seam).
 
-        aff_state: in-batch affinity cross-matches; when given,
-        MatchInterPodAffinity moves from the static pass into the scan with
-        carried per-topology-pair extras, so co-batched pods see each
-        other's placements (kills the batch>1 affinity-blindness gap)."""
-        if isinstance(aff_state, LeanBatchAffinity):
-            aff_state = densify_batch_affinity(aff_state)  # on device
+        aff_state: in-batch affinity cross-matches (LeanBatchAffinity);
+        when given, MatchInterPodAffinity moves from the static pass into
+        the scan with carried per-topology-pair extras, so co-batched pods
+        see each other's placements (kills the batch>1 affinity-blindness
+        gap)."""
+        if aff_state is not None and not isinstance(
+                aff_state, LeanBatchAffinity):
+            raise TypeError("the sequential scan takes the factored "
+                            "affinity state (encode_batch_affinity)")
         B = pods.n_pods
         G = cluster.group_counts.shape[1]
         # ---- static pass: every predicate except the dynamic ones, plus the
@@ -677,34 +714,75 @@ def make_sequential_scheduler(
             if percentage_of_nodes_to_score < 100  # 0 = adaptive
             else None
         )
-        # in-batch spread cross-matches (countMatchingPods AND semantics);
-        # shared helper so the speculative engine's bookkeeping is
-        # guaranteed identical
-        spread_match = pod_spread_match(pods, G)              # [B, B] [i, j]
+        # in-batch spread cross-matches (countMatchingPods AND semantics).
+        # A spread-lean batch (every pod in <= 1 group) carries them per
+        # group, [G + 1, N]: pod i's in-batch counts are its group's row
+        # (row G takes the groupless pods, whose counts stay 0).  Other
+        # batches carry [B, N] rows through the shared helper, so the
+        # speculative engine's bookkeeping is guaranteed identical
+        lean_spread = pods.spread_counts.shape[-1] != cluster.n_nodes
+        if lean_spread:
+            onehot_g = pod_group_onehot(pods, G)              # [B, G]
+            has_group = jnp.any(onehot_g > 0, axis=-1)
+            spread_xs = (
+                jnp.where(has_group, jnp.argmax(onehot_g, axis=-1), G),
+                has_group,
+            )
+        else:
+            spread_match = pod_spread_match(pods, G)          # [B, B] [i, j]
+            spread_xs = None
 
-        topo = cluster.topo_pairs.astype(jnp.float32)         # [N, TP]
-        TP = topo.shape[1]
+        # SelectorSpread's zone layout is the same at every step
+        zones = zone_layout(cluster, zone_key_id)
         if aff_state is not None:
-            aff_key_pairs = (
-                pods.aff_term_topo_key[:, :, None] == cluster.pair_topo_key[None, None]
-            )                                                 # [B, PT, TP]
-            anti_key_pairs = (
-                pods.anti_term_topo_key[:, :, None] == cluster.pair_topo_key[None, None]
-            )                                                 # [B, AT, TP]
-            pref_key_pairs = (
-                aff_state.pref_topo_key[:, :, None]
-                == cluster.pair_topo_key[None, None]
-            )                                                 # [B, PP, TP]
-            pref_w_all = aff_state.pref_weight                # [B, PP]
+            # the pods' pre-batch pair sets in node space, once: a node is
+            # in a pair set when one of its pairs is.  The steps keep the
+            # in-batch part in node space too, so no step reads the
+            # [N, TP] incidence
+            topo_t = cluster.topo_pairs.astype(jnp.float32).T  # [TP, N]
+
+            def on_nodes(x, weights=False):
+                """[..., TP] -> [..., N]: a node is in a pair set when
+                one of its pairs is, or collects its pairs' weights.  An
+                affinity-lean batch's pair tensors are 1 wide and all
+                zero (codec/encoder.py)."""
+                if x.shape[-1] != topo_t.shape[0]:
+                    return jnp.zeros(x.shape[:-1] + (cluster.n_nodes,),
+                                     jnp.float32 if weights else bool)
+                if weights:
+                    return jnp.matmul(x, topo_t, precision=_X)
+                return jnp.matmul(x.astype(jnp.float32), topo_t) > 0
+
+            GA = aff_state.aff_gm.shape[2]
+            # node_pairs with a last column of PAD: the column of a term
+            # whose key no node carries
+            np_ext = jnp.concatenate(
+                [aff_state.node_pairs,
+                 jnp.full((cluster.n_nodes, 1), PAD, jnp.int32)], axis=1)
+            KQ = np_ext.shape[1]
+            key_on_node = (np_ext >= 0).T                      # [KQ, N]
+
+            def key_col(keys):
+                """i32[...] topology key ids -> their np_ext column."""
+                hit = ((keys[..., None] == aff_state.node_pair_keys)
+                       & (aff_state.node_pair_keys >= 0))
+                return jnp.where(jnp.any(hit, axis=-1),
+                                 jnp.argmax(hit, axis=-1), KQ - 1)
 
         w_ipa = float(w[PRIO_INDEX["InterPodAffinityPriority"]])
         hard_w = float(cfg.hard_pod_affinity_weight)
 
         def step(state, xs):
             (requested, nonzero2, spread_extra, port_used, last_idx,
-             extra_aff, extra_anti, extra_forb, extra_pref) = state
+             dom_cnt, forb_node, pref_node) = state
             (smask, sscore, req, nz2, spread_base, pprio, pport, step_no,
-             aff_xs, attr_xs) = xs
+             spread_at, aff_xs, attr_xs) = xs
+            if lean_spread:
+                spread_row, has_group_j = spread_at
+                spread_now = jnp.where(has_group_j, spread_extra[spread_row],
+                                       0.0)
+            else:
+                spread_now = spread_extra[step_no]
             # dynamic resource fit (PodFitsResources on current state)
             fit = ~jnp.any(
                 (req[None, :] > 0)
@@ -738,28 +816,32 @@ def make_sequential_scheduler(
             port_bad = jnp.any(pport[None, :] & claimed_conflict, axis=-1)
             mask = smask & fit & ~port_bad
             if aff_state is not None:
-                # MatchInterPodAffinity against (pre-batch | in-batch) state
-                (aff_pairs_j, aff_valid_j, aff_self_j, aff_key_j,
-                 anti_pairs_j, anti_valid_j, anti_key_j, forb_j,
-                 pref_w_j, aff_match_j, anti_match_j, anti_own_j,
-                 aff_own_j, prefm_j, pref_own_j, pref_wt_j,
-                 pref_key_j) = aff_xs
-                aff_pairs = aff_pairs_j | extra_aff[step_no]       # [PT, TP]
-                aff_hit = (aff_pairs.astype(jnp.float32) @ topo.T) > 0   # [PT, N]
-                any_match = jnp.any(aff_pairs, axis=-1)            # [PT]
-                node_has_key = (aff_key_j.astype(jnp.float32) @ topo.T) > 0
+                # MatchInterPodAffinity against (pre-batch | in-batch)
+                # state.  In-batch, from the carry: dom_cnt[g, q, n]
+                # counts the batch's committed pods of label group g whose
+                # node shares n's pair under key column q, so the
+                # committed pods matching this pod's term t are its
+                # group-match row aff_gm[t] times dom_cnt at t's column
+                (pre_aff_j, pre_any_j, aff_valid_j, aff_self_j, qa_j,
+                 pre_anti_j, anti_valid_j, qn_j, pre_forb_j, pre_raw_j,
+                 gid_j, aff_gm_j, anti_gm_j, pref_gm_j, pref_wt_j,
+                 qp_j) = aff_xs
+                dom_any = (dom_cnt > 0).astype(jnp.float32)        # [G, KQ, N]
+                in_aff = _in_batch(aff_gm_j, dom_any, qa_j) > 0    # [PT, N]
+                aff_hit = pre_aff_j | in_aff
+                any_match = pre_any_j | jnp.any(in_aff, axis=-1)   # [PT]
+                node_has_key = key_on_node[qa_j]                   # [PT, N]
                 bootstrap = ~any_match[:, None] & aff_self_j[:, None] & node_has_key
                 term_ok = aff_hit | bootstrap | ~aff_valid_j[:, None]
                 aff_ok = jnp.all(term_ok, axis=0)                  # [N]
-                anti_pairs = anti_pairs_j | extra_anti[step_no]
-                anti_hit = (anti_pairs.astype(jnp.float32) @ topo.T) > 0
+                anti_hit = pre_anti_j | (_in_batch(anti_gm_j, dom_any, qn_j) > 0)
                 viol2 = jnp.any(anti_hit & anti_valid_j[:, None], axis=0)
-                forb = forb_j | extra_forb[step_no]
-                viol1 = (forb.astype(jnp.float32) @ topo.T) > 0    # [N]
+                # earlier committed pods' anti terms that match this pod
+                viol1 = pre_forb_j | forb_node[gid_j]              # [N]
                 mask = mask & aff_ok & ~viol1 & ~viol2
             least, most, balanced, spread, rtc = _dynamic_scores(
                 cluster, nz2, nonzero2, zone_key_id,
-                spread_base + spread_extra[step_no], rtc_xs, rtc_ys,
+                spread_base + spread_now, rtc_xs, rtc_ys, zones,
             )
             total = (
                 sscore
@@ -771,8 +853,14 @@ def make_sequential_scheduler(
             )
             if aff_state is not None:
                 # IPA score over (pre-batch | in-batch) raw pair weights,
-                # renormalized per step (interpod_affinity.go fScore)
-                raw = (pref_w_j + extra_pref[step_no]) @ topo.T    # [N]
+                # renormalized per step (interpod_affinity.go fScore):
+                # the earlier committed pods' terms by this pod's group
+                # (pref_node), and this pod's preferred terms over the
+                # committed pods they match, +-w each over their domains
+                cand = jnp.sum(_in_batch(
+                    pref_gm_j.astype(jnp.float32) * pref_wt_j[:, None],
+                    dom_cnt, qp_j), axis=0)                        # [N]
+                raw = pre_raw_j + pref_node[gid_j] + cand          # [N]
                 big = jnp.float32(3.4e38)
                 mn = jnp.min(jnp.where(cluster.valid, raw, big))
                 mx = jnp.max(jnp.where(cluster.valid, raw, -big))
@@ -862,98 +950,78 @@ def make_sequential_scheduler(
             requested = requested + onehot[:, None] * req[None, :]
             nonzero2 = nonzero2 + onehot[:, None] * nz2[None, :]
             # later pods whose selector set this pod covers see it at its node
-            spread_extra = spread_extra + (
-                spread_match[:, step_no][:, None] * onehot[None, :]
-            )
+            if lean_spread:
+                spread_extra = spread_extra.at[spread_row].add(
+                    onehot.astype(jnp.float32))
+            else:
+                spread_extra = spread_extra + (
+                    spread_match[:, step_no][:, None] * onehot[None, :]
+                )
             port_used = port_used | (onehot[:, None] & pport[None, :])
             if aff_state is not None:
-                # predicateMetadata.AddPod analog: the committed pod's
-                # topology pairs flow into later pods' affinity state
-                node_pairs = (onehot.astype(jnp.float32) @ topo) > 0   # [TP]
-                extra_aff = extra_aff | (
-                    aff_match_j[:, :, None] & aff_key_pairs & node_pairs[None, None]
-                )
-                extra_anti = extra_anti | (
-                    anti_match_j[:, :, None] & anti_key_pairs & node_pairs[None, None]
-                )
-                forb_contrib = jnp.einsum(
-                    "tb,tp->bp",
-                    anti_own_j.astype(jnp.float32),
-                    (anti_key_j & node_pairs[None]).astype(jnp.float32),
-                ) > 0
-                extra_forb = extra_forb | forb_contrib
+                # predicateMetadata.AddPod analog: the nodes sharing a
+                # domain with the committed pod's node, per key column,
+                # join its label group's count, and its own terms reach
+                # later pods by the groups they match, over each term's
+                # domain
+                hp = np_ext[host]                                  # [KQ]
+                dom = ((np_ext == hp[None, :]) & (hp[None, :] >= 0)
+                       & commit).T.astype(jnp.float32)             # [KQ, N]
+                dom_cnt = dom_cnt.at[gid_j].add(dom)
+                forb_node = forb_node | (_owner(anti_gm_j, dom, qn_j) > 0)
                 # hard-affinity symmetry: the committed pod's required
-                # affinity terms add hard_w per matching later pod per pair
-                # (encoder K_AFF_REQ group semantics)
-                extra_pref = extra_pref + hard_w * jnp.einsum(
-                    "tb,tp->bp",
-                    aff_own_j.astype(jnp.float32),
-                    (aff_key_j & node_pairs[None]).astype(jnp.float32),
-                )
-                # preferred (soft) terms, both directions:
-                # 1. LATER pods' own preferred terms the committed pod
-                #    matches gain +-w at the committed node's domain
-                kp = (
-                    pref_key_pairs & node_pairs[None, None]
-                ).astype(jnp.float32)                         # [B, PP, TP]
-                extra_pref = extra_pref + jnp.einsum(
-                    "it,itp->ip",
-                    prefm_j.astype(jnp.float32) * pref_w_all, kp,
-                )
-                # 2. the committed pod's preferred terms add +-w_j for each
-                #    later pod they match (existing-pod K_AFF_PREF/K_ANTI_PREF
-                #    group semantics)
-                extra_pref = extra_pref + jnp.einsum(
-                    "ti,t,tp->ip",
-                    pref_own_j.astype(jnp.float32),
-                    pref_wt_j,
-                    (pref_key_j & node_pairs[None]).astype(jnp.float32),
+                # affinity terms add hard_w per matching later pod per node
+                # of the domain (encoder K_AFF_REQ group semantics); its
+                # preferred terms add +-w for each later pod they match
+                # (existing-pod K_AFF_PREF/K_ANTI_PREF group semantics)
+                pref_node = (
+                    pref_node + hard_w * _owner(aff_gm_j, dom, qa_j)
+                    + _owner(pref_gm_j.astype(jnp.float32)
+                             * pref_wt_j[:, None], dom, qp_j)
                 )
             out_host = jnp.where(feasible, host, -1)
             return (
                 (requested, nonzero2, spread_extra, port_used, last_idx + 1,
-                 extra_aff, extra_anti, extra_forb, extra_pref),
+                 dom_cnt, forb_node, pref_node),
                 (out_host, attr_out, qual_out),
             )
 
         PV = ports.pod_ports.shape[1]
-        PT = pods.aff_term_pairs.shape[1]
-        AT = pods.anti_term_pairs.shape[1]
         if aff_state is not None:
+            N = cluster.n_nodes
             extras_init = (
-                jnp.zeros((B, PT, TP), bool),
-                jnp.zeros((B, AT, TP), bool),
-                jnp.zeros((B, TP), bool),
-                jnp.zeros((B, TP), jnp.float32),
+                jnp.zeros((GA, KQ, N), jnp.float32),
+                jnp.zeros((GA, N), bool),
+                jnp.zeros((GA, N), jnp.float32),
             )
         else:  # unused: scalar placeholders keep the carry structure cheap
-            extras_init = tuple(jnp.zeros(()) for _ in range(4))
+            extras_init = tuple(jnp.zeros(()) for _ in range(3))
         init = (
             cluster.requested,
             cluster.nonzero_req,
-            jnp.zeros((B, cluster.n_nodes), jnp.float32),
+            jnp.zeros((G + 1 if lean_spread else B, cluster.n_nodes),
+                      jnp.float32),
             jnp.zeros((cluster.n_nodes, PV), bool),
             last_index0.astype(jnp.int32),
         ) + extras_init
         if aff_state is not None:
             aff_xs_in = (
-                pods.aff_term_pairs,
+                on_nodes(pods.aff_term_pairs),                 # [B, PT, N]
+                jnp.any(pods.aff_term_pairs, axis=-1),         # [B, PT]
                 pods.aff_term_valid,
                 pods.aff_term_self,
-                aff_key_pairs,
-                pods.anti_term_pairs,
+                key_col(pods.aff_term_topo_key),               # [B, PT]
+                on_nodes(pods.anti_term_pairs),                # [B, AT, N]
                 pods.anti_term_valid,
-                anti_key_pairs,
-                pods.forbidden_pairs,
-                pods.pref_pair_weights,
-                aff_state.aff_match,
-                aff_state.anti_match,
-                aff_state.anti_own,
-                aff_state.aff_own,
-                aff_state.pref_match,
-                aff_state.pref_own,
+                key_col(pods.anti_term_topo_key),              # [B, AT]
+                on_nodes(pods.forbidden_pairs),                # [B, N]
+                on_nodes(pods.pref_pair_weights, weights=True),  # [B, N]
+                aff_state.gid,
+                aff_state.aff_gm,
+                aff_state.anti_gm,
+                aff_state.pref_gm,
                 aff_state.pref_weight,
-                pref_key_pairs,
+                key_col(aff_state.pref_topo_key),              # [B, PP]
             )
         else:
             aff_xs_in = None
@@ -967,6 +1035,7 @@ def make_sequential_scheduler(
             pods.priority,
             ports.pod_ports,
             jnp.arange(B, dtype=jnp.int32),
+            spread_xs,
             aff_xs_in,
             # extra-mask vetoes need no tensor here: a node rejected with
             # every predicate passing can ONLY be an extra-mask veto

@@ -10,6 +10,7 @@ parity suite can compare exactly.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -200,11 +201,26 @@ def node_prefer_avoid_pods(cluster: ClusterTensors, pods: PodBatch):
 # ------------------------------------------------------------ selector spread
 
 
-def spread_score_from_counts(counts, cluster: ClusterTensors, zone_key_id: int):
+def zone_layout(cluster: ClusterTensors, zone_key_id: int):
+    """(node_in_zone bool[N], zone_of_node i32[N] pair id, have_zones):
+    the part of SelectorSpread's zone aggregation that depends on the
+    cluster alone.  GetZoneKey gives each node at most ONE zone pair, so
+    the argmax column is exact."""
+    zmask = cluster.pair_topo_key == zone_key_id             # [TP]
+    zpairs_b = cluster.topo_pairs & zmask[None]              # [N, TP] bool
+    node_in_zone = jnp.any(zpairs_b, axis=-1)                # [N]
+    zone_of_node = jnp.argmax(zpairs_b, axis=-1)             # [N] pair id
+    return node_in_zone, zone_of_node, jnp.any(node_in_zone)
+
+
+def spread_score_from_counts(counts, cluster: ClusterTensors, zone_key_id: int,
+                             zones=None):
     """The SelectorSpread reduce (selector_spreading.go:95-140) given per-node
     matching-pod counts [..., N]: fScore = (1-2/3)*nodeScore + 2/3*zoneScore,
     int-truncated.  Zone aggregation is a segment-sum over each node's zone
-    pair id (scatter + gather, O(B*N))."""
+    pair id (scatter + gather, O(B*N)).  `zones` is zone_layout's result
+    for a caller that reuses it across calls (a scan's steps); with it, a
+    cluster without zones skips the aggregation."""
     max_node = jnp.max(counts, axis=-1, keepdims=True)
     # guarded denominator: the zero branch is selected away, but dividing
     # by 0 first would trip the checkify float guards (tests/test_checkify)
@@ -213,38 +229,40 @@ def spread_score_from_counts(counts, cluster: ClusterTensors, zone_key_id: int):
         MAX_PRIORITY * (max_node - counts) / jnp.maximum(max_node, 1.0),
         MAX_PRIORITY,
     )
+
     # zone aggregation as a segment-sum over each node's zone pair id:
     # O(B*N) scatter+gather instead of two [.., N] x [N, TP] matmuls over
     # the WHOLE pair vocabulary (hostname pairs make TP ~ N, so the matmul
     # form costs B*N*TP flops — negligible on the MXU, seconds on the CPU
-    # fallback).  GetZoneKey gives each node at most ONE zone pair, so the
-    # argmax column is exact.
-    zmask = cluster.pair_topo_key == zone_key_id             # [TP]
-    zpairs_b = cluster.topo_pairs & zmask[None]              # [N, TP] bool
-    node_in_zone = jnp.any(zpairs_b, axis=-1)                # [N]
-    zone_of_node = jnp.argmax(zpairs_b, axis=-1)             # [N] pair id
-    TP = zpairs_b.shape[1]
-    lead = counts.shape[:-1]
-    n = counts.shape[-1]
-    flat = counts.reshape((-1, n))
-    contrib = jnp.where(node_in_zone[None, :], flat, 0.0)
-    zsums = jnp.zeros((flat.shape[0], TP), flat.dtype)
-    zsums = zsums.at[:, zone_of_node].add(contrib)           # [M, TP]
-    zcount_per_node = zsums[:, zone_of_node].reshape(lead + (n,))
-    max_zone = jnp.max(zsums, axis=-1).reshape(lead + (1,))
-    zone_score = jnp.where(
-        max_zone > 0,
-        MAX_PRIORITY * (max_zone - zcount_per_node)
-        / jnp.maximum(max_zone, 1.0),
-        MAX_PRIORITY,
-    )
-    have_zones = jnp.any(node_in_zone)
-    blended = jnp.where(
-        have_zones & node_in_zone,
-        (1.0 - _ZONE_WEIGHT) * node_score + _ZONE_WEIGHT * zone_score,
-        node_score,
-    )
-    return jnp.floor(blended)
+    # fallback)
+    def zoned(node_in_zone, zone_of_node, have_zones):
+        TP = cluster.topo_pairs.shape[1]
+        lead = counts.shape[:-1]
+        n = counts.shape[-1]
+        flat = counts.reshape((-1, n))
+        contrib = jnp.where(node_in_zone[None, :], flat, 0.0)
+        zsums = jnp.zeros((flat.shape[0], TP), flat.dtype)
+        zsums = zsums.at[:, zone_of_node].add(contrib)       # [M, TP]
+        zcount_per_node = zsums[:, zone_of_node].reshape(lead + (n,))
+        max_zone = jnp.max(zsums, axis=-1).reshape(lead + (1,))
+        zone_score = jnp.where(
+            max_zone > 0,
+            MAX_PRIORITY * (max_zone - zcount_per_node)
+            / jnp.maximum(max_zone, 1.0),
+            MAX_PRIORITY,
+        )
+        blended = jnp.where(
+            have_zones & node_in_zone,
+            (1.0 - _ZONE_WEIGHT) * node_score + _ZONE_WEIGHT * zone_score,
+            node_score,
+        )
+        return jnp.floor(blended)
+
+    if zones is None:
+        return zoned(*zone_layout(cluster, zone_key_id))
+    # no zones: the blend above is the node score alone
+    return jax.lax.cond(zones[2], lambda: zoned(*zones),
+                        lambda: jnp.floor(node_score))
 
 
 def pod_group_onehot(pods: PodBatch, n_groups: int):

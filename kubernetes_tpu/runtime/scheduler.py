@@ -91,13 +91,18 @@ TAINT_NODE_UNSCHEDULABLE = "node.kubernetes.io/unschedulable"
 #   program_load  backend compiles + persistent-cache retrievals
 #   commit_state  } commit split: the state half (assume into the cache)
 #   commit_tail   } and the bind tail (binds, events, requeues)
+#   encode_affinity  the part of encode that builds a batch's affinity
+#                 state: the in-batch cross-match factors and the
+#                 encoder's per-pod term and pair columns
+#   fence_redo    the part of host_stall spent on batches whose engine
+#                 answer came from the exact redo
 PARTITION_PHASES = (
     "pop", "encode", "dispatch", "host_stall", "commit", "preempt",
     "observe",
 )
 OVERLAP_PHASES = (
     "fetch", "fence_queue", "fence_device", "fence_copy", "program_load",
-    "commit_state", "commit_tail",
+    "commit_state", "commit_tail", "encode_affinity", "fence_redo",
 )
 
 
@@ -541,6 +546,10 @@ class _InFlight:
     #                              launches only; None when off/degraded)
     quality: object = None       # host-materialized TopKQuality (set at
     #                              the commit fence, like attrib)
+    # the speculative engine's (repair rounds, exact-redo flag) for this
+    # launch, device scalars fetched at the fence (None for engines that
+    # report neither, and for degraded cycles)
+    engine_dev: Optional[tuple] = None
     # the encoded batch's request matrix (host ref) — the regret
     # counterfactual's pod-side input
     quality_reqs: object = None
@@ -1246,6 +1255,21 @@ class Scheduler:
                 "d2h", fetch.t_ready, fetch.t_done, lane=WORKER_LANE
             )
 
+    def _engine_outcome(self, inf: _InFlight,
+                        fence: PhaseSpan) -> bool:
+        """Count the speculative engine's repair rounds and which path
+        answered the batch (the exact redo or the speculative rounds),
+        and put both on the cycle's fence span.  Returns whether the
+        redo answered; False where the engine reports neither."""
+        if inf.engine_dev is None:
+            return False
+        rounds = int(np.asarray(inf.engine_dev[0]))
+        redo = bool(np.asarray(inf.engine_dev[1]))
+        m.ENGINE_BATCHES.inc(path="redo" if redo else "speculative")
+        m.ENGINE_ROUNDS.inc(rounds)
+        fence.attrs.update(engine_rounds=rounds, exact_redo=redo)
+        return redo
+
     def _fold_program_loads(self, span: Optional[Span],
                             tier: str = TIER_BULK) -> None:
         """Fold the program loads since the last fold into
@@ -1687,6 +1711,7 @@ class Scheduler:
         inf.attrib_dev = None
         inf.quality_dev = None
         inf.quality = None
+        inf.engine_dev = None
         # overwrite the dispatch-time attrs: the placements this cycle
         # commits came from the CPU engine, whatever was launched first
         inf.trace.annotate(degraded=True, engine="cpu")
@@ -1744,7 +1769,7 @@ class Scheduler:
             try:
                 if relaunch_pending:
                     (inf.hosts_dev, inf.fetch, inf.attrib_dev,
-                     inf.quality_dev) = inf.relaunch()
+                     inf.quality_dev, inf.engine_dev) = inf.relaunch()
                     relaunch_pending = False
                 staged = self._commit_state(inf)
             except BaseException as e:
@@ -1837,13 +1862,16 @@ class Scheduler:
                 # built BEFORE encode_pods so novel term topology keys
                 # register (and possibly grow the pair vocabulary) before
                 # any TP-wide tensor is cut
-                aff_state = (
-                    encode_batch_affinity(enc, pods)
-                    if len(pods) > 1 and batch_has_pod_affinity(pods)
-                    else None
-                )
+                def aff_phase():
+                    return self._phase_span(trace, "encode_affinity", tier)
+
+                aff_state = None
+                if len(pods) > 1 and batch_has_pod_affinity(pods):
+                    with aff_phase():
+                        aff_state = encode_batch_affinity(enc, pods)
                 classes = np.full(len(pods), -1, np.int32)
-                batch = enc.encode_pods(pods, classes)
+                batch = enc.encode_pods(pods, classes,
+                                        affinity_phase=aff_phase)
                 ports = encode_batch_ports(enc, pods)
                 # two-pass evaluation: nominated pods (other than those
                 # being scheduled now) are added to their nominated nodes
@@ -1974,20 +2002,26 @@ class Scheduler:
                 attrib = out[idx]
                 idx += 1
             qual = out[idx] if self._quality_k else None
-            if qual is not None:
-                # enqueue the tiny top-k D2H copies alongside the
-                # winners buffer so the fence materialize is a copy
-                # wait, never a compute sync
-                for leaf in qual:
-                    if hasattr(leaf, "copy_to_host_async"):
-                        leaf.copy_to_host_async()
+            # the speculative engine's repair rounds and exact-redo flag
+            # for this call (scalars of the same launch); None where the
+            # engine reports neither
+            engine = (getattr(fn, "last_rounds", None),
+                      getattr(fn, "last_redo", None))
+            if engine[0] is None:
+                engine = None
+            # enqueue the tiny D2H copies (top-k, engine scalars)
+            # alongside the winners buffer so the fence materialize is a
+            # copy wait, never a compute sync
+            for leaf in (*(qual or ()), *(engine or ())):
+                if hasattr(leaf, "copy_to_host_async"):
+                    leaf.copy_to_host_async()
             # async result path: only the compact winners buffer (i32[B]
             # node rows) crosses the wire — the D2H copy is enqueued NOW
             # and materializes on a worker thread, so the blocking fence in
             # _commit_state is usually a no-op by the time the pipelined
             # loop reaches it (batch k's fetch overlaps batch k's host tail
             # and batch k+1's dispatch)
-            return hosts, AsyncFetch(hosts), attrib, qual
+            return hosts, AsyncFetch(hosts), attrib, qual, engine
 
         def cpu_fetch():
             """Winners for THIS batch from the CPU reference engine, in the
@@ -2022,7 +2056,7 @@ class Scheduler:
 
         with self._phase_span(trace, "dispatch", tier) as dispatch:
             degraded = False
-            hosts_dev = attrib_dev = quality_dev = None
+            hosts_dev = attrib_dev = quality_dev = engine_dev = None
             if use_device:
                 launched = self._launch_resilient(launch)
             else:
@@ -2038,7 +2072,8 @@ class Scheduler:
                 )
                 fetch = cpu_fetch()
             else:
-                hosts_dev, fetch, attrib_dev, quality_dev = launched
+                hosts_dev, fetch, attrib_dev, quality_dev, engine_dev = (
+                    launched)
             self._last_index += len(pods)
             trace.annotate(
                 batch=len(pods),
@@ -2064,6 +2099,7 @@ class Scheduler:
             cpu_fetch=cpu_fetch, degraded=degraded,
             last_index0=last_index0, tier=tier, attrib_dev=attrib_dev,
             quality_dev=quality_dev,
+            engine_dev=engine_dev,
             quality_reqs=(
                 batch.req if self.quality is not None else None
             ),
@@ -2716,6 +2752,7 @@ class Scheduler:
                 inf.quality = type(inf.quality_dev)(
                     *(np.asarray(x) for x in inf.quality_dev)
                 )
+            redo = self._engine_outcome(inf, fence)
         t_state0 = fence.t1
         with self._phase_span(
             inf.trace, "commit", inf.tier, part="commit_state"
@@ -2726,6 +2763,8 @@ class Scheduler:
             # the worker was doing meanwhile.
             self._phase("fetch", inf.fetch.seconds, inf.tier)
             self._fence_split(inf.fetch, fence, inf.tier)
+            if redo:
+                self._phase("fence_redo", fence.seconds, inf.tier)
             # algorithm latency: encode + device filter/score/select, amortized
             # per pod (metrics.go SchedulingAlgorithmLatency)
             algo_dt = (time.monotonic() - inf.t_cycle0) / len(pods)

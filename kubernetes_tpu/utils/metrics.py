@@ -587,7 +587,9 @@ CYCLE_PHASE_SECONDS = REGISTRY.register(
         "fetch (dispatch to host copy, on the fetch worker) overlaps them; "
         "fence_queue|fence_device|fence_copy split host_stall by what the "
         "fetch worker was doing; program_load counts backend compiles and "
-        "persistent-cache retrievals",
+        "persistent-cache retrievals; encode_affinity is the part of "
+        "encode that builds a batch's affinity state, fence_redo the part "
+        "of host_stall on batches the exact redo answered",
         ("phase", "tier"),
     )
 )
@@ -602,6 +604,25 @@ COMMIT_PODS = REGISTRY.register(
         "derived for the pod alone (affinity, volumes, live term groups, "
         "replacement batches, the per-pod commit mode)",
         ("path",),
+    )
+)
+
+# the speculative engine's answers, by the path that gave them, and its
+# repair rounds (models/speculative.py: rounds, the exactness sentinel)
+ENGINE_BATCHES = REGISTRY.register(
+    LabeledCounter(
+        "scheduler_engine_batches_total",
+        "Batches the speculative engine answered, by path: 'speculative' "
+        "= its parallel rounds, 'redo' = the exactness sentinel tripped "
+        "and the exact sequential scan answered",
+        ("path",),
+    )
+)
+ENGINE_ROUNDS = REGISTRY.register(
+    Counter(
+        "scheduler_engine_rounds_total",
+        "Propose-and-commit rounds the speculative engine ran, summed "
+        "over its batches",
     )
 )
 
