@@ -50,6 +50,7 @@ from kubernetes_tpu.codec.schema import (
     NUM_VOL_TYPES,
     PAD,
     PadDims,
+    PairRows,
     PodBatch,
     RES_EPHEMERAL,
     RES_EXT0,
@@ -65,6 +66,7 @@ from kubernetes_tpu.codec.schema import (
     VOL_GCE,
     WILDCARD,
     _pow2,
+    factor_row_limit,
 )
 
 def normalized_image(name: str) -> str:
@@ -2627,7 +2629,9 @@ class SnapshotEncoder:
 
     def encode_pods(self, pods: Sequence[Pod],
                     classes_out: Optional[np.ndarray] = None,
-                    affinity_phase: Optional[Callable] = None) -> PodBatch:
+                    affinity_phase: Optional[Callable] = None,
+                    pair_rows: bool = False,
+                    pair_phase: Optional[Callable] = None) -> PodBatch:
         """Encode pending pods into a PodBatch, precomputing the
         inter-pod-affinity pair tensors against current cluster state.
 
@@ -2640,7 +2644,16 @@ class SnapshotEncoder:
 
         `affinity_phase`, a context-manager factory, is entered around
         the pass that fills the pods' inter-pod-affinity term and pair
-        columns (only batches that are not affinity-lean have one)."""
+        columns (only batches that are not affinity-lean have one).
+
+        The TP-wide pair leaves (PAIR_FIELDS) of a batch that is not
+        affinity-lean or not volume-lean hold one row per pod class.
+        `pair_rows` asks for them as PairRows (the class rows and each
+        pod's row index), which pack_tree ships without expanding them;
+        a batch with more classes than pack_tree's factoring limit still
+        gets dense leaves.  `pair_phase(rows, factored)`, a context-
+        manager factory, is entered around spreading the class rows
+        (only batches with TP-wide leaves have one)."""
         d = self.dims
         B = self.batch_pad(len(pods))
         if self._batch_width is None and B > d.B:
@@ -2799,16 +2812,12 @@ class SnapshotEncoder:
             pref_expr_nval=np.zeros((B, PSL, d.E), i32),
             pref_expr_num=np.full((B, PSL, d.E), np.nan, f32),
             pref_expr_valid=zb(B, PSL, d.E),
-            forbidden_pairs=zb(B, TPA),
-            aff_term_pairs=zb(B, d.PT, TPA),
             aff_term_valid=zb(B, d.PT),
             aff_term_self=zb(B, d.PT),
             aff_term_topo_key=zi(B, d.PT),
-            anti_term_pairs=zb(B, d.AT, TPA),
             anti_term_valid=zb(B, d.AT),
             anti_term_topo_key=zi(B, d.AT),
             anti_term_self=zb(B, d.AT),
-            pref_pair_weights=zf(B, TPA),
             group_ids=zi(B, d.GP),
             group_valid=zb(B, d.GP),
             svc_aff_fixed=zi(B, SA),
@@ -2816,9 +2825,7 @@ class SnapshotEncoder:
             image_bytes=zf(B, d.C),
             new_vol_counts=zf(B, d.VT),
             disk_vol_ids=zi(B, d.DV),
-            vol_zone_pairs=zb(B, d.VZ, TPV),
             vol_zone_valid=zb(B, d.VZ),
-            vol_bind_pairs=zb(B, d.VB, TPV),
             vol_bind_valid=zb(B, d.VB),
             vol_fail_all=zb(B),
         )
@@ -2854,12 +2861,14 @@ class SnapshotEncoder:
         # binder assumptions).
         local_first: Dict[Tuple, int] = {}
         local_hits: Dict[int, List[int]] = {}
-        aff_rows: List[int] = []
+        # (b, static class or -1) of each pod whose row is encoded below
+        todo: List[Tuple[int, int]] = []
         for b, pod in enumerate(pods):
             ck = self._pod_static_key(pod)
+            c = -1
             if ck is not None:
-                c = class_of.get(ck)
-                if c is None:
+                c = class_of.get(ck, -1)
+                if c < 0:
                     c = class_of[ck] = len(class_keys)
                     class_keys.append(ck)
                     hit_groups.append([])
@@ -2873,13 +2882,57 @@ class SnapshotEncoder:
                 if hit:
                     hit_groups[c].append(b)
                     continue
-            lk = self._pod_local_key(pod) if ck is None else None
-            if lk is not None:
-                first = local_first.get(lk)
-                if first is not None:
-                    local_hits.setdefault(first, []).append(b)
-                    continue
-                local_first[lk] = b
+            else:
+                lk = self._pod_local_key(pod)
+                if lk is not None:
+                    first = local_first.get(lk)
+                    if first is not None:
+                        local_hits.setdefault(first, []).append(b)
+                        continue
+                    local_first[lk] = b
+            todo.append((b, c))
+
+        # the TP-wide pair leaves of the families the batch is not lean
+        # in (the lean ones stay [B, ..., 1] zeros in `out`).  They hold a
+        # row per call-local class or keyless pod: row_of[b] is pod b's.
+        # The padding and every static-class pod share a zero row, since
+        # _pod_static_key admits no pod with affinity or volumes, nor any
+        # while live terms exist.  With pair_rows the leaves leave as
+        # PairRows (the class rows and row_of) unless there are more
+        # rows than pack_tree ships factored; else each class's row is
+        # copied to its pods.
+        pair_spec = dict(
+            forbidden_pairs=((), bool, aff_lean),
+            aff_term_pairs=((d.PT,), bool, aff_lean),
+            anti_term_pairs=((d.AT,), bool, aff_lean),
+            pref_pair_weights=((), f32, aff_lean),
+            vol_zone_pairs=((d.VZ,), bool, vol_lean),
+            vol_bind_pairs=((d.VB,), bool, vol_lean),
+        )
+        for k, (trail, dt, lean) in pair_spec.items():
+            if lean:
+                out[k] = np.zeros((B,) + trail + (1,), dt)
+        wide = not (aff_lean and vol_lean)
+        n_rows = 0
+        row_of = np.zeros(B, np.int32)
+        if wide:
+            n_rows = int(len(pods) < B or bool(class_keys))
+            for b, cls in todo:
+                if cls < 0:
+                    row_of[b] = n_rows
+                    n_rows += 1
+            for first, idxs in local_hits.items():
+                row_of[idxs] = row_of[first]
+        factored = wide and pair_rows and n_rows <= factor_row_limit(B)
+        pairs = {
+            k: np.zeros((n_rows if factored else B,) + trail + (d.TP,), dt)
+            for k, (trail, dt, lean) in pair_spec.items() if not lean
+        }
+
+        aff_rows: List[Tuple[int, int]] = []
+        for b, cls in todo:
+            pod = pods[b]
+            r = int(row_of[b]) if factored else b
             out["valid"][b] = True
             req = self._req_vector(pod.resource_request())
             out["req"][b, : req.shape[0]] = req
@@ -2953,7 +3006,7 @@ class SnapshotEncoder:
                         )
             # the pair columns come in one pass after this loop; a row
             # the cache keeps has none (no affinity, no live term group)
-            aff_rows.append(b)
+            aff_rows.append((b, r))
             for j, kid in enumerate(self.service_affinity_keys):
                 v = pod.spec.node_selector.get(it.string(kid))
                 if v is not None:
@@ -2978,21 +3031,21 @@ class SnapshotEncoder:
                 out["disk_vol_ids"][b, j] = dv
             zone_terms, bind_terms, fail_all = self._pod_volume_terms(pod)
             out["vol_fail_all"][b] = fail_all
-            for j, pairs in enumerate(zone_terms[: d.VZ]):
-                out["vol_zone_pairs"][b, j] = pairs[: d.TP]
+            for j, prs in enumerate(zone_terms[: d.VZ]):
+                pairs["vol_zone_pairs"][r, j] = prs[: d.TP]
                 out["vol_zone_valid"][b, j] = True
-            for j, pairs in enumerate(bind_terms[: d.VB]):
-                out["vol_bind_pairs"][b, j] = pairs[: d.TP]
+            for j, prs in enumerate(bind_terms[: d.VB]):
+                pairs["vol_bind_pairs"][r, j] = prs[: d.TP]
                 out["vol_bind_valid"][b, j] = True
-            if ck is not None:
-                self._pod_row_cache[ck] = {
+            if cls >= 0:
+                self._pod_row_cache[class_keys[cls]] = {
                     k: np.copy(v[b]) for k, v in out.items()
                 }
 
         if not aff_lean and aff_rows:
             with (affinity_phase or contextlib.nullcontext)():
-                for b in aff_rows:
-                    self._encode_pod_affinity(out, b, pods[b])
+                for b, r in aff_rows:
+                    self._encode_pod_affinity(out, b, pods[b], pairs, r)
 
         for first, idxs in local_hits.items():
             ia = np.asarray(idxs, np.intp)
@@ -3009,6 +3062,18 @@ class SnapshotEncoder:
             ia = np.asarray(idxs, np.intp)
             for k, v in cached.items():
                 out[k][ia] = v
+
+        if wide:
+            with (pair_phase(n_rows, factored) if pair_phase
+                  else contextlib.nullcontext()):
+                if factored:
+                    pairs = {k: PairRows(v, row_of) for k, v in pairs.items()}
+                else:
+                    for first, idxs in local_hits.items():
+                        ia = np.asarray(idxs, np.intp)
+                        for v in pairs.values():
+                            v[ia] = v[first]
+        out.update(pairs)
 
         # state-dependent, so computed fresh every call (outside the row
         # cache): per-node counts of existing pods matching ALL of each pod's
@@ -3278,8 +3343,10 @@ class SnapshotEncoder:
                 pairs += np.bincount(pids, minlength=self.dims.TP).astype(np.float32)
         return pairs, kid
 
-    def _encode_pod_affinity(self, out, b: int, pod: Pod) -> None:
-        """Fill forbidden/affinity pair tensors for one incoming pod.
+    def _encode_pod_affinity(self, out, b: int, pod: Pod, pairs,
+                             r: int) -> None:
+        """Fill forbidden/affinity pair tensors for one incoming pod: its
+        term columns at out[...][b], its pair columns at pairs[...][r].
 
         forbidden_pairs: existing pods' required anti-affinity terms that match
         this pod forbid their topology pairs (ref predicates.go
@@ -3297,20 +3364,20 @@ class SnapshotEncoder:
             if not self._matches_one(g.selector, g.namespaces, pod):
                 continue
             if g.kind == K_ANTI_REQ:
-                out["forbidden_pairs"][b] |= g.pair_counts[: d.TP] > 0
+                pairs["forbidden_pairs"][r] |= g.pair_counts[: d.TP] > 0
             elif g.kind == K_ANTI_PREF:
-                out["pref_pair_weights"][b] -= g.weight * g.pair_counts[: d.TP]
+                pairs["pref_pair_weights"][r] -= g.weight * g.pair_counts[: d.TP]
             elif g.kind == K_AFF_PREF:
-                out["pref_pair_weights"][b] += g.weight * g.pair_counts[: d.TP]
+                pairs["pref_pair_weights"][r] += g.weight * g.pair_counts[: d.TP]
             elif g.kind == K_AFF_REQ and hard_w:
-                out["pref_pair_weights"][b] += hard_w * g.pair_counts[: d.TP]
+                pairs["pref_pair_weights"][r] += hard_w * g.pair_counts[: d.TP]
         aff = pod.spec.affinity
         if aff is None:
             return
         if aff.pod_affinity:
             for j, term in enumerate(aff.pod_affinity.required[: d.PT]):
-                pairs, kid = self._term_pairs(term, pod.namespace)
-                out["aff_term_pairs"][b, j] = pairs > 0
+                cnt, kid = self._term_pairs(term, pod.namespace)
+                pairs["aff_term_pairs"][r, j] = cnt > 0
                 out["aff_term_valid"][b, j] = True
                 out["aff_term_topo_key"][b, j] = kid
                 sel = _sel_requirements(term.label_selector)
@@ -3320,12 +3387,12 @@ class SnapshotEncoder:
                     and sel.matches(pod.labels)
                 )
             for wt in aff.pod_affinity.preferred:
-                pairs, _ = self._term_pairs(wt.term, pod.namespace)
-                out["pref_pair_weights"][b] += float(wt.weight) * pairs
+                cnt, _ = self._term_pairs(wt.term, pod.namespace)
+                pairs["pref_pair_weights"][r] += float(wt.weight) * cnt
         if aff.pod_anti_affinity:
             for j, term in enumerate(aff.pod_anti_affinity.required[: d.AT]):
-                pairs, kid = self._term_pairs(term, pod.namespace)
-                out["anti_term_pairs"][b, j] = pairs > 0
+                cnt, kid = self._term_pairs(term, pod.namespace)
+                pairs["anti_term_pairs"][r, j] = cnt > 0
                 out["anti_term_valid"][b, j] = True
                 out["anti_term_topo_key"][b, j] = kid
                 sel = _sel_requirements(term.label_selector)
@@ -3335,5 +3402,5 @@ class SnapshotEncoder:
                     and sel.matches(pod.labels)
                 )
             for wt in aff.pod_anti_affinity.preferred:
-                pairs, _ = self._term_pairs(wt.term, pod.namespace)
-                out["pref_pair_weights"][b] -= float(wt.weight) * pairs
+                cnt, _ = self._term_pairs(wt.term, pod.namespace)
+                pairs["pref_pair_weights"][r] -= float(wt.weight) * cnt

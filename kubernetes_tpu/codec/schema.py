@@ -182,6 +182,55 @@ def _pow2(n: int, floor: int = 1) -> int:
     return 1 << (n - 1).bit_length()
 
 
+# pack_tree's row factoring (codec/transfer.py): a [B, ...] leaf of at
+# least _FACTOR_MIN_BYTES ships as its distinct rows plus an i32[B] index
+# while it has at most factor_row_limit(B) distinct rows.  Real workloads
+# are either controller-stamped (few distinct rows) or essentially
+# unique-rowed (~B), and the coarse pow2 bucket with a floor of 32 keeps
+# the packed meta — the jit cache key — stable across the batches of one
+# workload; a factored<->dense flip needs a 64x change in row cardinality,
+# which is workload drift, not batch noise.
+_FACTOR_MIN_BYTES = 1 << 20
+_FACTOR_MAX_FRAC = 8
+
+
+def factor_row_limit(b: int) -> int:
+    """Most distinct rows a [b, ...] leaf may have and still ship factored."""
+    return max(32, b // _FACTOR_MAX_FRAC)
+
+
+class PairRows:
+    """A [B, ...] batch leaf held as class rows: `rows` [C, ...], one per
+    pod class, and `index` i32[B], so row b of the dense leaf is
+    rows[index[b]].  encode_pods emits the TP-wide pair leaves so when its
+    caller asks (pair_rows=True); pack_tree ships them without expanding
+    them; dense() is the array every other reader takes.  Deliberately not
+    a pytree node: to jax.tree_util it is one leaf, so a PodBatch holding
+    it flattens to the same treedef as the dense batch."""
+
+    __slots__ = ("rows", "index")
+
+    def __init__(self, rows: np.ndarray, index: np.ndarray):
+        self.rows = rows
+        self.index = index
+
+    @property
+    def shape(self) -> tuple:
+        return (self.index.shape[0],) + self.rows.shape[1:]
+
+    @property
+    def dtype(self):
+        return self.rows.dtype
+
+    @property
+    def nbytes(self) -> int:
+        """Bytes of the dense leaf."""
+        return int(np.prod(self.shape)) * self.rows.itemsize
+
+    def dense(self) -> np.ndarray:
+        return self.rows[self.index]
+
+
 def aimd_pow2_widths(batch_size_min: int, batch_size: int) -> "list[int]":
     """The distinct pow2 ENCODE widths the AIMD batch sizer can visit while
     ramping from batch_size_min to batch_size: the additive-increase steps
@@ -411,6 +460,18 @@ class PodBatch:
     @property
     def n_pods(self) -> int:
         return self.req.shape[0]
+
+    def dense(self) -> "PodBatch":
+        """This batch with each PairRows leaf expanded to its [B, ...]
+        array; the batch itself when it holds none."""
+        kw = {f: getattr(self, f).dense() for f in PAIR_FIELDS
+              if isinstance(getattr(self, f), PairRows)}
+        return dataclasses.replace(self, **kw) if kw else self
+
+
+# PodBatch's TP-wide leaves: the ones encode_pods may emit as PairRows
+PAIR_FIELDS = ("forbidden_pairs", "aff_term_pairs", "anti_term_pairs",
+               "pref_pair_weights", "vol_zone_pairs", "vol_bind_pairs")
 
 
 @dataclass(frozen=True)

@@ -32,7 +32,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from kubernetes_tpu.codec import faults
-from kubernetes_tpu.codec.schema import _pow2
+from kubernetes_tpu.codec.schema import (
+    _FACTOR_MIN_BYTES,
+    PairRows,
+    _pow2,
+    factor_row_limit,
+)
 from kubernetes_tpu.utils.trace import annotation
 
 # ------------------------------------------------------ transfer accounting
@@ -191,17 +196,19 @@ def ready_fence(tree, tag: str = "fence"):
 
 
 class _FetchWorker:
-    """One persistent daemon thread draining AsyncFetch jobs — per-cycle
-    thread create/teardown was measurable under trickle arrival (hundreds
-    of cycles/s), and a DAEMON thread (unlike a ThreadPoolExecutor's
-    joined workers) cannot let a hung fetch block interpreter exit."""
+    """One persistent daemon thread draining jobs in submission order
+    (AsyncFetch's, or the telemetry hub's sample materialization) —
+    per-cycle thread create/teardown was measurable under trickle arrival
+    (hundreds of cycles/s), and a DAEMON thread (unlike a
+    ThreadPoolExecutor's joined workers) cannot let a hung fetch block
+    interpreter exit."""
 
-    def __init__(self) -> None:
+    def __init__(self, name: str) -> None:
         import queue as _q
 
         self._jobs: Any = _q.SimpleQueue()
         self.thread = threading.Thread(
-            target=self._drain, daemon=True, name="ktpu-async-fetch"
+            target=self._drain, daemon=True, name=name
         )
         self.thread.start()
 
@@ -225,19 +232,25 @@ class _FetchWorker:
                 traceback.print_exc()
 
 
-_FETCH_WORKER: "_FetchWorker | None" = None
-_FETCH_WORKER_LOCK = threading.Lock()
+_WORKERS: "dict[str, _FetchWorker]" = {}
+_WORKERS_LOCK = threading.Lock()
+
+
+def daemon_worker(name: str) -> _FetchWorker:
+    """The process's worker thread `name`, started on first use (and
+    again after a fork).  A job queued on one worker never waits behind
+    another worker's jobs."""
+    w = _WORKERS.get(name)
+    if w is None or not w.thread.is_alive():  # first use, or post-fork
+        with _WORKERS_LOCK:
+            w = _WORKERS.get(name)
+            if w is None or not w.thread.is_alive():
+                w = _WORKERS[name] = _FetchWorker(name)
+    return w
 
 
 def _fetch_worker() -> _FetchWorker:
-    global _FETCH_WORKER
-    w = _FETCH_WORKER
-    if w is None or not w.thread.is_alive():  # first use, or post-fork
-        with _FETCH_WORKER_LOCK:
-            w = _FETCH_WORKER
-            if w is None or not w.thread.is_alive():
-                w = _FETCH_WORKER = _FetchWorker()
-    return w
+    return daemon_worker("ktpu-async-fetch")
 
 
 class AsyncFetch:
@@ -367,18 +380,29 @@ def _group(dtype) -> str:
     raise TypeError(f"unsupported leaf dtype {dtype!r}")
 
 
-# Leaves at least this big get row-deduplicated before packing: workload
-# batches are controller-stamped, so the [B, ...] pair/mask tensors repeat
-# a handful of distinct rows and the wire cost collapses ~B/G x.  Content
-# (bytes) keyed — no semantic assumption can go stale.
-_FACTOR_MIN_BYTES = 1 << 20
-# Factoring wins only while the unique-row bucket stays <= B/8: real
-# workloads are either controller-stamped (U ~ #deployments, tiny) or
-# essentially unique-rowed (U ~ B).  The coarse pow2 bucket with a floor
-# of 32 keeps meta — and therefore the jit cache key — stable across the
-# batches of one workload; a factored<->dense flip needs a 64x change in
-# row cardinality, which is workload drift, not batch noise.
-_FACTOR_MAX_FRAC = 8
+def factors_by_default() -> bool:
+    """Whether pack_tree row-factors when not told: on every backend but
+    the CPU's, where there is no transfer to save."""
+    return jax.default_backend() != "cpu"
+
+
+def _unique_rows(rows: np.ndarray, order, max_u: int):
+    """Deduplicate rows[order] by content, in that order: (the distinct
+    rows, i32 index of each order element into them), or None once more
+    than max_u distinct rows appear (factoring can no longer win)."""
+    seen: dict = {}
+    uniq_rows: list = []
+    idx = np.empty(len(order), np.int32)
+    for i, r in enumerate(order):
+        key = rows[r].tobytes()
+        u = seen.get(key)
+        if u is None:
+            if len(uniq_rows) >= max_u:
+                return None
+            u = seen[key] = len(uniq_rows)
+            uniq_rows.append(rows[r])
+        idx[i] = u
+    return uniq_rows, idx
 
 
 def pack_tree(tree, factor: "bool | None" = None) -> Tuple[Tuple[np.ndarray, ...], Any]:
@@ -394,11 +418,18 @@ def pack_tree(tree, factor: "bool | None" = None) -> Tuple[Tuple[np.ndarray, ...
     unique rows (pow2-padded, floor 32) plus an i32[B] row index;
     unpack_tree gathers the dense leaf back ON DEVICE: a 2048-pod
     anti-affinity batch carries ~150MB of dense pair tensors with ~20
-    distinct rows.  factor=None auto-disables on the CPU backend
-    (no transfer to save); tests pass factor=True to force the path.
+    distinct rows.  factor=None follows factors_by_default() (off on
+    the CPU backend); tests pass factor=True to force the path.
+
+    A PairRows leaf (encode_pods' class-row form of a pair leaf) ships
+    the same way without hashing its B dense rows: its few class rows are
+    deduplicated by content in order of first use, which gives the uniq,
+    idx and meta the dense leaf would give.  Where a leaf does not ship
+    factored, it is expanded first, so the wire form never depends on
+    which form the caller held.
     """
     if factor is None:
-        factor = jax.default_backend() != "cpu"
+        factor = factors_by_default()
     leaves, treedef = jax.tree_util.tree_flatten(tree)
     chunks = {g: [] for g in _GROUPS}
     offs = {g: 0 for g in _GROUPS}
@@ -410,34 +441,43 @@ def pack_tree(tree, factor: "bool | None" = None) -> Tuple[Tuple[np.ndarray, ...
         offs[g] += flat.size
         chunks[g].append(flat)
 
+    def _append_factored(uniq_rows, idx, dtype, g, shape):
+        U = max(32, _pow2(len(uniq_rows)))
+        uniq = np.zeros((U,) + uniq_rows[0].shape, dtype)
+        uniq[: len(uniq_rows)] = uniq_rows
+        # factored leaf = two packed entries: uniq then idx
+        _append(uniq, g, "uniq", (U,) + shape[1:])
+        _append(idx, "i", "idx", (shape[0],))
+
     for leaf in leaves:
+        if isinstance(leaf, PairRows):
+            # pre-factored by the encoder: dedupe its few class rows by
+            # content, in order of first use, which gives the very uniq
+            # and idx the row hashing below gives for the dense leaf
+            g = _group(leaf.dtype)
+            if factor and leaf.nbytes >= _FACTOR_MIN_BYTES \
+                    and leaf.shape[0] > 1:
+                rows = leaf.rows.reshape(len(leaf.rows), -1)
+                used, first = np.unique(leaf.index, return_index=True)
+                order = used[np.argsort(first)]
+                got = _unique_rows(rows, order, factor_row_limit(leaf.shape[0]))
+                if got is not None:
+                    uniq_rows, u = got
+                    remap = np.zeros(len(rows), np.int32)
+                    remap[order] = u
+                    _append_factored(uniq_rows, remap[leaf.index],
+                                     leaf.dtype, g, leaf.shape)
+                    continue
+            leaf = leaf.dense()
         a = np.asarray(leaf)
         g = _group(a.dtype)
         if factor and a.nbytes >= _FACTOR_MIN_BYTES and a.ndim >= 1 \
                 and a.shape[0] > 1:
             B = a.shape[0]
-            max_u = max(32, B // _FACTOR_MAX_FRAC)
             rows = a.reshape(B, -1)
-            seen: dict = {}
-            idx = np.empty(B, np.int32)
-            uniq_rows = []
-            for r in range(B):
-                key = rows[r].tobytes()
-                u = seen.get(key)
-                if u is None:
-                    if len(uniq_rows) >= max_u:
-                        uniq_rows = None  # early bail: can never win now
-                        break
-                    u = seen[key] = len(uniq_rows)
-                    uniq_rows.append(rows[r])
-                idx[r] = u
-            if uniq_rows is not None:
-                U = max(32, _pow2(len(uniq_rows)))
-                uniq = np.zeros((U, rows.shape[1]), a.dtype)
-                uniq[: len(uniq_rows)] = uniq_rows
-                # factored leaf = two packed entries: uniq then idx
-                _append(uniq, g, "uniq", (U,) + a.shape[1:])
-                _append(idx, "i", "idx", (B,))
+            got = _unique_rows(rows, range(B), factor_row_limit(B))
+            if got is not None:
+                _append_factored(got[0], got[1], a.dtype, g, a.shape)
                 continue
         _append(a, g, None, a.shape)
     bufs = tuple(

@@ -49,6 +49,7 @@ from kubernetes_tpu.codec.faults import (
     classify_device_error,
 )
 from kubernetes_tpu.codec import faults as device_faults
+from kubernetes_tpu.codec import transfer
 from kubernetes_tpu.codec.transfer import AsyncFetch, fence_split, host_fetch
 from kubernetes_tpu.ops.predicates import filter_batch, required_affinity_ok
 from kubernetes_tpu.runtime.cache import SchedulerCache
@@ -96,6 +97,10 @@ TAINT_NODE_UNSCHEDULABLE = "node.kubernetes.io/unschedulable"
 #                 encoder's per-pod term and pair columns
 #   fence_redo    the part of host_stall spent on batches whose engine
 #                 answer came from the exact redo
+#   encode_pairs  the part of encode that spreads a batch's TP-wide pair
+#                 leaves over its pods: each class row copied to the
+#                 class's pods (dense), or the class rows and row index
+#                 handed on as they are (factored)
 PARTITION_PHASES = (
     "pop", "encode", "dispatch", "host_stall", "commit", "preempt",
     "observe",
@@ -103,6 +108,7 @@ PARTITION_PHASES = (
 OVERLAP_PHASES = (
     "fetch", "fence_queue", "fence_device", "fence_copy", "program_load",
     "commit_state", "commit_tail", "encode_affinity", "fence_redo",
+    "encode_pairs",
 )
 
 
@@ -1869,9 +1875,23 @@ class Scheduler:
                 if len(pods) > 1 and batch_has_pod_affinity(pods):
                     with aff_phase():
                         aff_state = encode_batch_affinity(enc, pods)
+                def pair_phase(rows, factored):
+                    m.ENCODE_PAIR_LEAVES.inc(
+                        form="factored" if factored else "dense")
+                    return self._phase_span(trace, "encode_pairs", tier,
+                                            pair_rows=rows)
+
                 classes = np.full(len(pods), -1, np.int32)
-                batch = enc.encode_pods(pods, classes,
-                                        affinity_phase=aff_phase)
+                # the speculative engine's device launch packs the batch
+                # with row factoring (pack_tree), so it takes the pair
+                # leaves as class rows; every other reader gets the
+                # dense batch (batch.dense())
+                batch = enc.encode_pods(
+                    pods, classes, affinity_phase=aff_phase,
+                    pair_rows=(use_device and self._speculative_fn is not None
+                               and transfer.factors_by_default()),
+                    pair_phase=pair_phase,
+                )
                 ports = encode_batch_ports(enc, pods)
                 # two-pass evaluation: nominated pods (other than those
                 # being scheduled now) are added to their nominated nodes
@@ -1922,14 +1942,15 @@ class Scheduler:
                 fwk.tensor_filter_plugins or fwk.tensor_score_plugins
             ):
                 B, N = batch.n_pods, cluster.n_nodes
+                dense = batch.dense()
                 if fwk.tensor_filter_plugins:
                     extra_mask = np.asarray(fwk.run_filter_tensor(
-                        pc, cluster, batch, np.ones((B, N), bool)
+                        pc, cluster, dense, np.ones((B, N), bool)
                     ))
                 if fwk.tensor_score_plugins:
                     extra_score = np.asarray(
                         fwk.run_score_tensor(
-                            pc, cluster, batch, np.zeros((B, N), np.float32)
+                            pc, cluster, dense, np.zeros((B, N), np.float32)
                         ),
                         np.float32,
                     )
@@ -2134,7 +2155,7 @@ class Scheduler:
             # the launch box so a hub-mode retry re-points the recorded
             # cluster at the snapshot the retry actually consumed.
             inf.ledger_inputs = dict(
-                cluster=cluster_used, batch=batch, ports=ports,
+                cluster=cluster_used, batch=batch.dense(), ports=ports,
                 nominated=nominated, aff_state=aff_state,
                 extra_mask=extra_mask, extra_score=extra_score,
                 last_index0=last_index0,

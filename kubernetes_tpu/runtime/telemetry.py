@@ -50,6 +50,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from kubernetes_tpu.codec.transfer import daemon_worker
 from kubernetes_tpu.ops.analytics import (
     analytics_to_dict,
     cluster_analytics_auto,
@@ -299,10 +300,11 @@ class TelemetryHub:
     readers (metrics scrape, /debug/cluster, heartbeat, bench) come from
     other threads and take the hub lock only around ring/summary state.
 
-    Analytics cadence is AMORTIZED: on each due cycle the hub first
-    materializes the launch dispatched one interval ago (a ~50-float
-    D2H that has long since landed) and only then dispatches the next —
-    the scheduling thread never waits on analytics compute."""
+    Analytics cadence is AMORTIZED: on each due cycle the hub dispatches
+    one launch and hands its output to the `ktpu-telemetry` worker
+    thread, which fetches it (a ~50-float D2H), sets the cluster gauges
+    and appends the ring sample — the scheduling thread never waits on
+    analytics compute nor builds the sample."""
 
     def __init__(
         self,
@@ -320,9 +322,9 @@ class TelemetryHub:
         self._ewma_alpha = float(ewma_alpha)
         self._lock = threading.Lock()
         self._ring: deque = deque(maxlen=max(1, int(ring_capacity)))
-        # in-flight analytics: (cycle, tier, device-output pytree or
-        # host ClusterAnalytics, source tag)
-        self._pending: Optional[Tuple[int, str, object, str]] = None
+        # set once the newest dispatched analytics launch is a sample
+        self._landed = threading.Event()
+        self._landed.set()
         self.analytics: Optional[dict] = None  # last materialized sample
         self.analytics_cycle = -1
         self.samples_total = 0
@@ -420,16 +422,13 @@ class TelemetryHub:
         if self._cycles_since_dispatch < self.interval_cycles:
             return
         self._cycles_since_dispatch = 0
-        # materialize the PREVIOUS interval's launch (long since landed),
-        # then dispatch the next — the amortization that keeps this hook
-        # off the critical path
-        sample = self._materialize_pending()
-        if sample is not None and span is not None:
+        # the span carries the newest sample that has landed (the
+        # previous interval's, unless the worker is still on it)
+        a = self.analytics
+        if a is not None and span is not None:
             span.annotate(
-                cluster_util_cpu=sample["analytics"]["utilization"]["cpu"][
-                    "mean"
-                ],
-                cluster_fragmentation=sample["analytics"]["fragmentation"],
+                cluster_util_cpu=a["utilization"]["cpu"]["mean"],
+                cluster_fragmentation=a["fragmentation"],
             )
         if resident is not None:
             # mesh-aware dispatch (ops/analytics.py): sharded resident
@@ -438,10 +437,21 @@ class TelemetryHub:
             # vs the numpy reference; single-chip buffers take the
             # classic kernel unchanged
             out = cluster_analytics_auto(*resident)
-            self._pending = (cycle, tier, out, "device")
+            source = "device"
         elif host_snapshot is not None:
             out = cluster_analytics_np(*host_snapshot)
-            self._pending = (cycle, tier, out, "host")
+            source = "host"
+        else:
+            return
+        # the fetch, the gauges and the ring sample run on the telemetry
+        # worker, in dispatch order: the scheduling thread neither waits
+        # for the launch nor pays for turning it into a sample
+        landed = threading.Event()
+        self._landed = landed
+        pending = (cycle, tier, out, source)
+        daemon_worker("ktpu-telemetry").submit(
+            lambda: self._materialize(pending, landed)
+        )
 
     def record_mesh(
         self,
@@ -481,22 +491,27 @@ class TelemetryHub:
 
     # ------------------------------------------------------ materialize
 
-    def _materialize_pending(self) -> Optional[dict]:
-        """Fetch the in-flight analytics launch (if any) into a ring
-        sample, updating the cluster gauges.  Cheap by construction: the
-        launch is one interval old and its output is ~50 floats."""
-        with self._lock:  # readers race the scheduling thread here
-            pending, self._pending = self._pending, None
-        if pending is None:
-            return None
-        cycle, tier, out, source = pending
+    def _await_samples(self) -> None:
+        """Wait until the newest dispatched analytics launch is a ring
+        sample (readers call this so they see the latest snapshot)."""
+        self._landed.wait()
+
+    def _materialize(self, pending, landed: threading.Event) -> None:
+        """Worker job: fetch one analytics launch (~50 floats) into a ring
+        sample, updating the cluster gauges."""
+        try:
+            self._sample(*pending)
+        finally:
+            landed.set()
+
+    def _sample(self, cycle, tier, out, source) -> None:
         try:
             host = type(out)(
                 *(np.asarray(x) for x in _leaves_in_order(out))
             )
         except Exception:  # noqa: BLE001 — a faulted launch loses ONE
             #                 sample, never the telemetry stream
-            return None
+            return
         a = analytics_to_dict(host)
         self._set_cluster_gauges(a)
         sample = {
@@ -519,7 +534,6 @@ class TelemetryHub:
             self._ring.append(sample)
             self.samples_total += 1
         m.TELEMETRY_SAMPLES.inc()
-        return sample
 
     @staticmethod
     def _set_cluster_gauges(a: dict) -> None:
@@ -548,7 +562,7 @@ class TelemetryHub:
     def summary(self) -> dict:
         """Latest materialized analytics + hub accounting — the bench
         `cluster_health` stage body."""
-        self._materialize_pending()
+        self._await_samples()
         with self._lock:
             out = {
                 "analytics": self.analytics,
@@ -571,7 +585,7 @@ class TelemetryHub:
         """GET /debug/cluster body: newest-first bounded sample series +
         the summary.  `limit` keeps the newest n samples (the shared
         debug_body halves it further until the body fits the 4MB cap)."""
-        self._materialize_pending()
+        self._await_samples()
         with self._lock:
             samples = list(self._ring)
         if limit is not None and limit >= 0:

@@ -625,6 +625,15 @@ ENGINE_ROUNDS = REGISTRY.register(
         "over its batches",
     )
 )
+ENCODE_PAIR_LEAVES = REGISTRY.register(
+    LabeledCounter(
+        "scheduler_encode_pair_leaves_total",
+        "Batches whose TP-wide pair leaves the live cycle encoded (those "
+        "not affinity-lean or not volume-lean), by form: 'factored' = "
+        "one row per pod class plus a row index, 'dense' = a row per pod",
+        ("form",),
+    )
+)
 
 # device-resident megacycle (ISSUE 12): K pre-encoded batches chained
 # through the cluster state in one XLA launch (models/megacycle.py)

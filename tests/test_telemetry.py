@@ -325,6 +325,36 @@ def test_degraded_cycle_falls_back_to_host_analytics():
     assert payload["samples"][-1]["source"] == "host"
 
 
+def test_samples_are_built_on_the_telemetry_worker():
+    """on_cycle dispatches and returns: the fetch, the gauges and the
+    ring sample are the worker's, in dispatch order, and summary() waits
+    for the newest of them."""
+    import threading
+
+    hub = TelemetryHub()
+    built = []
+    sample = hub._sample
+
+    def record(cycle, *rest):
+        built.append((cycle, threading.current_thread().name))
+        sample(cycle, *rest)
+
+    hub._sample = record
+    N, R = 4, 8
+    alloc = np.ones((N, R), np.float32)
+    req = np.zeros((N, R), np.float32)
+    valid = np.ones(N, bool)
+    for c in range(5):
+        hub.on_cycle(cycle=c, tier="bulk", cycle_s=0.01, placed=1,
+                     unschedulable=0, host_snapshot=(alloc, req, valid))
+    s = hub.summary()
+    assert built == [(c, "ktpu-telemetry") for c in range(5)]
+    assert s["samples"] == 5 and s["cycle"] == 4
+    assert [x["cycle"] for x in hub.debug_payload()["samples"]] == [
+        0, 1, 2, 3, 4,
+    ]
+
+
 def test_telemetry_off_removes_the_hook():
     sched, queue = _mini_scheduler(telemetry=False)
     assert sched.telemetry is None
