@@ -475,22 +475,6 @@ def run(args) -> dict:
             )
         except Exception as e:  # noqa: BLE001
             detail["latency_tiers_error"] = f"{type(e).__name__}: {e}"
-        # ---- megacycle stage (ISSUE 12): a scaled-down K-sweep (K <= 4,
-        # shape capped like the sharded stage) — per-K pods/s + host
-        # seconds per pod + the K-vs-1 placement-identity pin.  CPU
-        # child only like the tier stage (a control-plane figure;
-        # --megacycle is the standalone full-scale sweep)
-        try:
-            mega_args = argparse.Namespace(**vars(args))
-            mega_args.nodes = min(args.nodes, 1000)
-            mega_args.pods = min(args.pods, 4096)
-            mega_args.batch = min(args.batch, 256)
-            detail["megacycle"] = run_megacycle(
-                mega_args,
-                ks=[k for k in (1, 2, 4) if k <= args.megacycle_max],
-            )
-        except Exception as e:  # noqa: BLE001
-            detail["megacycle_error"] = f"{type(e).__name__}: {e}"
         # ---- replica stage (ISSUE 14): a scaled-down N sweep (N = 1, 2)
         # of the queue-sharded replica set + the multi-tenant storm —
         # scaling factor, conflict rate, zero-lost-pods.  CPU child only
@@ -584,16 +568,6 @@ def run(args) -> dict:
         out["tiered_bulk_tput_ratio"] = detail["latency_tiers"][
             "bulk_tput_ratio"
         ]
-    if "megacycle" in detail:
-        # the megacycle acceptance pair, tracked at top level: best
-        # sweep throughput + host seconds per pod at the deepest K
-        # (the figure the device-resident loop exists to shrink), plus
-        # the K-vs-1 identity flag
-        out["megacycle_pods_per_s"] = detail["megacycle"]["best_pods_per_s"]
-        out["megacycle_host_s_per_pod"] = detail["megacycle"][
-            "host_s_per_pod_at_max_k"
-        ]
-        out["megacycle_identity"] = detail["megacycle"]["identical"]
     if "quality" in detail:
         # the placement-quality acceptance trio, tracked at top level:
         # decision confidence (tolerance-banded — a margin COLLAPSE and
@@ -1326,11 +1300,6 @@ def run_tiered(args, single_lane_ref: "float | None" = None) -> dict:
             cycle_deadline_s=deadline,
             express_lane=True, express_batch_size=express_width,
             express_priority_threshold=1000,
-            # megacycle-under-tiers leg (ISSUE 12 acceptance): the
-            # express preemption point sits BETWEEN megacycles, so the
-            # express p99 under a K-deep bulk backlog is the honest
-            # worst-case the megacycle adds; default 1 = the classic run
-            megacycle_batches=getattr(args, "tiered_megacycle", 1),
         ),
     )
     t_warm0 = time.monotonic()
@@ -1432,150 +1401,6 @@ def run_tiered(args, single_lane_ref: "float | None" = None) -> dict:
         "express_pods": len(exp_lat),
         "bulk_pods": len(bulk_binds),
         "cycle_deadline_s": deadline,
-        "megacycles": sched.megacycles_total,
-    }
-
-
-def run_megacycle(args, ks=None) -> dict:
-    """Megacycle K-sweep (ISSUE 12): the same live workload drained with
-    megacycleBatches = 1, 2, 4, ... — per-K pods/s, HOST seconds per pod
-    (the figure the megacycle exists to shrink: enqueue + fence stall +
-    commit from the perf observatory's split), and a placement-identity
-    pin of every K against K=1.  Each K gets a fresh cluster and the
-    SAME warmup pod set, so the pre-timed state is identical across the
-    sweep and the identity comparison is honest."""
-    from kubernetes_tpu.runtime.cache import SchedulerCache
-    from kubernetes_tpu.runtime.queue import PriorityQueue
-    from kubernetes_tpu.runtime.scheduler import Scheduler, SchedulerConfig
-
-    if ks is None:
-        ks = []
-        k = 1
-        while k <= max(1, args.megacycle_max):
-            ks.append(k)
-            k *= 2
-    kmax = max(ks)
-    # warm BOTH dispatch shapes the timed window can hit: enough depth
-    # to form (and compile) the K-deep megacycle ladder, plus a partial
-    # trailing batch so the single-cycle path (the sweep's tail window)
-    # is compiled too — a fresh compile inside a timed window would
-    # read as a K-regression
-    warm_n = args.batch * max(2, kmax) + max(1, args.batch // 2)
-    curve = []
-    placements = {}
-    for K in ks:
-        enc = _build_encoder(args)
-        cache = SchedulerCache(enc)
-        queue = PriorityQueue()
-        sched = Scheduler(
-            cache=cache, queue=queue, binder=lambda pod, node: True,
-            config=SchedulerConfig(
-                batch_size=args.batch, batch_window_s=0.0,
-                engine=args.engine, disable_preemption=True,
-                batched_commit=True, pipeline_commit=True,
-                megacycle_batches=K,
-            ),
-        )
-
-        def _drain(budget_s: float) -> int:
-            placed = 0
-            deadline = time.monotonic() + budget_s
-            while time.monotonic() < deadline:
-                got = sched.run_once(timeout=0.0)
-                placed += got
-                if got == 0 and not sched.pipeline_pending:
-                    if not queue.has_schedulable():
-                        break
-                    time.sleep(0.002)
-            return placed + sched.flush_pipeline()
-
-        # warmup: enough depth to form (and compile) the full-K ladder
-        # outside the timed window; same pod set for every K
-        for j in range(warm_n):
-            queue.add(_pending_pod(args, args.pods + j))
-        _drain(600)
-        host0 = sched.perfobs.summary()["host_s"]
-        mega0 = sched.megacycles_total
-        pending = [_pending_pod(args, i) for i in range(args.pods)]
-        t0 = time.monotonic()
-        for p in pending:
-            queue.add(p)
-        placed = _drain(900)
-        dt = time.monotonic() - t0
-        host_s = sched.perfobs.summary()["host_s"] - host0
-        placements[K] = {
-            (r.pod.namespace, r.pod.name): r.node for r in sched.results
-        }
-        curve.append({
-            "k": K,
-            "pods_per_s": round(placed / dt, 1) if dt > 0 else 0.0,
-            "host_s_per_pod": (
-                round(host_s / placed, 6) if placed else None
-            ),
-            "host_seconds": round(host_s, 3),
-            "seconds": round(dt, 3),
-            "placed": placed,
-            "megacycles": sched.megacycles_total - mega0,
-        })
-        sys.stderr.write(
-            f"bench: megacycle k={K}: {curve[-1]['pods_per_s']} pods/s, "
-            f"{curve[-1]['host_s_per_pod']} host s/pod, "
-            f"{curve[-1]['megacycles']} megacycles\n"
-        )
-    identical = all(placements[K] == placements[ks[0]] for K in ks)
-    host_curve = [
-        c["host_s_per_pod"] for c in curve
-        if c["host_s_per_pod"] is not None
-    ]
-    decreasing = all(
-        b < a for a, b in zip(host_curve, host_curve[1:])
-    )
-    best = max(curve, key=lambda c: c["pods_per_s"])
-    # express-under-megacycle leg (the acceptance line: express p99
-    # under a K-deep bulk backlog no worse than the tiered numbers):
-    # one tiered run with megacycleBatches=kmax — the express lane's
-    # preemption point sits between megacycles
-    express = None
-    if kmax > 1:
-        try:
-            t_args = argparse.Namespace(**vars(args))
-            t_args.tiered_megacycle = kmax
-            tiered = run_tiered(
-                t_args, single_lane_ref=curve[0]["pods_per_s"]
-            )
-            express = {
-                "express_p50_ms": tiered["tiers"]["express"].get("p50"),
-                "express_p99_ms": tiered["express_p99_ms"],
-                "bulk_tput_ratio": tiered["bulk_tput_ratio"],
-                "megacycles": tiered["megacycles"],
-                "k": kmax,
-            }
-        except Exception as e:  # noqa: BLE001 — the sweep still banks
-            express = {"error": f"{type(e).__name__}: {e}"}
-    return {
-        "curve": curve,
-        "identical": identical,
-        "host_s_per_pod_decreasing": decreasing,
-        "best_k": best["k"],
-        "best_pods_per_s": best["pods_per_s"],
-        "host_s_per_pod_at_max_k": curve[-1]["host_s_per_pod"],
-        "engine": args.engine,
-        **({"express_under_megacycle": express}
-           if express is not None else {}),
-    }
-
-
-def run_megacycle_metric(args) -> dict:
-    """--megacycle standalone mode: the K sweep as the run's one JSON
-    line (value = best pods/s across the sweep; the identity flag and
-    the host-seconds curve ride detail)."""
-    out = run_megacycle(args)
-    return {
-        "metric": "megacycle_k_sweep",
-        "value": out["best_pods_per_s"],
-        "unit": "pods/s",
-        "megacycle_identity": out["identical"],
-        "detail": out,
     }
 
 
@@ -2466,8 +2291,6 @@ def run_main(args) -> int:
             result = run_density(args)
         elif args.tiered:
             result = run_tiered_metric(args)
-        elif args.megacycle:
-            result = run_megacycle_metric(args)
         elif args.autoscale_live:
             result = run_autoscale_live(args)
         elif args.autoscale:
@@ -2609,16 +2432,6 @@ _BASELINE_CHECKS = (
      "lower", 2.0),
     ("node_encode_speedup", ("node_encode_speedup",), "higher", 1.0),
     ("express_p99_ms", ("express_p99_ms",), "lower", 1.5),
-    # megacycle (ISSUE 12): the chained-launch throughput and the host
-    # seconds it exists to shrink — a regression in the K-deep path
-    # (lost chaining, a per-sub-batch fence sneaking back) moves these
-    ("megacycle_pods_per_s",
-     ("megacycle_pods_per_s", "detail.megacycle.best_pods_per_s"),
-     "higher", 1.0),
-    ("megacycle_host_s_per_pod",
-     ("megacycle_host_s_per_pod",
-      "detail.megacycle.host_s_per_pod_at_max_k"),
-     "lower", 1.5),
     # placement quality (ISSUE 13): margin is BAND-gated — a collapse
     # (every decision a coin flip) and an explosion (scores diverged)
     # both mean the scoring function changed out from under us; the
@@ -2972,15 +2785,6 @@ def main():
                     "scheduler; reports per-tier p50/p99, bulk throughput "
                     "ratio vs single-lane, and a compile-inclusive "
                     "cold_start_seconds (the compile-cache figure)")
-    ap.add_argument("--megacycle", action="store_true",
-                    help="megacycle mode (ISSUE 12): sweep "
-                    "megacycleBatches K = 1, 2, 4, ... through the live "
-                    "path — pods/s + host seconds per pod per K, with "
-                    "every K's placements pinned identical to K=1")
-    ap.add_argument("--megacycle-max", type=int, default=8,
-                    help="deepest K the --megacycle sweep (and the "
-                    "default report's scaled-down megacycle stage, "
-                    "capped at 4 there) reaches")
     ap.add_argument("--autoscale", action="store_true",
                     help="capacity-planning what-if scenario (ISSUE 15):"
                     " class-compressed binpack of a duplicate-heavy "

@@ -158,13 +158,6 @@ def build_parser() -> argparse.ArgumentParser:
                    "records a bounded device+host trace there; a "
                    "graceful no-op where the backend lacks profiler "
                    "support")
-    p.add_argument("--megacycle-batches", type=int, default=None,
-                   help="chain up to K pre-encoded batches through the "
-                   "cluster state in one XLA launch (config "
-                   "megacycleBatches; default 1 = single-cycle "
-                   "dispatch).  Chain-safe batches only — anything "
-                   "carrying pod-affinity/ports/volumes/gangs rides the "
-                   "single-cycle path, placements identical either way")
     p.add_argument("--replicas", type=int, default=None,
                    help="run N queue-sharded scheduler replicas "
                    "(threads) over one cache/queue, committing through "
@@ -324,8 +317,6 @@ def main(argv=None) -> int:
         cc.invariant_checks = False
     if args.profile_dir is not None:
         cc.profile_dir = args.profile_dir
-    if args.megacycle_batches is not None:
-        cc.megacycle_batches = args.megacycle_batches
     if args.replicas is not None:
         cc.replicas = args.replicas
     if args.namespace_quotas is not None:

@@ -134,13 +134,6 @@ class KubeSchedulerConfiguration:
     # itself — host/device split, phase x width EWMA, transfer
     # accounting at /debug/perf — is always-on
     profile_dir: Optional[str] = None
-    # device-resident megacycle (runtime/scheduler.py +
-    # models/megacycle.py): chain up to this many pre-encoded batches
-    # through the cluster state in ONE XLA launch, committing the K
-    # winner vectors behind the next launch; 1 = single-cycle dispatch
-    # bit-for-bit.  Only chain-safe batches ride a megacycle (no
-    # pod-affinity/ports/volumes/gangs/nominated pods; lean spread)
-    megacycle_batches: int = 1
     # placement-quality observatory (runtime/quality.py): in-launch
     # winner-pinned top-k width (qualityTopK; 0 disables the seam —
     # placements bit-identical either way), the amortized FFD-regret
@@ -283,7 +276,6 @@ class KubeSchedulerConfiguration:
             ),
             invariant_checks=bool(d.get("invariantChecks", True)),
             profile_dir=d.get("profileDir"),
-            megacycle_batches=int(d.get("megacycleBatches", 1)),
             quality_top_k=int(d.get("qualityTopK", 3)),
             quality_interval_cycles=int(d.get("qualityIntervalCycles", 32)),
             quality_drift_threshold=float(

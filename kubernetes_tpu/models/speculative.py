@@ -878,12 +878,6 @@ def make_speculative_scheduler(
     # with repair — NOT sequential-commit ordered; gang scheduling's
     # cross-gang drop guard must never run on this engine
     schedule.engine_kind = "speculative"
-    # the raw traceable device path (while_loop rounds + in-program
-    # exactness redo) for callers composing INSIDE jit — the megacycle
-    # driver (models/megacycle.py) scans it over K chained batches.
-    # Signature: _impl(cluster, {"pods","pp","cf",...}, last_index0) ->
-    # (hosts, req, nz, rounds, inv, quality-or-None)
-    schedule.raw_impl = _impl
     # the jitted packed program per meta — what the device path
     # launches; the compile tests lower it for a described chip
     schedule.packed = _packed

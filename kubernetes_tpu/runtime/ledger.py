@@ -248,11 +248,6 @@ class DecisionLedger:
                 "degraded": bool(outcome.get("degraded", False)),
                 "time": time.time(),
                 "pods": decisions,
-                # (k, K) when the cycle was sub-batch k of a K-deep
-                # megacycle launch (ISSUE 12) — /debug/decisions readers
-                # can join the K blocks of one launch
-                **({"mega": outcome["mega"]}
-                   if outcome.get("mega") is not None else {}),
                 # queue-sharded replicas (ISSUE 14): dispatching replica
                 # + reconciler commit sequence, so /debug/decisions
                 # readers can reconstruct the cross-replica interleaving

@@ -4,8 +4,8 @@ transfer accounting, and on-demand XLA profiler capture (ISSUE 11).
 The r05 phase counters said dispatch was ~20 ms while fetch+commit burned
 ~370 ms per 10k pods — but nothing attributed that wall time between host
 Python, the wire, and actual device execution, which is exactly the
-measurement the device-resident megacycle work (ROADMAP item 2) and the
-learned-scoring loop (item 4) need.  This module is that instrument:
+measurement dispatch/fence work and the learned-scoring loop need.  This
+module is that instrument:
 
   * **Per-cycle cost model.**  The scheduler feeds `on_cycle` one record
     per committed cycle, split by the ready-fence timestamps around the
@@ -249,15 +249,10 @@ class PerfObservatory:
         wall_s: float,
         transfers: Optional[dict] = None,
         trace_id: str = "",
-        mega: Optional[Tuple[int, int]] = None,
     ) -> None:
         """Fold one committed cycle into the cost model.  `transfers` is
         the cycle's codec.transfer.transfer_delta — what the wire moved
-        between this cycle's dispatch and its commit tail.  `mega` =
-        (k, K) marks sub-batch k of a K-deep megacycle launch (ISSUE
-        12): its device/enqueue/wall figures are the 1/K share of the
-        one shared launch, reconstructed by the scheduler so the phase
-        totals still reconcile across the megacycle path."""
+        between this cycle's dispatch and its commit tail."""
         split = {
             "host_enqueue": float(enqueue_s),
             "device_execute": float(execute_s),
@@ -281,8 +276,6 @@ class PerfObservatory:
             ),
             "transfers": transfers or {},
             "trace_id": trace_id,
-            **({"mega": [int(mega[0]), int(mega[1])]}
-               if mega is not None else {}),
         }
         with self._lock:
             for phase, v in split.items():

@@ -7,16 +7,15 @@ runner-up nodes were, how dense the packing is against a greedy
 counterfactual, and whether any of it is drifting.  Three pieces:
 
   * **In-launch top-k.**  The engines' `quality_topk` static flag
-    (ops/select.select_topk; models/batched.py / speculative.py /
-    megacycle.py) makes every launch ALSO return, per pod, the K best
-    feasible node rows with the WINNER PINNED at column 0, their total
-    scores, and the feasible-candidate count — read off the exact
-    (mask, score, winner) state the placement used, so placements are
-    bit-identical flag-on/off (pinned by tests/test_quality.py, both
-    engines, megacycle, single-chip and sharded).  The scheduler
-    materializes the pytree at the same commit fence as PR 7's
-    attribution, so quality costs one extra D2H copy, never a second
-    sync.
+    (ops/select.select_topk; models/batched.py / speculative.py) makes
+    every launch ALSO return, per pod, the K best feasible node rows
+    with the WINNER PINNED at column 0, their total scores, and the
+    feasible-candidate count — read off the exact (mask, score, winner)
+    state the placement used, so placements are bit-identical
+    flag-on/off (pinned by tests/test_quality.py, both engines,
+    single-chip and sharded).  The scheduler materializes the pytree at
+    the same commit fence as PR 7's attribution, so quality costs one
+    extra D2H copy, never a second sync.
 
   * **Per-decision records.**  `on_cycle` folds each committed cycle
     into margin (top-1 minus runner-up, normalized), feasible-count,
@@ -366,10 +365,10 @@ class QualityObservatory:
         # interval's launch, then dispatch the next — the scheduling
         # thread never waits on the binpack compute).  The cadence
         # counter resets ONLY on an actual dispatch: a due cycle that
-        # cannot sample (degraded, no snapshot — e.g. megacycle windows
-        # k>0 — or an empty batch) leaves the interval due, so the next
-        # eligible cycle samples instead of the cadence silently
-        # starving when the due slot keeps landing on ineligible cycles
+        # cannot sample (degraded, no snapshot, or an empty batch)
+        # leaves the interval due, so the next eligible cycle samples
+        # instead of the cadence silently starving when the due slot
+        # keeps landing on ineligible cycles
         self._cycles_since_regret += 1
         if self._cycles_since_regret >= self.interval_cycles:
             self._materialize_regret()

@@ -461,7 +461,6 @@ def debug_payload(limit: Optional[int] = None) -> dict:
                 "conflicts": getattr(s, "conflicts_total", 0),
                 "race_requeued": getattr(s, "race_requeued_total", 0),
                 "quota_vetoed": getattr(s, "quota_vetoed_total", 0),
-                "megacycles": getattr(s, "megacycles_total", 0),
                 "breaker": s.device_health.state,
                 "engine": getattr(s, "_engine_kind", "?"),
                 "queue_shard": getattr(s, "_replica_id", 0),

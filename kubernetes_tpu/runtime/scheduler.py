@@ -295,21 +295,6 @@ class SchedulerConfig:
     # accounting — is always-on by design (dict ops per cycle; the <2%
     # budget is pinned by perf_smoke alongside the span/telemetry pins)
     profile_dir: Optional[str] = None
-    # --- device-resident megacycle (ISSUE 12: models/megacycle.py) ---
-    # chain up to this many pre-encoded batches through the cluster
-    # state in ONE XLA launch (a lax.scan over the K axis), committing
-    # the K winner vectors asynchronously behind the next megacycle's
-    # dispatch — the host pays one dispatch + one fence per K batches.
-    # 1 = today's single-cycle path bit-for-bit.  The effective K per
-    # launch is the pow2 floor of the eligible batches actually queued
-    # (bounding compiled shapes to the pow2 ladder); AIMD sizes it like
-    # the batch width when adaptive_batch is on.  Only batches whose
-    # cross-batch coupling is resources + lean SelectorSpread ride a
-    # megacycle (no pod-affinity/ports/volumes/gangs/nominated pods, no
-    # extender or framework fan-out) — anything else falls back to
-    # single cycles, placements bit-identical either way (pinned by
-    # tests/test_megacycle.py).
-    megacycle_batches: int = 1
     # --- placement-quality observatory (ISSUE 13: runtime/quality.py) ---
     # in-launch top-k width: every engine launch ALSO returns, per pod,
     # the K best feasible node rows (winner pinned at column 0), their
@@ -449,7 +434,6 @@ class SchedulerConfig:
             ),
             invariant_checks=getattr(cc, "invariant_checks", True),
             profile_dir=getattr(cc, "profile_dir", None),
-            megacycle_batches=getattr(cc, "megacycle_batches", 1),
             quality_top_k=getattr(cc, "quality_top_k", 3),
             quality_interval_cycles=getattr(
                 cc, "quality_interval_cycles", 32
@@ -542,11 +526,6 @@ class _InFlight:
     # codec.transfer.transfer_totals() snapshot at encode time: the
     # commit tail diffs against it to get THIS cycle's wire traffic
     xfer0: Optional[dict] = None
-    # --- device-resident megacycle (ISSUE 12) ---
-    # (k, K) when this cycle is sub-batch k of a K-batch megacycle:
-    # its winners came from one shared launch whose device window is
-    # attributed 1/K to each sub-batch (span, perfobs, telemetry)
-    mega: Optional[Tuple[int, int]] = None
     # --- placement-quality observatory (ISSUE 13) ---
     quality_dev: object = None   # device TopKQuality pytree (quality
     #                              launches only; None when off/degraded)
@@ -563,11 +542,8 @@ class _InFlight:
     # classes_out (-1 = no static key): the state commit derives each
     # class's encoder inputs once (encoder.add_pods)
     classes: Optional[np.ndarray] = None
-    # the snapshot refs the regret counterfactual packs into — set ONLY
-    # when they are genuinely THIS cycle's pre-dispatch state: every
-    # single cycle, but only sub-batch 0 of a megacycle (windows k>0
-    # placed against chained state the shared snapshot predates; FFD
-    # against the emptier pre-megacycle capacity would overstate regret)
+    # the snapshot refs the regret counterfactual packs into: THIS
+    # cycle's pre-dispatch state
     quality_snapshot: Optional[tuple] = None
     # --- device-resident capacity planner (ISSUE 15) ---
     # the cycle's host (allocatable, requested, valid) refs for the
@@ -582,27 +558,15 @@ class _InFlight:
     # commit sequence number stamped by the reconciler (the "sequenced
     # winner" order; rides the ledger block for cross-replica audit)
     commit_seq: int = -1
-    # encoder generation right after THIS cycle's state commit: a
-    # megacycle propagates it to the next window's fence so chained
-    # windows keep the zero-conflict fast path when no sibling
-    # interleaved between sub-batch commits
-    gen_after: int = -1
 
 
 class _HostResult:
     """AsyncFetch-shaped handle for an already-materialized winners
-    buffer (the degraded CPU-engine path, and the per-sub-batch slices
-    of a fetched megacycle): never faults.  execute/materialize carry
-    the reconstructed per-sub-batch share of a megacycle's one device
-    window (0 for genuinely host-computed results)."""
+    buffer (the degraded CPU-engine path): never faults."""
 
-    def __init__(self, hosts: np.ndarray, seconds: float = 0.0,
-                 execute_seconds: float = 0.0,
-                 materialize_seconds: float = 0.0):
+    def __init__(self, hosts: np.ndarray, seconds: float = 0.0):
         self._hosts = hosts
         self.seconds = seconds
-        self.execute_seconds = execute_seconds
-        self.materialize_seconds = materialize_seconds
 
     def ready(self) -> bool:
         return True
@@ -642,27 +606,6 @@ class _Staged:
     # (batch index, pod) vetoed by a namespace quota: parked
     # unschedulable with backoff (spinning on a full quota helps nobody)
     quota_lost: List[Tuple] = field(default_factory=list)
-
-
-@dataclass
-class _MegaFlight:
-    """One dispatched megacycle: K sub-batch _InFlight records sharing
-    ONE launch (stacked i32[K, B] winners, one AsyncFetch, one relaunch
-    closure).  The resilience stack treats it as one retryable unit —
-    a classified fault at the fence relaunches the WHOLE megacycle with
-    the same rotation bases, and giving up on the device serves the K
-    batches sequentially from the CPU adapter, bit-identically
-    (each sub-batch's state commit lands before the next one's adapter
-    call, so the adapter sees exactly the chained state the scan saw)."""
-
-    windows: List[_InFlight]
-    hosts_dev: object
-    fetch: object                # AsyncFetch of the stacked winners
-    relaunch: Optional[Callable] = None
-    t_cycle0: float = 0.0
-    # stacked device TopKQuality ([K, B, ...] leaves) when the quality
-    # seam is on; materialized at the fence and sliced per sub-batch
-    quality_dev: object = None
 
 
 class Scheduler:
@@ -788,9 +731,9 @@ class Scheduler:
         )
         self._unsched_key = enc.interner.intern(TAINT_NODE_UNSCHEDULABLE)
         # placement-quality top-k width (ISSUE 13): a STATIC output-only
-        # engine flag — both engines (and the megacycle driver) return
-        # the winner-pinned top-k + feasible counts alongside the
-        # winners, placements bit-identical flag-on/off
+        # engine flag — both engines return the winner-pinned top-k +
+        # feasible counts alongside the winners, placements
+        # bit-identical flag-on/off
         self._quality_k = max(0, int(self.config.quality_top_k))
         engine_kw = dict(
             cfg=self.config.filter_config,
@@ -894,35 +837,6 @@ class Scheduler:
         self._engine_kind = (
             "sequential" if self._speculative_fn is None else "speculative"
         )
-        # device-resident megacycle (ISSUE 12): the K-batch scan driver
-        # over the SAME engine impl the single-cycle path runs —
-        # megacycle placements are chained-single-cycle placements by
-        # construction.  Attribution cycles stay single (the per-pod
-        # attribution pytree is a single-batch output shape).
-        self._mega_fn = None
-        if self._shared_engines:
-            self._mega_fn = share_engines_with._mega_fn
-        elif self.config.megacycle_batches > 1:
-            if self.config.attribution:
-                klog.infof(
-                    "megacycleBatches=%d ignored: attribution cycles "
-                    "dispatch single batches", self.config.megacycle_batches,
-                )
-            else:
-                from kubernetes_tpu.models.megacycle import (
-                    make_megacycle_scheduler,
-                )
-
-                self._mega_fn = make_megacycle_scheduler(
-                    **engine_kw, engine=self._engine_kind
-                )
-        # effective megacycle depth (AIMD-steered like the batch width
-        # when adaptive_batch is on; static = the configured cap)
-        self._cur_mega = (
-            1 if self.config.adaptive_batch
-            else max(1, self.config.megacycle_batches)
-        )
-        self.megacycles_total = 0
         self.framework = framework
         # scheduler-side extender chain (core/extender.go; chained in config
         # order at generic_scheduler.go:527-554); built from the Policy's
@@ -1353,8 +1267,6 @@ class Scheduler:
                 if self.invariants is not None else None
             ),
             "adaptive_batch": self._cur_batch,
-            "megacycle_depth": self._cur_mega,
-            "megacycles_total": self.megacycles_total,
             "pipeline_pending": self.pipeline_pending,
             "scheduling_cycle": self.queue.scheduling_cycle,
             # latency tier of the most recently dispatched cycle — pairs
@@ -1658,7 +1570,6 @@ class Scheduler:
             return
         floor = max(1, cfg.batch_size_min)
         cur = self._cur_batch
-        mega = self._cur_mega
         if cfg.cycle_deadline_s > 0 and cycle_s > cfg.cycle_deadline_s:
             m.CYCLE_DEADLINE_EXCEEDED.inc()
             self._postmortem(
@@ -1667,33 +1578,18 @@ class Scheduler:
                 f"budget (batch {cur})",
             )
             cur = max(floor, cur // 2)
-            # latency overruns shed megacycle depth first too: the K-deep
-            # launch is the coarsest-grained unit of committed work
-            mega = max(1, mega // 2)
         else:
             depth = self.queue.active_depth()
             if depth > cur:
                 cur = min(cfg.batch_size, cur + floor)
             elif depth <= cur // 2:
                 cur = max(floor, cur // 2)
-            # megacycle depth grows only once the width is saturated
-            # (pressure converts into wider launches before deeper ones)
-            # and decays with the backlog, in pow2 steps so every served
-            # K is a prewarm-able ladder shape
-            if cfg.megacycle_batches > 1:
-                if cur >= cfg.batch_size and depth > cur * mega:
-                    mega = min(cfg.megacycle_batches, mega * 2)
-                elif depth <= cur * mega // 2:
-                    mega = max(1, mega // 2)
         if cur != self._cur_batch:
             self._annotate(
                 "aimd_resize", f"batch {self._cur_batch} -> {cur}",
                 batch=cur,
             )
         self._cur_batch = cur
-        if cfg.megacycle_batches > 1:
-            self._cur_mega = mega
-            m.MEGACYCLE_DEPTH.set(float(mega))
         m.ADAPTIVE_BATCH.set(float(cur))
 
     def _note_device_fault(self, fault_class: str, err: BaseException,
@@ -2187,523 +2083,6 @@ class Scheduler:
                     raise
                 return None
 
-    # ------------------------------------------- device-resident megacycle
-    #
-    # ISSUE 12: chain K pre-encoded batches through the donated cluster
-    # state in ONE launch (models/megacycle.py), commit the K winner
-    # vectors asynchronously behind the next megacycle's dispatch.  The
-    # eligibility gates below admit exactly the batches whose cross-batch
-    # coupling the on-device carry (resources + lean SelectorSpread)
-    # reproduces bit-identically — everything else rides single cycles.
-
-    def _megacycle_ready(self) -> bool:
-        """Scheduler-level gate: can THIS control-plane state form a
-        megacycle at all?  Cheap (attribute reads) — checked once per
-        run_once before any extra pop."""
-        cfg = self.config
-        if self._mega_fn is None or cfg.megacycle_batches <= 1:
-            return False
-        if self.framework is not None or not cfg.batched_commit:
-            return False
-        if any(
-            e.config.filter_verb or e.config.prioritize_verb
-            for e in self.extenders
-        ):
-            return False  # the fan-out is per-single-batch host work
-        if self.queue.nominated_pods():
-            return False  # two-pass nominated state is host-recomputed
-        if cfg.cpu_fallback and not self.device_health.device_available:
-            return False  # breaker open: single degraded cycles
-        enc = self.cache.encoder
-        if enc.term_groups:
-            return False  # live affinity terms: commits move topo state
-        if cfg.filter_config.service_affinity_labels:
-            return False  # CheckServiceAffinity reads existing-pod state
-        return True
-
-    def _megacycle_safe(self, pods: Sequence[Pod]) -> bool:
-        """Pod-level gate for one window: every pod's only cross-batch
-        effect must be resources + at-most-one spread group (the
-        encoder's lean shape, whose counts the device carry chains
-        exactly).  Mirrors encode_pods' own group-membership rule."""
-        enc = self.cache.encoder
-        spread = enc._spread
-        memo: Dict[tuple, int] = {}
-        for p in pods:
-            if self.POD_GROUP_LABEL in p.labels:
-                return False
-            a = p.spec.affinity
-            if a is not None and (
-                a.pod_affinity is not None
-                or a.pod_anti_affinity is not None
-            ):
-                return False
-            if p.spec.volumes or p.host_ports():
-                return False
-            if spread:
-                sig = (p.namespace, tuple(sorted(p.labels.items())))
-                n = memo.get(sig)
-                if n is None:
-                    n = sum(
-                        1 for ns, sel in spread
-                        if ns == p.namespace and sel.matches(p.labels)
-                    )
-                    memo[sig] = n
-                if n > 1:
-                    return False
-        return True
-
-    def _pop_megacycle_windows(self, first: Sequence[Pod], width: int):
-        """Pop up to K-1 more batch windows behind the already-popped
-        `first` (queue depth permitting, never blocking), keeping only
-        megacycle-safe ones; the kept count is floored to a power of two
-        so every launched K is a prewarm-able ladder shape.  Returns
-        (windows, cycles, leftovers) — leftover windows (the pow2
-        remainder, or the first unsafe window) are readded to the queue
-        (shed-exempt, like every requeue of a popped pod) and re-pop on
-        the next iteration."""
-        windows: List[List[Pod]] = [list(first)]
-        cycles = [self.queue.scheduling_cycle]
-        leftovers: List[List[Pod]] = []
-        k_target = min(
-            self._cur_mega if self.config.adaptive_batch
-            else self.config.megacycle_batches,
-            self.config.megacycle_batches,
-        )
-        mega_pop_kw = (
-            {"shard": self._replica_id, "of": self._replica_of}
-            if self._replica_of > 1 else {}
-        )
-        with self._phase_span(None, "pop"):
-            while len(windows) < k_target:
-                w = self.queue.pop_batch(width, 0.0, 0.0, **mega_pop_kw)
-                if not w:
-                    break
-                if self.invariants is not None:
-                    self.invariants.note_popped(
-                        w, self.queue.scheduling_cycle
-                    )
-                if self._megacycle_safe(w):
-                    windows.append(w)
-                    cycles.append(self.queue.scheduling_cycle)
-                else:
-                    leftovers.append(w)
-                    break
-        k_eff = 1 << (len(windows).bit_length() - 1)  # pow2 floor
-        leftovers = windows[k_eff:] + leftovers
-        windows = windows[:k_eff]
-        for w in leftovers:
-            for p in w:
-                self.queue.readd(p)
-        return windows, cycles[:k_eff]
-
-    def _dispatch_megacycle(self, windows: List[List[Pod]],
-                            cycles: List[int]) -> _MegaFlight:
-        """Encode the K windows against ONE snapshot, stack them, and
-        launch the megacycle scan.  Returns with the device computing
-        all K sub-batches; the stacked winners fetch is in flight."""
-        from kubernetes_tpu.codec.transfer import transfer_totals
-        from kubernetes_tpu.models.megacycle import stack_windows
-
-        K = len(windows)
-        with self._phase_span(None, "encode", windows=K) as encode:
-            t_cycle0 = encode.t0
-            xfer0 = transfer_totals()
-            enc = self.cache.encoder
-            spans = [
-                Span(
-                    "schedule_cycle", start=t_cycle0, pods=len(w),
-                    cycle=cycles[k], tier=TIER_BULK, mega=f"{k + 1}/{K}",
-                )
-                for k, w in enumerate(windows)
-            ]
-            self._cur_span = encode.parent = spans[0]
-            self._cur_tier = TIER_BULK
-            use_device = (
-                self.device_health.allow_device()
-                if self.config.cpu_fallback
-                else True
-            )
-            with self.cache._lock:
-                classes = [np.full(len(w), -1, np.int32) for w in windows]
-                batches = [
-                    enc.encode_pods(w, c) for w, c in zip(windows, classes)
-                ]
-                shapes = {
-                    tuple(
-                        np.asarray(leaf).shape
-                        for leaf in jax.tree_util.tree_leaves(b)
-                    )
-                    for b in batches
-                }
-                if len(shapes) > 1:
-                    # a later window grew a sticky pad dim: one more pass
-                    # encodes every window at the (now stable) max shapes
-                    batches = [
-                        enc.encode_pods(w, c)
-                        for w, c in zip(windows, classes)
-                    ]
-                ports = [encode_batch_ports(enc, w) for w in windows]
-                cluster, generation = self.cache.snapshot()
-                # hub mode: the hub is the dirty stream's single consumer
-                # (see _encode_and_dispatch)
-                dirty_rows = (
-                    enc.take_dirty_rows()
-                    if use_device and self._hub is None else None
-                )
-                node_row_map = dict(enc.node_rows)
-            # per-sub-batch rotation bases: base + cumulative RAW pod
-            # counts, exactly what K separate cycles would have seen
-            li0: List[int] = []
-            acc = self._last_index
-            for w in windows:
-                li0.append(acc)
-                acc += len(w)
-            self._last_index = acc
-            li0_arr = np.asarray(li0, np.int32)
-            batch_k = stack_windows(batches)
-            ports_k = stack_windows(ports)
-        mega_fn = self._mega_fn
-        launch_box = {"cluster": cluster, "generation": generation,
-                      "ledger": None}
-
-        def launch():
-            device_faults.check(
-                device_faults.SITE_DISPATCH, devices=self._mesh_ids
-            )
-            if self._hub is not None:
-                c2, g2, dev_cluster = self._hub.refresh()
-                launch_box["cluster"], launch_box["generation"] = c2, g2
-                if launch_box["ledger"] is not None:
-                    launch_box["ledger"]["cluster"] = c2
-            else:
-                dev_cluster = self._dev_snapshot.update(
-                    cluster, dirty_rows=dirty_rows
-                )
-            out = mega_fn(dev_cluster, batch_k, ports_k, li0_arr)
-            hosts = out[0]
-            qual = out[2] if self._quality_k else None
-            if qual is not None:
-                for leaf in qual:
-                    if hasattr(leaf, "copy_to_host_async"):
-                        leaf.copy_to_host_async()
-            return hosts, AsyncFetch(hosts), qual
-
-        with self._phase_span(spans[0], "dispatch", windows=K) as dispatch:
-            launched = (
-                self._launch_resilient(launch) if use_device else None
-            )
-            self._fold_program_loads(spans[0])
-        t_disp_end = dispatch.t1
-        degraded_dispatch = launched is None
-        hosts_dev = fetch = quality_dev = None
-        if not degraded_dispatch:
-            hosts_dev, fetch, quality_dev = launched
-        else:
-            m.DEGRADED_CYCLES.inc(K)
-            self._postmortem(
-                "degraded_cycle",
-                "breaker open at megacycle dispatch" if not use_device
-                else "megacycle dispatch gave up on the device",
-            )
-        infs: List[_InFlight] = []
-        for k, w in enumerate(windows):
-            spans[k].annotate(
-                batch=len(w),
-                dirty_rows=(
-                    len(dirty_rows) if k == 0 and dirty_rows is not None
-                    else -1
-                ),
-                breaker=self.device_health.state,
-                degraded=degraded_dispatch,
-                engine="cpu" if degraded_dispatch else self._engine_kind,
-                shards=self.mesh.size if self.mesh is not None else 0,
-            )
-
-            def cpu_fetch(pods=w, base=li0[k], rows=node_row_map):
-                t0 = time.monotonic()
-                if self._hub is not None:
-                    # degraded replica window: serialize the live-cache
-                    # read against sibling commits (see the single-cycle
-                    # cpu_fetch)
-                    with self.cache._lock:
-                        hosts = self.cpu_engine.schedule_batch(
-                            pods, base,
-                            extra_mask=None, extra_score=None,
-                            nominated=[], masked=frozenset(), row_map=rows,
-                        )
-                else:
-                    hosts = self.cpu_engine.schedule_batch(
-                        pods, base,
-                        extra_mask=None, extra_score=None,
-                        nominated=[], masked=frozenset(), row_map=rows,
-                    )
-                return _HostResult(hosts, seconds=time.monotonic() - t0)
-
-            inf = _InFlight(
-                pods=list(w), hosts_dev=None, fetch=None,
-                generation=launch_box["generation"], cycle=cycles[k],
-                ext_failed={},
-                pc=None, t_cycle0=t_cycle0, trace=spans[k],
-                relaunch=None, cpu_fetch=cpu_fetch,
-                degraded=degraded_dispatch, last_index0=li0[k],
-                tier=TIER_BULK, classes=classes[k],
-                quality_reqs=(
-                    batches[k].req if self.quality is not None else None
-                ),
-                # only window 0's placements saw exactly this snapshot;
-                # later windows placed against chained state, so their
-                # cycles skip the FFD counterfactual (margins/feasible
-                # still record — only the regret cadence passes them by)
-                quality_snapshot=(
-                    (cluster.allocatable, cluster.requested, cluster.valid)
-                    if (self.quality is not None and k == 0) else None
-                ),
-                telemetry_host=(
-                    (cluster.allocatable, cluster.requested, cluster.valid)
-                    if self.telemetry is not None else None
-                ),
-                # window 0's refs suffice for the capacity planner: its
-                # interval cadence samples at most one window per
-                # megacycle anyway, and the backlog solve wants the
-                # pre-megacycle fleet state
-                capacity_snapshot=(
-                    (cluster.allocatable, cluster.requested, cluster.valid)
-                    if (self.capacity is not None and k == 0) else None
-                ),
-                width=batches[k].n_pods,
-                enqueue_s=(t_disp_end - t_cycle0) / K,
-                xfer0=xfer0 if k == 0 else None,
-                mega=(k, K),
-                reqs=(
-                    batches[k].req if self._reconciler is not None
-                    else None
-                ),
-            )
-            if self._replica_of > 1:
-                spans[k].annotate(replica=self._replica_id)
-            if self.ledger is not None:
-                # sub-batch k > 0 replays against the host snapshot taken
-                # AFTER sub-batch k-1's state commit (patched in at the
-                # commit loop) — the host-side twin of the device chain,
-                # so every block replays through the single-batch engine
-                inf.ledger_inputs = dict(
-                    cluster=launch_box["cluster"] if k == 0 else None,
-                    batch=batches[k], ports=ports[k],
-                    nominated=None, aff_state=None,
-                    extra_mask=None, extra_score=None,
-                    last_index0=li0[k],
-                )
-                if k == 0:
-                    launch_box["ledger"] = inf.ledger_inputs
-            infs.append(inf)
-        self.megacycles_total += 1
-        m.MEGACYCLES.inc()
-        m.MEGACYCLE_DEPTH.set(float(K))
-        return _MegaFlight(
-            windows=infs, hosts_dev=hosts_dev, fetch=fetch,
-            relaunch=None if degraded_dispatch else launch,
-            t_cycle0=t_cycle0, quality_dev=quality_dev,
-        )
-
-    def _commit_state_mega(self, mf: _MegaFlight,
-                           staged: List[_Staged]) -> List[_Staged]:
-        """The megacycle's resilient fence + per-sub-batch state
-        commits.  One retryable unit: a classified fault relaunches the
-        WHOLE megacycle (same encoded batches, same rotation bases);
-        giving up on the device replays the K batches sequentially
-        through the CPU adapter — each sub-batch's state commit lands
-        before the next adapter call, so the adapter sees exactly the
-        chained state the device scan would have.
-
-        `staged` is the CALLER's list, appended sub-batch by sub-batch
-        as each state commit lands: on an error escaping mid-loop the
-        caller (_commit_state_mega_or_requeue) still sees exactly which
-        windows committed — their winners sit assumed and their tails
-        must run; everything after them requeues."""
-        K = len(mf.windows)
-        attempt = 0
-        relaunch_pending = False
-        hosts_all = None
-        qual_all = None
-        with self._phase_span(
-            mf.windows[0].trace, "host_stall", child="fence"
-        ) as fence:
-            while mf.fetch is not None:
-                try:
-                    if relaunch_pending:
-                        # relaunch at the TOP of the try (the single-cycle
-                        # loop's relaunch_pending discipline): a classified
-                        # fault raised by the re-dispatch itself must feed
-                        # the same retry/degrade policy, not escape it
-                        mf.hosts_dev, mf.fetch, mf.quality_dev = mf.relaunch()
-                        relaunch_pending = False
-                    hosts_all = np.asarray(mf.fetch.result())
-                    for k, inf in enumerate(mf.windows):
-                        self._validate_hosts(hosts_all[k], len(inf.pods))
-                    if mf.quality_dev is not None:
-                        # the stacked top-k rides the same fence discipline
-                        # as the winners: by now the launch has computed, so
-                        # this is the pre-enqueued copy landing; a fault here
-                        # retries/degrades the whole megacycle
-                        qual_all = type(mf.quality_dev)(
-                            *(np.asarray(x) for x in mf.quality_dev)
-                        )
-                    break
-                except BaseException as e:
-                    fc = classify_device_error(e)
-                    if fc is None:
-                        raise
-                    shard = self._shard_of(e)
-                    self._note_device_fault(
-                        fc, e,
-                        "megacycle-dispatch" if relaunch_pending
-                        else "megacycle-fence",
-                    )
-                    mf.windows[0].trace.annotate(
-                        fault_class=fc, fault_attempts=attempt + 1
-                    )
-                    if shard is not None:
-                        mf.windows[0].trace.annotate(fault_shard=shard)
-                    if self._fault_retry_allowed(
-                        fc, attempt,
-                        can_relaunch=mf.relaunch is not None, shard=shard,
-                    ):
-                        attempt += 1
-                        relaunch_pending = True
-                        continue
-                    if not self.config.cpu_fallback:
-                        raise
-                    hosts_all = None
-                    break
-        stall = fence.seconds
-        self._fence_split(mf.fetch, fence, TIER_BULK)
-        if hosts_all is None:
-            # degraded megacycle: K sequential CPU-adapter sub-batches
-            if mf.fetch is not None:
-                m.DEGRADED_CYCLES.inc(K)
-                self._postmortem(
-                    "degraded_cycle", "megacycle fence gave up on the device"
-                )
-            for inf in mf.windows:
-                self._stage_mega_window(inf, None)
-                inf.fetch = inf.cpu_fetch()
-                inf.degraded = True
-                inf.trace.annotate(degraded=True, engine="cpu")
-                staged.append(self._commit_state(inf))
-            return staged
-        # device success: heal streaks, slice the one fetched window
-        # into per-sub-batch handles carrying 1/K of the device timings
-        self.device_health.record_success()
-        if self.shard_health is not None and self._mesh_ids:
-            self.shard_health.heal(self._mesh_ids)
-        f = mf.fetch
-        prev_gen = -1
-        for k, inf in enumerate(mf.windows):
-            self._stage_mega_window(inf, None)
-            if k > 0 and prev_gen >= 0:
-                # chained fence (ISSUE 14): window k placed against the
-                # state window k-1's commit produced — if no sibling
-                # replica interleaved since, the zero-conflict fast path
-                # still applies (commits only ever make real usage <=
-                # what the on-device chain assumed, so verdicts hold)
-                inf.generation = prev_gen
-            if qual_all is not None:
-                # slice sub-batch k's already-host quality rows; the
-                # fence's materialize in _commit_state is then a no-op
-                inf.quality = type(qual_all)(
-                    *(np.asarray(x)[k] for x in qual_all)
-                )
-            inf.fetch = _HostResult(
-                hosts_all[k],
-                seconds=f.seconds / K,
-                execute_seconds=getattr(f, "execute_seconds", 0.0) / K,
-                materialize_seconds=(
-                    getattr(f, "materialize_seconds", 0.0) / K
-                ),
-            )
-            st = self._commit_state(inf)
-            prev_gen = inf.gen_after
-            if k == 0:
-                st.stall_s += stall
-            staged.append(st)
-        return staged
-
-    def _stage_mega_window(self, inf: _InFlight, _unused) -> None:
-        """Pre-commit hook for one megacycle sub-batch: patch the ledger
-        record's snapshot to the CURRENT host truth (sub-batches after
-        the first replay against the state their predecessors committed
-        — the host-side twin of the on-device chain)."""
-        if (
-            self.ledger is not None
-            and inf.ledger_inputs is not None
-            and inf.ledger_inputs.get("cluster") is None
-        ):
-            with self.cache._lock:
-                inf.ledger_inputs["cluster"] = self.cache.snapshot()[0]
-
-    def _commit_state_mega_or_requeue(
-        self, mf: _MegaFlight
-    ) -> List[_Staged]:
-        """The megacycle batch-loss guard (the _commit_state_or_requeue
-        analog): on an error that escaped the classified machinery, the
-        pods of every sub-batch whose state was NOT yet committed are
-        requeued (the shared `staged` list tracks exactly which windows
-        landed before the error), the tails of already-committed
-        sub-batches still run (their winners sit assumed and must bind
-        or roll back), every un-staged window's span retires into the
-        flight recorder with the error, and the error propagates."""
-        staged: List[_Staged] = []
-        try:
-            return self._commit_state_mega(mf, staged)
-        except BaseException as e:
-            done = {id(st.inf) for st in staged}
-            err = f"{type(e).__name__}: {e}"
-            for inf in mf.windows:
-                if id(inf) not in done:
-                    self.queue.add_unschedulable_batch(inf.pods, inf.cycle)
-                    # staged windows' spans retire via their tails below;
-                    # the failed ones must still reach /debug/traces
-                    inf.trace.annotate(error=err)
-                    inf.trace.finish()
-                    self.flight_recorder.record(inf.trace)
-            if classify_device_error(e) is None:
-                self._postmortem("unclassified_error", err)
-            for st in staged:
-                self._commit_tail(st)
-            raise
-
-    def _commit_state_prev(self, prev) -> List[_Staged]:
-        """Normalize the in-flight slot's state-commit: a megacycle
-        yields K staged sub-batches, a plain cycle one."""
-        if isinstance(prev, _MegaFlight):
-            return self._commit_state_mega_or_requeue(prev)
-        return [self._commit_state_or_requeue(prev)]
-
-    def schedule_megacycle(
-        self, windows: List[List[Pod]], cycles: Optional[List[int]] = None,
-    ) -> List[ScheduleResult]:
-        """Place K batch windows through one megacycle launch,
-        synchronously (the schedule_cycle analog; the pipelined run
-        loop uses the in-flight slot instead).  Caller guarantees
-        _megacycle_ready() and per-window _megacycle_safe()."""
-        self.flush_pipeline()
-        if cycles is None:
-            cycles = [self.queue.scheduling_cycle] * len(windows)
-        try:
-            self._maybe_probe_shards()
-            mf = self._dispatch_megacycle(windows, cycles)
-        except BaseException:
-            for w in windows:
-                self.queue.add_unschedulable_batch(
-                    list(w), self.queue.scheduling_cycle
-                )
-            raise
-        results: List[ScheduleResult] = []
-        for st in self._commit_state_mega_or_requeue(mf):
-            results.extend(self._commit_tail(st))
-        return results
-
     def _validate_hosts(self, hosts, n_pods: int) -> np.ndarray:
         """Structural validation of a fetched winners buffer: a corrupted
         D2H transfer must surface as a CLASSIFIED fault (retried like a
@@ -2836,7 +2215,6 @@ class Scheduler:
                     staged.race_lost = race_lost
                     staged.quota_lost = quota_lost
                     self._assume_winners(inf, winners, hosts, commit)
-                    inf.gen_after = enc.generation
                     if self.invariants is not None and winners:
                         rows = sorted(
                             {int(hosts[i]) for i, _, _, _ in winners}
@@ -2983,13 +2361,6 @@ class Scheduler:
             fetch = inf.fetch
             commit_s = staged.state_seconds + time.monotonic() - t_tail0
             wall_s = time.monotonic() - inf.t_cycle0
-            if inf.mega is not None:
-                # one launch served K sub-batches: attribute 1/K of the
-                # shared wall to each (floored at its own host split so
-                # every sample stays self-consistent); the device pair
-                # was already sliced 1/K onto the fetch handle
-                host_s = inf.enqueue_s + staged.stall_s + commit_s
-                wall_s = max(wall_s / inf.mega[1], host_s)
             self.perfobs.on_cycle(
                 width=inf.width or len(inf.pods),
                 tier=inf.tier,
@@ -3002,7 +2373,6 @@ class Scheduler:
                 wall_s=wall_s,
                 transfers=xfer_delta,
                 trace_id=inf.trace.trace_id,
-                mega=inf.mega,
             )
         except Exception as e:  # noqa: BLE001 — observability must
             # never fail a cycle whose placements are already committed
@@ -3233,10 +2603,6 @@ class Scheduler:
             "pods": [[p.namespace, p.name] for p in pods],
             "winners": np.asarray(staged.hosts[: len(pods)], np.int32),
             "time": time.time(),
-            # sub-batch k of a K-deep megacycle launch: the record is one
-            # of K replayable blocks (each against the host snapshot its
-            # predecessors' commits produced)
-            **({"mega": list(inf.mega)} if inf.mega is not None else {}),
             # queue-sharded replicas (ISSUE 14): which replica dispatched
             # this cycle, and its reconciler commit sequence number —
             # cross-replica replay stays deterministic because every
@@ -4232,53 +3598,6 @@ class Scheduler:
             klog.V(1).infof(
                 "prewarm: width %d compiled in %.2fs", w, timings[w]
             )
-        # megacycle shapes (ISSUE 12 satellite): the K x pow2-width
-        # ladder, capped by megacycleBatches, so the first megacycle
-        # after cold start is a cache hit instead of a fresh compile.
-        # Keys are "megaKxW" strings (the plain-width keys stay ints).
-        if self._mega_fn is not None and self.config.megacycle_batches > 1:
-            from kubernetes_tpu.models.megacycle import stack_windows
-
-            k_ladder = []
-            k = 2
-            while k <= self.config.megacycle_batches:
-                k_ladder.append(k)
-                k *= 2
-            for K in k_ladder:
-                for w in widths:
-                    t0 = time.monotonic()
-                    wins = [
-                        [pod_factory(i + j * w) for i in range(w)]
-                        for j in range(K)
-                    ]
-                    with self.cache._lock, enc.batch_width(w):
-                        batches = [enc.encode_pods(ws) for ws in wins]
-                        ports_l = [
-                            encode_batch_ports(enc, ws) for ws in wins
-                        ]
-                        cluster, _ = self.cache.snapshot()
-                        dirty_rows = (
-                            enc.take_dirty_rows()
-                            if self._hub is None else None
-                        )
-                    dev_cluster = self._device_update(
-                        cluster, dirty_rows
-                    )
-                    li0 = np.arange(K, dtype=np.int32) * w + np.int32(
-                        self._last_index
-                    )
-                    # index instead of unpack: the quality variant
-                    # returns a third output this warm launch discards
-                    hosts = self._mega_fn(
-                        dev_cluster, stack_windows(batches),
-                        stack_windows(ports_l), li0,
-                    )[0]
-                    jax.block_until_ready(hosts)
-                    timings[f"mega{K}x{w}"] = time.monotonic() - t0
-                    klog.V(1).infof(
-                        "prewarm: megacycle %dx%d compiled in %.2fs",
-                        K, w, timings[f"mega{K}x{w}"],
-                    )
         return timings
 
     @property
@@ -4289,63 +3608,42 @@ class Scheduler:
 
     def flush_pipeline(self) -> int:
         """Drain the double-buffer slot: fetch + commit any in-flight
-        pipelined batch (or megacycle).  No-op when nothing is in
-        flight.  Returns the number of pods placed from the drain."""
+        pipelined batch.  No-op when nothing is in flight.  Returns the
+        number of pods placed from the drain."""
         inf, self._in_flight = self._in_flight, None
         if inf is None:
             return 0
-        n = 0
-        for st in self._commit_state_prev(inf):
-            results = self._commit_tail(st)
-            n += sum(1 for r in results if r.node is not None)
-        return n
+        results = self._commit_tail(self._commit_state_or_requeue(inf))
+        return sum(1 for r in results if r.node is not None)
 
-    def _run_pipelined(self, pods: Sequence[Pod],
-                       mega: Optional[Tuple[List[List[Pod]], List[int]]]
-                       = None) -> int:
+    def _run_pipelined(self, pods: Sequence[Pod]) -> int:
         """Double-buffered cycle: apply the in-flight batch's STATE half
         (fetch + batched assume — the part the next snapshot must see),
         dispatch the new batch, then run the previous batch's side-effect
         tail while the device computes.  Device idle time shrinks to the
         fetch->dispatch gap (assume + encode), and the per-pod Python tail
-        (binds, events, metrics, preemption) hides behind device compute.
-
-        With `mega` = (windows, cycles), the new dispatch is a megacycle
-        (ISSUE 12) and the in-flight slot may hold one: all K in-flight
-        state commits land before the new launch encodes, and all K host
-        tails overlap the new device window — host_commit fully behind
-        device_execute."""
+        (binds, events, metrics, preemption) hides behind device compute."""
         prev, self._in_flight = self._in_flight, None
-        n = 0
-        staged: List[_Staged] = []
+        staged: Optional[_Staged] = None
         dispatched = False
         try:
             if prev is not None:
-                staged = self._commit_state_prev(prev)
-            if mega is not None:
-                self._in_flight = self._dispatch_megacycle(*mega)
-            else:
-                self._in_flight = self._encode_and_dispatch(pods)
+                staged = self._commit_state_or_requeue(prev)
+            self._in_flight = self._encode_and_dispatch(pods)
             dispatched = True
         finally:
             if not dispatched:
                 # batch k+1 was popped but never reached the device
                 # (batch k's ready-fence raised, or the dispatch itself
                 # did): requeue it — popped pods must never be lost
-                lost = (
-                    [p for w in mega[0] for p in w]
-                    if mega is not None else list(pods)
-                )
                 self.queue.add_unschedulable_batch(
-                    lost, self.queue.scheduling_cycle
+                    list(pods), self.queue.scheduling_cycle
                 )
             # batch k's tail MUST run even if batch k+1's dispatch raises:
             # its losers were already popped from the queue (the requeue
             # happens in the tail) and its winners sit assumed-but-unbound
-            for st in staged:
-                results = self._commit_tail(st)
-                n += sum(1 for r in results if r.node is not None)
-        return n
+            results = [] if staged is None else self._commit_tail(staged)
+        return sum(1 for r in results if r.node is not None)
 
     def run_once(self, timeout: float = 0.1) -> int:
         """Pop one cycle's batch and schedule it; returns the number of
@@ -4569,34 +3867,7 @@ class Scheduler:
                         p, node, time.monotonic() - t_cycle
                     )
         if plain:
-            # megacycle formation (ISSUE 12): when the control plane and
-            # this window are chain-safe, pop up to K-1 more windows and
-            # launch them as ONE device scan; the commit of the K winner
-            # vectors runs behind the NEXT megacycle's dispatch (the
-            # pipelined slot).  Any ineligible window falls back to the
-            # single-cycle path below, placements identical either way.
-            windows = None
-            if self._megacycle_ready() and self._megacycle_safe(plain):
-                windows, win_cycles = self._pop_megacycle_windows(
-                    plain,
-                    self._cur_batch if self.config.adaptive_batch
-                    else self.config.batch_size,
-                )
-            if windows is not None and len(windows) > 1:
-                if (
-                    self.config.pipeline_commit
-                    and self.framework is None
-                ):
-                    n += self._run_pipelined(
-                        plain, mega=(windows, win_cycles)
-                    )
-                else:
-                    n += sum(
-                        1
-                        for r in self.schedule_megacycle(windows, win_cycles)
-                        if r.node is not None
-                    )
-            elif (
+            if (
                 self.config.pipeline_commit
                 and self.config.batched_commit
                 and self.framework is None

@@ -635,25 +635,6 @@ ENCODE_PAIR_LEAVES = REGISTRY.register(
     )
 )
 
-# device-resident megacycle (ISSUE 12): K pre-encoded batches chained
-# through the cluster state in one XLA launch (models/megacycle.py)
-MEGACYCLES = REGISTRY.register(
-    Counter(
-        "scheduler_megacycles_total",
-        "Megacycle launches dispatched (each chains K>=2 batches "
-        "through the donated cluster state in one XLA launch; single-"
-        "cycle dispatches are not counted here)",
-    )
-)
-MEGACYCLE_DEPTH = REGISTRY.register(
-    Gauge(
-        "scheduler_megacycle_batches",
-        "Effective megacycle depth K (batches chained per launch): the "
-        "AIMD-steered current value under adaptiveBatch, else the last "
-        "launched depth; 1 = single-cycle dispatch",
-    )
-)
-
 # decision ledger + attribution (ISSUE 7): unschedulable verdicts by the
 # dominant failing plugin (fed from the engine's attribution launch), and
 # the ledger's own accounting — cycles accepted, bytes appended, records
@@ -858,8 +839,7 @@ PERF_PHASE_EWMA = REGISTRY.register(
         "scheduler_perf_phase_ewma_seconds",
         "EWMA seconds per cycle phase (host_enqueue|device_execute|"
         "d2h_materialize|host_stall|host_commit) and executable batch "
-        "width — the per-cycle cost model the device-resident megacycle "
-        "work (ROADMAP item 2) reads from; served at /debug/perf",
+        "width — the per-cycle cost model; served at /debug/perf",
         ("phase", "width"),
         # 5 phases x the AIMD pow2 ladder (+ express width); the guard
         # fires only if width labels start leaking non-pow2 values

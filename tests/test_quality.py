@@ -1,9 +1,9 @@
 """Placement-quality observatory (ISSUE 13).
 
 Pins the tentpole contracts: the engines' `quality_topk` static flag is
-OUTPUT-ONLY — placements bit-identical flag-on/off for both engines,
-the megacycle driver, and the live Scheduler (single-chip and the
-8-virtual-device sharded mesh) with winner == top-1 everywhere; the
+OUTPUT-ONLY — placements bit-identical flag-on/off for both engines
+and the live Scheduler (single-chip and the 8-virtual-device sharded
+mesh) with winner == top-1 everywhere; the
 observatory's margin/feasible/regret records off a live run; the
 dual-window drift detector's fire-once/re-arm hysteresis and its
 postmortem seam; the FFD counterfactual's per-bin-capacity binpack; and
@@ -261,38 +261,6 @@ def test_speculative_quality_identity_under_hybrid_redo(packed):
     assert (np.asarray(q.top_nodes)[:n][~placed] == -1).all()
 
 
-@pytest.mark.megacycle
-@pytest.mark.parametrize("engine", ["sequential", "speculative"])
-def test_megacycle_quality_identity(engine):
-    from kubernetes_tpu.models.megacycle import (
-        make_megacycle_scheduler,
-        stack_windows,
-    )
-
-    enc = SnapshotEncoder()
-    enc.add_nodes(_skewed_nodes())
-    w1 = _pods(8, prefix=f"mg{engine}a-")
-    w2 = _pods(8, prefix=f"mg{engine}b-")
-    b1, p1 = _encode(enc, w1)
-    b2, p2 = _encode(enc, w2)
-    cluster = enc.snapshot()
-    kw = _engine_kw(enc)
-    bk = stack_windows([b1, b2])
-    pk = stack_windows([p1, p2])
-    li = np.asarray([0, len(w1)], np.int32)
-    plain = make_megacycle_scheduler(**kw, engine=engine)
-    qual = make_megacycle_scheduler(**kw, engine=engine, quality_topk=3)
-    h0 = np.asarray(plain(cluster, bk, pk, li)[0])
-    out = qual(cluster, bk, pk, li)
-    hq, q = np.asarray(out[0]), out[2]
-    assert np.array_equal(h0, hq)
-    tn = np.asarray(q.top_nodes)
-    assert tn.shape[0] == 2 and tn.shape[2] == 3
-    for k in range(2):
-        placed = hq[k] >= 0
-        assert np.array_equal(tn[k][placed, 0], hq[k][placed])
-
-
 # --------------------------------------------------- live scheduler pins
 
 
@@ -365,30 +333,6 @@ def test_sharded_live_quality_identity():
             assert s.quality is not None
             assert s.quality.decisions_total >= 32
     assert maps["single_q"] == maps["mesh_q"] == maps["mesh_plain"]
-
-
-@pytest.mark.megacycle
-def test_live_megacycle_quality_records():
-    """Megacycle-formed cycles feed the observatory per sub-batch: the
-    K-deep launch's stacked top-k slices into per-cycle records, and
-    placements match the quality-off megacycle run."""
-    runs = {}
-    for k in (0, 3):
-        s = _live(k, megacycle_batches=4)
-        # chain-safe pods only (no node_selector variance needed)
-        for i in range(64):
-            s.queue.add(make_pod(f"mg{k}-{i}", cpu="100m", mem="64Mi",
-                                 labels={"app": f"d{i % 3}"}))
-        _drain(s)
-        runs[k] = {
-            pn[1].replace(f"mg{k}-", ""): node
-            for pn, node in _placements(s).items()
-        }
-        if k:
-            assert s.megacycles_total > 0, "no megacycle formed"
-            assert s.quality.decisions_total >= 64
-            assert s.quality.margin_count > 0
-    assert runs[0] == runs[3]
 
 
 def test_quality_records_margin_feasible_regret():

@@ -557,23 +557,3 @@ def test_threaded_replicas_drain_and_config_plumbing():
         SchedulerReplicaSet(
             replicas=2, config=_config(batched_commit=False)
         )
-
-
-def test_replicas_with_megacycles():
-    """Replicas dispatch megacycles against the shared snapshot: the
-    chained-window fence keeps sub-batches on the fast path when no
-    sibling interleaves, and conservation holds either way."""
-    rs = SchedulerReplicaSet(
-        replicas=2,
-        cache=SchedulerCache(SnapshotEncoder(TEST_DIMS)),
-        config=_config(batch_size=8, megacycle_batches=2),
-    )
-    for i in range(4):
-        rs.cache.add_node(make_node(f"n{i}", cpu="16", mem="32Gi"))
-    for i in range(64):
-        rs.queue.add(make_pod(f"M{i}", cpu="50m"))
-    _drive(rs, rounds=80)
-    assert rs.placed_total == 64, rs.summary()
-    assert rs.invariant_violations_total() == 0
-    assert rs.assert_drained()
-    assert rs.primary.megacycles_total + rs.schedulers[1].megacycles_total > 0
